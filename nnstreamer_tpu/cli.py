@@ -296,6 +296,9 @@ def main(argv=None) -> int:
         return inspect_element(args.inspect)
     if not args.pipeline:
         ap.error("pipeline description required")
+    from .core.hw import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile of this process
     backend_eps = None
     if args.backends is not None:
         from .query.router import parse_endpoints

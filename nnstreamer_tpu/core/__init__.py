@@ -29,7 +29,7 @@ from .registry import (
     unregister_subplugin,
 )
 from .config import Config, get_config, reset_config
-from .hw import AcceleratorSpec, available_platforms, default_device, tpu_available
+from .hw import AcceleratorSpec, available_platforms, default_device, on_tpu
 from .log import logger
 
 __all__ = [
@@ -42,6 +42,6 @@ __all__ = [
     "SubpluginType", "get_all_subplugins", "get_subplugin", "has_subplugin",
     "register_subplugin", "unregister_subplugin",
     "Config", "get_config", "reset_config",
-    "AcceleratorSpec", "available_platforms", "default_device", "tpu_available",
+    "AcceleratorSpec", "available_platforms", "default_device", "on_tpu",
     "logger",
 ]

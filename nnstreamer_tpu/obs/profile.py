@@ -74,8 +74,10 @@ DISPATCH_HOOK: Optional["Profiler"] = None
 ENGINE_HOOK: Optional["Profiler"] = None
 
 #: Hook consumed by ops/pallas entry points at trace time: records
-#: which Pallas kernels (label, shape, dtype) end up inside compiled
-#: programs — device-lane labels for fused dispatches.
+#: which Pallas entry points (label, shape, dtype) were traced into
+#: compiled programs — device-lane labels for fused dispatches. Whether
+#: the Mosaic body or the jnp reference is lowered follows the platform
+#: the program is placed on (ops/pallas).
 KERNEL_HOOK = None  # Optional[Callable[[str, Any, Any], None]]
 
 #: Hook consumed by sched/engine.py after each coalesced device batch:
@@ -129,7 +131,6 @@ class Profiler:
         # utilization state per lane name ("lm", "tp", "xla")
         self._util: Dict[str, Dict[str, float]] = {}
         self._params_cache: Dict[int, float] = {}  # id(engine) -> n_params
-        self._peak_cache: Optional[Tuple[float, float]] = None
         self._m: Optional[Dict[str, Any]] = None
 
     # -- lifecycle ------------------------------------------------------ #
@@ -185,29 +186,28 @@ class Profiler:
             self._attach_util_gauges(name)
 
     # -- peak / roofline ------------------------------------------------ #
-    def _peaks(self) -> Tuple[float, float]:
-        """(peak FLOP/s, peak HBM bytes/s) for device 0, cached."""
-        if self._peak_cache is None:
-            try:
-                import jax
+    def _peaks(self) -> Optional[Tuple[float, float]]:
+        """(peak FLOP/s, peak HBM bytes/s) for device 0 — None when its
+        kind has no entry in the peak tables (a CPU): no peak, no MFU or
+        roofline gauge."""
+        import jax
 
-                from ..utils import probes
-                dev = jax.devices()[0]
-                self._peak_cache = (probes.chip_peak_flops(dev),
-                                    probes.chip_peak_hbm_bw(dev))
-            except Exception:
-                self._peak_cache = (0.0, 0.0)
-        return self._peak_cache
+        from ..utils import probes
+        dev = jax.devices()[0]
+        try:
+            return probes.chip_peak_flops(dev), probes.chip_peak_hbm_bw(dev)
+        except probes.UnknownDeviceError:
+            return None
 
     def _mfu_of(self, name: str) -> float:
         peak, _ = self._peaks()
         st = self._util.get(name)
-        return (st["flops_s"] / peak) if (st and peak) else 0.0
+        return (st["flops_s"] / peak) if st else 0.0
 
     def _roofline_of(self, name: str) -> float:
         peak, bw = self._peaks()
         st = self._util.get(name)
-        if not st or not peak or not bw or not st["intensity"]:
+        if not st or not st["intensity"]:
             return 0.0
         return st["intensity"] / (peak / bw)
 
@@ -218,12 +218,14 @@ class Profiler:
     def _attach_util_gauges(self, name: str) -> None:
         if self._m is None:
             return
+        self._m["achieved"].labels(name).set_function(
+            lambda n=name: self._achieved_of(n))
+        if self._peaks() is None:
+            return
         self._m["mfu"].labels(name).set_function(
             lambda n=name: self._mfu_of(n))
         self._m["roofline"].labels(name).set_function(
             lambda n=name: self._roofline_of(n))
-        self._m["achieved"].labels(name).set_function(
-            lambda n=name: self._achieved_of(n))
 
     def _update_util(self, name: str, flops: float, bytes_: float,
                      dt_s: float) -> None:
@@ -633,10 +635,13 @@ class Profiler:
             f"({st['dropped']} dropped), {st['dispatches']} dispatches, "
             f"sync every {st['sample_every']}",
         ]
+        has_peak = self._peaks() is not None
         for name in sorted(st["lanes"]):
+            util = (f"mfu={self._mfu_of(name):.4f} "
+                    f"roofline={self._roofline_of(name):.3f} "
+                    if has_peak else "")
             lines.append(
-                f"  lane {name}: mfu={self._mfu_of(name):.4f} "
-                f"roofline={self._roofline_of(name):.3f} "
+                f"  lane {name}: {util}"
                 f"achieved={self._achieved_of(name):.3e} FLOP/s")
         for s in self.samples()[:10]:
             dev = s["mean_device_us"]

@@ -16,11 +16,14 @@ fuser (ops/epilogue.py) and the decoders' device-reduce paths:
   * ``dequant_gelu_requant`` — int32 GEMM accumulator → f32 dequant →
     gelu → per-row int8 requant, keeping the w8a8 MLP int8 end-to-end.
 
-Every kernel has a jnp reference used off-TPU and for interpret-mode
-correctness tests; fused callers rely on the references matching the
-unfused lax/NumPy paths bit-for-bit, so change them in lockstep with
-their consumers (decoders/bounding_box.py, decoders/image_segment.py,
-ops/int8.py).
+Every kernel has a jnp reference; the entry points choose between the
+two per lowering platform (``per_platform``): a program placed on a TPU
+gets the Mosaic kernel, the same program placed anywhere else gets the
+reference. ``interpret=True`` forces the Pallas body through the
+interpreter (tests). Fused callers rely on the references
+matching the unfused lax/NumPy paths bit-for-bit, so change them in
+lockstep with their consumers (decoders/bounding_box.py,
+decoders/image_segment.py, ops/int8.py).
 """
 
 from __future__ import annotations
@@ -31,17 +34,11 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...obs import profile as _profile
-
-
-def _on_tpu() -> bool:
-    try:
-        dev = jax.devices()[0]
-    except Exception:  # noqa: BLE001
-        return False
-    return "tpu" in dev.platform.lower() or "TPU" in str(dev.device_kind)
-
+from . import per_platform
 
 _LANE = 128
 
@@ -78,62 +75,82 @@ def nms_sweep_reference(x0: jax.Array, y0: jax.Array, x1: jax.Array,
     return jnp.where(alive, scores, -1.0)
 
 
-def _nms_kernel(rows_ref, o_ref, *, k: int, iou_thr: float, threshold: float):
-    rows = rows_ref[...]                       # (kp, 128) f32, cols 0-4 used
-    x0, y0 = rows[:, 0:1], rows[:, 1:2]
-    x1, y1 = rows[:, 2:3], rows[:, 3:4]
-    sc = rows[:, 4:5]
-    area = (x1 - x0) * (y1 - y0)               # (kp, 1)
-    ix = jnp.minimum(x1, x1.T) - jnp.maximum(x0, x0.T)   # (kp, kp)
-    iy = jnp.minimum(y1, y1.T) - jnp.maximum(y0, y0.T)
+def _nms_kernel(cols_ref, rows_ref, o_ref, sup_ref, *, k: int,
+                iou_thr: float, threshold: float):
+    # the candidates arrive in both orientations so the (kp, kp) pair
+    # matrices form by broadcast alone (no in-kernel transposes):
+    # cols_ref (kp, 128) — candidate i down the sublanes, x0/y0/x1/y1 in
+    # lanes 0-3; rows_ref (8, kp) — candidate j along the lanes,
+    # x0/y0/x1/y1/score in sublanes 0-4
+    cols = cols_ref[...]
+    rows = rows_ref[...]
+    x0c, y0c = cols[:, 0:1], cols[:, 1:2]              # (kp, 1)
+    x1c, y1c = cols[:, 2:3], cols[:, 3:4]
+    x0r, y0r = rows[0:1, :], rows[1:2, :]              # (1, kp)
+    x1r, y1r = rows[2:3, :], rows[3:4, :]
+    sc = rows[4:5, :]
+    area_c = (x1c - x0c) * (y1c - y0c)
+    area_r = (x1r - x0r) * (y1r - y0r)
+    ix = jnp.minimum(x1c, x1r) - jnp.maximum(x0c, x0r)  # (kp, kp)
+    iy = jnp.minimum(y1c, y1r) - jnp.maximum(y0c, y0r)
     inter = jnp.clip(ix, 0) * jnp.clip(iy, 0)
-    union = area + area.T - inter
+    union = area_c + area_r - inter
     iou = jnp.where(union > 0, inter / union, 0.0)
-    kp = rows.shape[0]
+    kp = cols.shape[0]
     later = (jax.lax.broadcasted_iota(jnp.int32, (kp, kp), 1)
              > jax.lax.broadcasted_iota(jnp.int32, (kp, kp), 0))
-    suppresses = (iou > iou_thr) & later
+    # row i of the scratch = who candidate i suppresses; the sweep reads
+    # one row per step straight from VMEM (Mosaic has no dynamic slice of
+    # a value, only of a ref)
+    sup_ref[...] = jnp.where((iou > iou_thr) & later, 1.0, 0.0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
 
-    def body(i, alive):
-        sup_i = jax.lax.dynamic_slice_in_dim(suppresses, i, 1, 0)   # (1, kp)
-        alive_i = jax.lax.dynamic_slice_in_dim(alive, i, 1, 0)      # (1, 1)
-        return alive & ~(alive_i & sup_i.T)
+    def body(i, alive):                                # alive (1, kp) 0/1
+        alive_i = jnp.sum(jnp.where(lane == i, alive, 0.0),
+                          axis=1, keepdims=True)       # (1, 1)
+        return alive * (1.0 - alive_i * sup_ref[pl.ds(i, 1), :])
 
-    alive = jax.lax.fori_loop(0, k, body, sc >= threshold)
-    out = jnp.where(alive, sc, -1.0)
-    o_ref[...] = jnp.broadcast_to(out, (kp, _LANE))
+    alive = jax.lax.fori_loop(0, k, body,
+                              jnp.where(sc >= threshold, 1.0, 0.0))
+    o_ref[...] = jnp.broadcast_to(jnp.where(alive > 0.0, sc, -1.0),
+                                  o_ref.shape)
+
+
+def _nms_pallas(x0, y0, x1, y1, scores, *, iou_threshold: float,
+                threshold: float, interpret: bool) -> jax.Array:
+    k = scores.shape[0]
+    kp = -(-k // _LANE) * _LANE
+    fields = jnp.stack([x0, y0, x1, y1, scores]).astype(jnp.float32)
+    # pad slots carry score -1: dead from the start, never kept/suppress
+    rows = jnp.zeros((8, kp), jnp.float32).at[4, :].set(-1.0)
+    rows = rows.at[:5, :k].set(fields)
+    cols = jnp.zeros((kp, _LANE), jnp.float32).at[:k, :4].set(fields[:4].T)
+    out = pl.pallas_call(
+        functools.partial(_nms_kernel, k=k, iou_thr=float(iou_threshold),
+                          threshold=float(threshold)),
+        out_shape=jax.ShapeDtypeStruct((8, kp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((kp, kp), jnp.float32)],
+        interpret=interpret,
+    )(cols, rows)
+    return out[0, :k].astype(scores.dtype)
 
 
 def nms_sweep(x0: jax.Array, y0: jax.Array, x1: jax.Array, y1: jax.Array,
               scores: jax.Array, *, iou_threshold: float, threshold: float,
               interpret: bool = False) -> jax.Array:
-    """Greedy-NMS sweep on the VPU; jnp fallback off-TPU.
+    """Greedy-NMS sweep on the VPU; jnp reference off-TPU.
 
     K is the PRE_NMS_TOPK candidate budget (≤ a few hundred), so the
     whole (K, K) IoU matrix fits one VMEM block — no grid.
     """
-    if not (interpret or _on_tpu()):
-        return nms_sweep_reference(x0, y0, x1, y1, scores,
-                                   iou_threshold, threshold)
     if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
         _profile.KERNEL_HOOK("pallas.nms_sweep", scores.shape, scores.dtype)
-    from jax.experimental import pallas as pl
-
-    k = scores.shape[0]
-    kp = max(8, -(-k // 8) * 8)
-    rows = jnp.zeros((kp, _LANE), jnp.float32)
-    for col, v in enumerate((x0, y0, x1, y1)):
-        rows = rows.at[:k, col].set(v.astype(jnp.float32))
-    rows = rows.at[:k, 4].set(scores.astype(jnp.float32))
-    if kp != k:
-        rows = rows.at[k:, 4].set(-1.0)  # pad rows dead: never kept/suppress
-    out = pl.pallas_call(
-        functools.partial(_nms_kernel, k=k, iou_thr=float(iou_threshold),
-                          threshold=float(threshold)),
-        out_shape=jax.ShapeDtypeStruct((kp, _LANE), jnp.float32),
-        interpret=interpret,
-    )(rows)
-    return out[:k, 0]
+    return per_platform(
+        functools.partial(_nms_pallas, iou_threshold=iou_threshold,
+                          threshold=threshold, interpret=interpret),
+        functools.partial(nms_sweep_reference, iou_threshold=iou_threshold,
+                          threshold=threshold),
+        interpret, x0, y0, x1, y1, scores)
 
 
 # --------------------------------------------------------------------------- #
@@ -154,15 +171,8 @@ def _class_reduce_kernel(x_ref, s_ref, i_ref, *, l: int):
     i_ref[...] = jnp.broadcast_to(idx, i_ref.shape)
 
 
-def class_reduce(cls: jax.Array,
-                 interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """(N, L) class scores → (best_score (N,), best_index (N,))."""
-    if not (interpret or _on_tpu()):
-        return class_reduce_reference(cls)
-    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
-        _profile.KERNEL_HOOK("pallas.class_reduce", cls.shape, cls.dtype)
-    from jax.experimental import pallas as pl
-
+def _class_reduce_pallas(cls: jax.Array, *, interpret: bool
+                         ) -> Tuple[jax.Array, jax.Array]:
     n, l = cls.shape
     lp = -(-l // _LANE) * _LANE
     block_rows = min(max(8, -(-n // 8) * 8), 512)
@@ -181,6 +191,16 @@ def class_reduce(cls: jax.Array,
         interpret=interpret,
     )(x)
     return best[:n, 0].astype(cls.dtype), idx[:n, 0]
+
+
+def class_reduce(cls: jax.Array,
+                 interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """(N, L) class scores → (best_score (N,), best_index (N,))."""
+    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
+        _profile.KERNEL_HOOK("pallas.class_reduce", cls.shape, cls.dtype)
+    return per_platform(
+        functools.partial(_class_reduce_pallas, interpret=interpret),
+        class_reduce_reference, interpret, cls)
 
 
 # --------------------------------------------------------------------------- #
@@ -216,25 +236,11 @@ def _argmax_colorize_kernel(x_ref, pal_ref, o_ref, *, c: int):
     o_ref[...] = out.astype(jnp.int32).astype(jnp.uint8)
 
 
-def segment_colorize(x: jax.Array, palette: Any, pre_argmaxed: bool = False,
-                     interpret: bool = False) -> jax.Array:
-    """(..., C) logits (or (...) class ids when pre_argmaxed) → (..., 4)
-    RGBA uint8 via a (256, 4) palette, fused argmax+gather on device.
-
-    The palette gather runs as a one-hot matmul on the MXU — palette
-    values are uint8 (< 256, exact in f32), so the result is identical
-    to ``palette[argmax(x, -1)]`` on host.
-    """
-    if not (interpret or _on_tpu()):
-        return segment_colorize_reference(x, palette, pre_argmaxed)
-    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
-        _profile.KERNEL_HOOK("pallas.segment_colorize", x.shape, x.dtype)
-    from jax.experimental import pallas as pl
-
-    pal = jnp.zeros((256, _LANE), jnp.float32)
-    pal_np = np.asarray(palette)
-    pal = pal.at[:pal_np.shape[0], :pal_np.shape[1]].set(
-        jnp.asarray(pal_np, jnp.float32))
+def _segment_colorize_pallas(x: jax.Array, *, palette: np.ndarray,
+                             pre_argmaxed: bool, interpret: bool
+                             ) -> jax.Array:
+    pal = np.zeros((256, _LANE), np.float32)
+    pal[:palette.shape[0], :palette.shape[1]] = palette
     if pre_argmaxed:
         lead = x.shape
         flat = x.reshape(-1).astype(jnp.int32)
@@ -267,8 +273,28 @@ def segment_colorize(x: jax.Array, palette: Any, pre_argmaxed: bool = False,
                   pl.BlockSpec((256, _LANE), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_rows, _LANE), lambda i: (i, 0)),
         interpret=interpret,
-    )(inp, pal)
+    )(inp, jnp.asarray(pal))
     return out[:p, :4].reshape(tuple(lead) + (4,))
+
+
+def segment_colorize(x: jax.Array, palette: Any, pre_argmaxed: bool = False,
+                     interpret: bool = False) -> jax.Array:
+    """(..., C) logits (or (...) class ids when pre_argmaxed) → (..., 4)
+    RGBA uint8 via a (256, 4) palette, fused argmax+gather on device.
+
+    The palette gather runs as a one-hot matmul on the MXU — palette
+    values are uint8 (< 256, exact in f32), so the result is identical
+    to ``palette[argmax(x, -1)]`` on host.
+    """
+    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
+        _profile.KERNEL_HOOK("pallas.segment_colorize", x.shape, x.dtype)
+    palette = np.asarray(palette)
+    return per_platform(
+        functools.partial(_segment_colorize_pallas, palette=palette,
+                          pre_argmaxed=pre_argmaxed, interpret=interpret),
+        functools.partial(segment_colorize_reference, palette=palette,
+                          pre_argmaxed=pre_argmaxed),
+        interpret, x)
 
 
 # --------------------------------------------------------------------------- #
@@ -296,8 +322,13 @@ def _dgr_kernel(y_ref, xs_ref, ws_ref, q_ref, s_ref, *, out_dtype):
     y = y_ref[...].astype(jnp.float32)                # (br, fp)
     xs = xs_ref[...][:, 0:1]                          # (br, 1)
     ws = ws_ref[...][0:1, :]                          # (1, fp)
-    h = (y * xs * ws).astype(out_dtype)
-    xf = jax.nn.gelu(h).astype(jnp.float32)
+    h = (y * xs * ws).astype(out_dtype)               # the GEMM's rounding
+    # gelu in f32, rounded to out_dtype ONCE. Left in bf16, Mosaic rounds
+    # after every elementwise op on chips without a bf16 VPU (v5e) and
+    # drifts up to 3 int8 codes from XLA's unfused bf16 chain; this form
+    # stays within 2 (chip_smoke.py bounds it), and is exact in f32.
+    xf = jax.nn.gelu(h.astype(jnp.float32)).astype(out_dtype) \
+        .astype(jnp.float32)
     # padded columns carry ws=0 → h=0 → gelu(0)=0: no effect on absmax
     absmax = jnp.max(jnp.abs(xf), axis=1, keepdims=True)
     s = jnp.where(absmax == 0.0, 1.0, absmax / 127.0)
@@ -305,28 +336,25 @@ def _dgr_kernel(y_ref, xs_ref, ws_ref, q_ref, s_ref, *, out_dtype):
     s_ref[...] = jnp.broadcast_to(s, s_ref.shape)
 
 
-def dequant_gelu_requant(y: jax.Array, xs: jax.Array, ws: jax.Array,
-                         out_dtype=jnp.bfloat16, interpret: bool = False
-                         ) -> Tuple[jax.Array, jax.Array]:
-    """Fused w8a8 MLP inner epilogue.
+#: int32 accumulator bytes one grid step may hold: the kernel keeps the
+#: block plus ~4 float32 temporaries of its size live, double-buffered
+#: input included, inside Mosaic's scoped VMEM (16 MiB by default on v5e —
+#: a fixed 256-row block is 16 MiB at F=16384 and fails to compile there)
+_DGR_BLOCK_BYTES = 2 * 1024 * 1024
 
-    ``y`` is the (..., F) int32 GEMM accumulator, ``xs`` the (..., 1)
-    activation scales, ``ws`` the (F,) weight scales. Returns the
-    requantized (..., F) int8 activations and their (..., 1) scales, so
-    the second GEMM consumes int8 directly — no f32 round trip in HBM.
-    """
-    if not (interpret or _on_tpu()):
-        return dequant_gelu_requant_reference(y, xs, ws, out_dtype)
-    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
-        _profile.KERNEL_HOOK("pallas.dequant_gelu_requant", y.shape, y.dtype)
-    from jax.experimental import pallas as pl
 
+def _dgr_pallas(y: jax.Array, xs: jax.Array, ws: jax.Array, *, out_dtype,
+                interpret: bool) -> Tuple[jax.Array, jax.Array]:
     lead = y.shape[:-1]
     f = y.shape[-1]
     y2 = y.reshape(-1, f)
     r = y2.shape[0]
     fp = -(-f // _LANE) * _LANE
-    block_rows = min(max(32, -(-max(r, 1) // 32) * 32), 256)
+    # rows per step: whole rows (the per-row absmax needs them), in
+    # multiples of the int8 output tile height, as many as the VMEM
+    # budget allows — 128 at F=4096, 32 at F=16384
+    fit = max(32, _DGR_BLOCK_BYTES // (fp * 4) // 32 * 32)
+    block_rows = min(max(32, -(-max(r, 1) // 32) * 32), fit, 256)
     rp = -(-max(r, 1) // block_rows) * block_rows
     ypad = jnp.zeros((rp, fp), jnp.int32).at[:r, :f].set(y2)
     xspad = jnp.zeros((rp, _LANE), jnp.float32).at[:r, 0].set(
@@ -348,3 +376,23 @@ def dequant_gelu_requant(y: jax.Array, xs: jax.Array, ws: jax.Array,
     )(ypad, xspad, wspad)
     return (q[:r, :f].reshape(tuple(lead) + (f,)),
             s[:r, :1].reshape(tuple(lead) + (1,)))
+
+
+def dequant_gelu_requant(y: jax.Array, xs: jax.Array, ws: jax.Array,
+                         out_dtype=jnp.bfloat16, interpret: bool = False
+                         ) -> Tuple[jax.Array, jax.Array]:
+    """Fused w8a8 MLP inner epilogue.
+
+    ``y`` is the (..., F) int32 GEMM accumulator, ``xs`` the (..., 1)
+    activation scales, ``ws`` the (F,) weight scales. Returns the
+    requantized (..., F) int8 activations and their (..., 1) scales, so
+    the second GEMM consumes int8 directly — no f32 round trip in HBM.
+    """
+    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
+        _profile.KERNEL_HOOK("pallas.dequant_gelu_requant", y.shape, y.dtype)
+    return per_platform(
+        functools.partial(_dgr_pallas, out_dtype=out_dtype,
+                          interpret=interpret),
+        functools.partial(dequant_gelu_requant_reference,
+                          out_dtype=out_dtype),
+        interpret, y, xs, ws)

@@ -13,7 +13,9 @@ Causal masking is two-level: whole k-blocks strictly above the diagonal
 are skipped via ``pl.when``, the diagonal block applies the per-element
 mask.
 
-Off-TPU (tests, CPU mesh) the same kernel runs in interpret mode.
+Where the computation is not placed on a TPU (tests, CPU mesh) the same
+kernel runs through the Pallas interpreter; the choice is made per
+lowering platform (``jax.lax.platform_dependent``).
 
 Reference equivalent: the reference has no attention kernels (its models
 are CNNs served by vendor runtimes); this is TPU-first scope from
@@ -30,19 +32,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ... import tune as _tune
 from ...obs import profile as _profile
-from .preprocess import _on_tpu
 
 _NEG_INF = -1e30  # mask value; finite so (m - m) stays NaN-free
-
-try:  # pallas is part of jax, but keep the module importable without it
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
@@ -136,13 +132,13 @@ def _pick_block(lp: int, want: int) -> int:
     return best or 1
 
 
-#: the candidate grid the autotuner sweeps/ranks — exactly the
-#: FLASH_TUNE_r05 hand-sweep grid, so a tuner pick can never be worse
-#: than the best hand-swept point on the same hardware
+#: the candidate grid the autotuner sweeps/ranks — exactly the round-5
+#: hand-sweep grid, so a tuner pick can never be worse than the best
+#: hand-swept point on the same hardware
 _TUNE_GRID = ((128, 128), (256, 256), (512, 512), (512, 1024),
               (1024, 1024))
-#: hand-swept default (FLASH_TUNE_r05 winner) — what every call gets
-#: when the tuner is off or has nothing better
+#: hand-swept at round 5 — what every call gets when the tuner is off
+#: or has nothing better
 _DEFAULT_BLOCKS = (512, 1024)
 
 
@@ -166,7 +162,7 @@ def _block_features(b: int, h: int, L: int, d: int, itemsize: int):
     return features
 
 
-def _tuned_blocks(q, k, v, causal: bool, interpret: bool):
+def _tuned_blocks(q, k, v, causal: bool, interpret: Optional[bool]):
     """Resolve (block_q, block_k) through the autotuner. Store/model
     hits are free; with neither, a bounded measured sweep times the
     candidate grid on throwaway arrays of the caller's shape — safe
@@ -207,45 +203,12 @@ def _tuned_blocks(q, k, v, causal: bool, interpret: bool):
         return _DEFAULT_BLOCKS
 
 
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = True,
-                    block_q: Optional[int] = None,
-                    block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None,
-                    return_residuals: bool = False,
-                    _force_pad_d: bool = False):
-    """Causal (or full) attention over ``(B, H, L, D)`` tensors.
-
-    Sequence length is padded up to a block multiple internally (padded
-    keys are masked via an explicit length mask), and on real TPUs a
-    head dim that is not a multiple of the 128-wide lanes is zero-padded
-    internally too (score-neutral; padded v columns sliced off, softmax
-    scale from the true head dim) — callers never pad anything.
-
-    ``block_q``/``block_k`` default to the FLASH_TUNE_r05 hand-swept
-    512/1024 — unless the autotuner hook is installed, in which case
-    unset blocks resolve through its store/model/sweep (docs/tuning.md).
-    Explicit values always win and never consult the tuner.
-
-    Precision model: scores and the output accumulate in f32; the
-    softmax weights are rounded to v's dtype before the PV matmul (the
-    standard flash configuration). With bf16 inputs this differs from a
-    full-f32 dense computation by ~1e-2 relative.
-    """
-    if pl is None:  # pragma: no cover
-        raise RuntimeError("pallas unavailable in this jax build")
-    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
-        _profile.KERNEL_HOOK("pallas.flash_attention", q.shape, q.dtype)
-    if interpret is None:
-        interpret = not _on_tpu()
-    if block_q is None or block_k is None:
-        tq, tk = _tuned_blocks(q, k, v, causal, interpret)
-        block_q = tq if block_q is None else block_q
-        block_k = tk if block_k is None else block_k
+def _flash_pallas(q, k, v, *, causal: bool, block_q: int, block_k: int,
+                  return_residuals: bool, pad_d: bool, interpret: bool):
     b, h, L, d_orig = q.shape
     sm_scale = 1.0 / float(np.sqrt(d_orig))  # from the TRUE head dim
     d = d_orig
-    if (not interpret or _force_pad_d) and d % 128:
+    if pad_d and d % 128:
         # real-TPU lanes are 128-wide: zero-pad the head dim (zero q/k
         # columns add nothing to the scores; zero v columns are sliced
         # off at return). sm_scale above already uses the true d.
@@ -300,8 +263,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         # batch·heads and q-blocks are independent; only the k axis is an
         # accumulation (scratch carries across it) and must stay ordered
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
@@ -311,3 +273,50 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 m_out.reshape(b, h, Lp)[:, :, :L],
                 l_out.reshape(b, h, Lp)[:, :, :L])
     return result.reshape(b, h, Lp, d)[:, :, :L, :d_orig]
+
+
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    causal: bool = True,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
+                    interpret: Optional[bool] = None,
+                    return_residuals: bool = False,
+                    _force_pad_d: bool = False):
+    """Causal (or full) attention over ``(B, H, L, D)`` tensors.
+
+    Sequence length is padded up to a block multiple internally (padded
+    keys are masked via an explicit length mask), and on real TPUs a
+    head dim that is not a multiple of the 128-wide lanes is zero-padded
+    internally too (score-neutral; padded v columns sliced off, softmax
+    scale from the true head dim) — callers never pad anything.
+
+    ``interpret=None`` (the default) compiles the kernel with Mosaic
+    where the computation is placed on a TPU and runs it through the
+    Pallas interpreter on every other platform; a bool forces one.
+
+    ``block_q``/``block_k`` default to the round-5 hand-swept 512/1024 —
+    unless the autotuner hook is installed, in which case unset blocks
+    resolve through its store/model/sweep (docs/tuning.md). Explicit
+    values always win and never consult the tuner.
+
+    Precision model: scores and the output accumulate in f32; the
+    softmax weights are rounded to v's dtype before the PV matmul (the
+    standard flash configuration). With bf16 inputs this differs from a
+    full-f32 dense computation by ~1e-2 relative.
+    """
+    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
+        _profile.KERNEL_HOOK("pallas.flash_attention", q.shape, q.dtype)
+    if block_q is None or block_k is None:
+        tq, tk = _tuned_blocks(q, k, v, causal, interpret)
+        block_q = tq if block_q is None else block_q
+        block_k = tk if block_k is None else block_k
+    run = functools.partial(
+        _flash_pallas, causal=causal, block_q=block_q, block_k=block_k,
+        return_residuals=return_residuals)
+    if interpret is not None:
+        return run(q, k, v, pad_d=_force_pad_d or not interpret,
+                   interpret=interpret)
+    return jax.lax.platform_dependent(
+        q, k, v,
+        tpu=functools.partial(run, pad_d=True, interpret=False),
+        default=functools.partial(run, pad_d=_force_pad_d, interpret=True))

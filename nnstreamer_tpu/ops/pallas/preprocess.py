@@ -11,8 +11,10 @@ the pallas path (/opt/skills/guides/pallas_guide.md patterns):
   * ``quantize_affine``  — float → uint8 affine quantization (the reverse
     boundary; reference quantized-model pipelines).
 
-Both have jnp reference implementations used as fallback off-TPU and for
-correctness tests (pallas interpret mode on CPU).
+Both have jnp reference implementations: the entry points lower the
+Mosaic kernel where the computation is placed on a TPU and the reference
+on every other platform (``per_platform``); ``interpret=True`` runs the
+Pallas body through the interpreter (tests).
 """
 
 from __future__ import annotations
@@ -22,17 +24,10 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.experimental import pallas as pl
 
 from ...obs import profile as _profile
-
-
-def _on_tpu() -> bool:
-    try:
-        dev = jax.devices()[0]
-    except Exception:  # noqa: BLE001
-        return False
-    return "tpu" in dev.platform.lower() or "TPU" in str(dev.device_kind)
+from . import per_platform
 
 
 # --------------------------------------------------------------------------- #
@@ -55,17 +50,8 @@ def normalize_u8_reference(x: jax.Array, scale: float, bias: float,
     return (x.astype(jnp.float32) * scale + bias).astype(out_dtype)
 
 
-def normalize_u8(x: jax.Array, scale: float = 1.0 / 127.5,
-                 bias: float = -1.0, out_dtype=jnp.bfloat16,
-                 interpret: bool = False) -> jax.Array:
-    """Normalize a uint8 tensor on the VPU via pallas; falls back to the jnp
-    path when not on TPU (unless interpret=True for testing)."""
-    if not (interpret or _on_tpu()):
-        return normalize_u8_reference(x, scale, bias, out_dtype)
-    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
-        _profile.KERNEL_HOOK("pallas.normalize_u8", x.shape, x.dtype)
-    from jax.experimental import pallas as pl
-
+def _normalize_pallas(x: jax.Array, *, scale: float, bias: float, out_dtype,
+                      interpret: bool) -> jax.Array:
     orig_shape = x.shape
     flat = x.reshape(-1)
     n = flat.shape[0]
@@ -91,6 +77,21 @@ def normalize_u8(x: jax.Array, scale: float = 1.0 / 127.5,
     return out.reshape(-1)[:n].reshape(orig_shape)
 
 
+def normalize_u8(x: jax.Array, scale: float = 1.0 / 127.5,
+                 bias: float = -1.0, out_dtype=jnp.bfloat16,
+                 interpret: bool = False) -> jax.Array:
+    """Normalize a uint8 tensor on the VPU via pallas; the jnp reference
+    where the computation is not placed on a TPU."""
+    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
+        _profile.KERNEL_HOOK("pallas.normalize_u8", x.shape, x.dtype)
+    return per_platform(
+        functools.partial(_normalize_pallas, scale=scale, bias=bias,
+                          out_dtype=out_dtype, interpret=interpret),
+        functools.partial(normalize_u8_reference, scale=scale, bias=bias,
+                          out_dtype=out_dtype),
+        interpret, x)
+
+
 # --------------------------------------------------------------------------- #
 # quantize_affine: q = clip(round(x / scale) + zero_point, 0, 255) as uint8
 # --------------------------------------------------------------------------- #
@@ -108,19 +109,13 @@ def quantize_affine_reference(x: jax.Array, scale: float,
     return jnp.clip(q, 0, 255).astype(jnp.uint8)
 
 
-def quantize_affine(x: jax.Array, scale: float, zero_point: int = 0,
-                    interpret: bool = False) -> jax.Array:
-    if not (interpret or _on_tpu()):
-        return quantize_affine_reference(x, scale, zero_point)
-    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
-        _profile.KERNEL_HOOK("pallas.quantize_affine", x.shape, x.dtype)
-    from jax.experimental import pallas as pl
-
+def _quantize_pallas(x: jax.Array, *, scale: float, zero_point: int,
+                     interpret: bool) -> jax.Array:
     orig_shape = x.shape
     flat = x.reshape(-1)
     n = flat.shape[0]
     lane = 128
-    block = 8 * lane
+    block = 32 * lane  # uint8 output tiles are 32 rows high
     padded = -(-n // block) * block
     if padded != n:
         flat = jnp.pad(flat, (0, padded - n))
@@ -138,3 +133,15 @@ def quantize_affine(x: jax.Array, scale: float, zero_point: int = 0,
         interpret=interpret,
     )(tiled)
     return out.reshape(-1)[:n].reshape(orig_shape)
+
+
+def quantize_affine(x: jax.Array, scale: float, zero_point: int = 0,
+                    interpret: bool = False) -> jax.Array:
+    if _profile.KERNEL_HOOK is not None:  # trace-time kernel label
+        _profile.KERNEL_HOOK("pallas.quantize_affine", x.shape, x.dtype)
+    return per_platform(
+        functools.partial(_quantize_pallas, scale=scale,
+                          zero_point=zero_point, interpret=interpret),
+        functools.partial(quantize_affine_reference, scale=scale,
+                          zero_point=zero_point),
+        interpret, x)

@@ -31,19 +31,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map (0.8+) with fallback to the experimental module;
-    replication checking off (we manage specs explicitly)."""
-    if hasattr(jax, "shard_map"):
-        for flag in ({"check_vma": False}, {"check_rep": False}, {}):
-            try:
-                return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, **flag)
-            except TypeError:
-                continue
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with replication checking off (specs are
+    managed explicitly)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _online_block(q, k, v, m_prev, l_prev, o_prev, mask=None):

@@ -18,8 +18,8 @@ when expired and travels on the wire as remaining budget;
 
 ``async_depth=N`` (TPU-first addition, default 1 = reference-equivalent
 synchronous semantics): keep up to N requests in flight on the one TCP
-stream. A server whose filter runs on a high-RTT device (a tunneled TPU)
-costs one device round trip per frame; with N>1 those round trips overlap
+stream. Each frame costs one wire round trip plus the server's device
+round trip; with N>1 those round trips overlap
 and offload throughput approaches N/RTT instead of 1/RTT — the query-layer
 analog of tensor_decoder's ``async_depth``. Results return in order (the
 stream and the server pipeline are serial), so PTS restoration is a FIFO.

@@ -2,12 +2,11 @@
 
 The reference exposes per-filter invoke latency / throughput as runtime
 props (tensor_filter.c:366-400, tensor_filter_common.c:967-981) but cannot
-say *where* an invoke's time goes.  On TPU — especially through a
-high-RTT tunnel — a synchronous per-invoke number is dominated by the
-round-trip, not by chip time, so these probes measure each phase the way
-streaming pipelines actually run it: **pipelined**, K transfers/invokes in
-flight, reporting the amortized per-frame cost.  A separate single
-synchronous round-trip isolates the RTT itself.
+say *where* an invoke's time goes.  A synchronous per-invoke number
+mixes the host↔device round trip with chip time, so these probes measure
+each phase the way streaming pipelines actually run it: **pipelined**, K
+transfers/invokes in flight, reporting the amortized per-frame cost.  A
+separate single synchronous round-trip isolates the round trip itself.
 
 ``model_flops`` asks XLA's compiled-cost analysis for the per-invoke FLOP
 count; ``mfu`` relates achieved FLOP/s to the chip's peak (bf16 MXU).
@@ -22,15 +21,15 @@ import numpy as np
 
 #: per-chip peak dense-matmul FLOP/s used for MFU accounting, keyed by a
 #: substring of jax device_kind. bf16 MXU numbers (public chip specs).
+#: Accelerators only: a device that matches no key has no peak, and
+#: asking for one raises (a utilization of a CPU means nothing).
 PEAK_FLOPS = {
     "v5 lite": 197e12,  # TPU v5e
     "v5e": 197e12,
     "v4": 275e12,
     "v5p": 459e12,
     "v6": 918e12,  # Trillium
-    "cpu": 1e11,  # nominal; MFU on CPU is not meaningful
 }
-DEFAULT_PEAK = 197e12
 
 #: per-chip peak HBM bandwidth (bytes/s), same device_kind keying —
 #: the roofline's memory ceiling (public chip specs).
@@ -40,13 +39,14 @@ PEAK_HBM_BW = {
     "v4": 1228e9,
     "v5p": 2765e9,
     "v6": 1640e9,  # Trillium
-    "cpu": 50e9,  # nominal DDR figure; roofline on CPU is not meaningful
 }
-DEFAULT_HBM_BW = 819e9
 
 
-def _by_device_kind(table: Dict[str, float], default: float,
-                    device: Any = None) -> float:
+class UnknownDeviceError(LookupError):
+    """The device's ``device_kind`` is not in the peak tables."""
+
+
+def _by_device_kind(table: Dict[str, float], device: Any = None) -> float:
     import jax
 
     device = device or jax.devices()[0]
@@ -54,15 +54,18 @@ def _by_device_kind(table: Dict[str, float], default: float,
     for key, val in table.items():
         if key in kind:
             return val
-    return default
+    raise UnknownDeviceError(
+        f"no peak recorded for device kind {kind!r} "
+        f"(known: {sorted(table)}); add it to utils/probes.py with its "
+        "source before reporting a utilization on it")
 
 
 def chip_peak_flops(device: Any = None) -> float:
-    return _by_device_kind(PEAK_FLOPS, DEFAULT_PEAK, device)
+    return _by_device_kind(PEAK_FLOPS, device)
 
 
 def chip_peak_hbm_bw(device: Any = None) -> float:
-    return _by_device_kind(PEAK_HBM_BW, DEFAULT_HBM_BW, device)
+    return _by_device_kind(PEAK_HBM_BW, device)
 
 
 def ridge_intensity(device: Any = None) -> float:
@@ -79,8 +82,6 @@ def model_flops(fn: Callable, *example_args: Any) -> Optional[float]:
     try:
         compiled = jax.jit(fn).lower(*example_args).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returned [dict]
-            cost = cost[0] if cost else {}
         flops = float(cost.get("flops", 0.0)) if cost else 0.0
         return flops if flops > 0 else None
     except Exception:
@@ -92,7 +93,7 @@ def mfu(flops_per_frame: Optional[float], fps: float,
     """Model FLOPs utilization: achieved FLOP/s over chip peak. Only an
     *MFU* when fps is measured over device-busy time (a saturating or
     synced loop). For an end-to-end pipeline rate — where batching
-    budgets, tunnel RTT, and host stages sit between frames — use
+    budgets, wire round trips, and host stages sit between frames — use
     ``pipeline_util``, which is the same ratio under its honest name."""
     if not flops_per_frame or not np.isfinite(fps):
         return None
@@ -181,111 +182,3 @@ def phase_split(fn: Callable, example: Sequence[np.ndarray],
         "compute_us": round(compute * 1e6, 1),
         "d2h_us": round(max(d2h, 0.0) * 1e6, 1),
     }
-
-
-def tpu_smoke(device: Any = None) -> Dict[str, str]:
-    """On-chip smoke lane: exercises the paths the CPU test suite pins to
-    the virtual mesh and reports pass/fail per item (VERDICT r2 weak #7).
-
-    Items: device-resident element flow, decoder submit/complete device
-    reduce, bucketed dynamic-count invoke, donate=true, non-interpret
-    Pallas kernel.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    device = device or jax.devices()[0]
-    results: Dict[str, str] = {"device": str(device)}
-
-    def run(name: str, thunk: Callable[[], None]) -> None:
-        try:
-            thunk()
-            results[name] = "pass"
-        except Exception as e:  # noqa: BLE001 - report, don't crash bench
-            results[name] = f"FAIL: {type(e).__name__}: {e}"[:200]
-
-    def device_resident_flow():
-        from fractions import Fraction
-
-        from ..core import Caps
-        from ..graph import Pipeline
-
-        p = Pipeline()
-        frames = [np.random.default_rng(i).integers(0, 255, (16, 16, 3))
-                  .astype(np.uint8) for i in range(4)]
-        src = p.add_new("appsrc", caps=Caps("video/x-raw", {
-            "format": "RGB", "width": 16, "height": 16,
-            "framerate": Fraction(0, 1)}), data=frames)
-        conv = p.add_new("tensor_converter")
-        filt = p.add_new("tensor_filter", framework="xla-tpu",
-                         model="zoo://scaler?dims=3:16:16:1&types=uint8"
-                               "&scale=2")
-        sink = p.add_new("tensor_sink", store=True)
-        Pipeline.link(src, conv, filt, sink)
-        p.run(timeout=300)
-        assert sink.num_buffers == 4
-        assert sink.buffers[0].memories[0].is_device, "output left device"
-
-    def submit_complete():
-        from ..core.buffer import Buffer
-        from ..core.types import TensorsConfig, TensorsInfo
-        from ..decoders.base import find_decoder
-
-        seg = np.random.default_rng(0).normal(
-            size=(1, 8, 8, 5)).astype(np.float32)
-        cfg = TensorsConfig(TensorsInfo.from_strings("5:8:8:1", "float32"))
-        d = find_decoder("image_segment")()
-        d.init({1: "tflite-deeplab"})
-        tok = d.submit(Buffer.of(jax.device_put(seg, device)), cfg)
-        assert isinstance(tok, tuple), "device reduce path not taken"
-        out = d.complete(tok, cfg)
-        ref = d.decode(Buffer.of(seg), cfg)
-        np.testing.assert_array_equal(out.memories[0].host(),
-                                      ref.memories[0].host())
-
-    def bucketed():
-        from ..core.buffer import TensorMemory
-        from ..filters.base import FilterProps
-        from ..filters.xla import XLAFilter
-
-        f = XLAFilter()
-        f.open(FilterProps(model="zoo://passthrough", custom="bucket=4"))
-        outs = f.invoke([TensorMemory(np.full((3, 3), i, np.float32))
-                         for i in range(3)])
-        got = outs[0].host()
-        assert got.shape == (3, 3, 3)
-        np.testing.assert_array_equal(
-            got, np.stack([np.full((3, 3), i, np.float32)
-                           for i in range(3)]))
-
-    def donate():
-        from ..core.buffer import TensorMemory
-        from ..filters.base import FilterProps
-        from ..filters.xla import XLAFilter
-
-        f = XLAFilter()
-        f.open(FilterProps(model="zoo://scaler?scale=3",
-                           custom="donate=true,sync=true"))
-        x = np.ones((4, 4), np.float32)
-        outs = f.invoke([TensorMemory(jax.device_put(x, device))])
-        np.testing.assert_allclose(outs[0].host(), x * 3)
-
-    def pallas_compiled():
-        from ..ops.pallas.preprocess import _on_tpu, normalize_u8
-
-        assert _on_tpu(), "pallas probe needs the real chip"
-        x = jax.device_put(np.arange(256, dtype=np.uint8).reshape(2, 128),
-                           device)
-        out = np.asarray(normalize_u8(x, scale=1 / 255.0, bias=0.0,
-                                      out_dtype=jnp.float32,
-                                      interpret=False)).astype(np.float32)
-        np.testing.assert_allclose(
-            out, np.arange(256, dtype=np.float32).reshape(2, 128) / 255.0,
-            rtol=1e-6)
-
-    run("device_resident_flow", device_resident_flow)
-    run("decoder_submit_complete", submit_complete)
-    run("bucketed_invoke", bucketed)
-    run("donate_invoke", donate)
-    run("pallas_noninterpret", pallas_compiled)
-    return results

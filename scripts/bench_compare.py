@@ -77,7 +77,7 @@ LANES: Dict[str, int] = {
     "epilogue_fusion_dispatch_ratio": +1,
     # autotuner (tune/): a warm store must answer without sweeping
     # (0 is the contract, any growth is a persistence regression), and
-    # the tuner's flash-block pick must match or beat the FLASH_TUNE_r05
+    # the tuner's flash-block pick must match or beat the round-5
     # hand sweep it replaces (ratio >= 1)
     "autotune_warm_sweeps": -1,
     "autotune_flash_vs_hand": +1,

@@ -158,3 +158,82 @@ class TestAccelerator:
         assert s.enabled and s.preference == ("tpu", "cpu")
         assert not AcceleratorSpec.parse("false").enabled
         assert AcceleratorSpec.parse(None).enabled
+
+    def test_unavailable_preference_says_so(self, caplog):
+        """A stated preference that is not there still resolves (reference
+        parse_accl_hw semantics) — with a warning naming where it landed."""
+        import logging
+
+        with caplog.at_level(logging.WARNING):
+            dev = AcceleratorSpec.parse("true:tpu").pick_device()
+        assert dev.platform == "cpu"
+        assert any("tpu not available" in r.getMessage()
+                   for r in caplog.records)
+
+    def test_on_tpu_asks_the_device(self):
+        import jax
+        from types import SimpleNamespace
+
+        from nnstreamer_tpu.core.hw import on_tpu
+
+        assert not on_tpu(jax.devices()[0])
+        assert on_tpu(SimpleNamespace(platform="tpu"))
+
+
+class TestCompileCache:
+    """core/hw.enable_compile_cache: placed from outside or at one fixed
+    path — the path is part of the cache key, so it must never move."""
+
+    @pytest.fixture
+    def restore(self):
+        import jax
+
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_placement_is_left_alone(self, monkeypatch, restore):
+        import jax
+
+        from nnstreamer_tpu.core.hw import enable_compile_cache
+
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        assert enable_compile_cache() == "/placed/outside"
+        assert jax.config.jax_compilation_cache_dir is None  # untouched
+
+    def test_default_is_fixed_path_in_checkout(self, monkeypatch, restore):
+        import os
+
+        import jax
+
+        import nnstreamer_tpu
+        from nnstreamer_tpu.core.hw import enable_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(nnstreamer_tpu.__file__)))
+        want = os.path.join(checkout, ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert enable_compile_cache() == want  # same path every call
+
+
+def test_chip_smoke_refuses_a_cpu():
+    """`JAX_PLATFORMS=cpu python chip_smoke.py` must fail fast — before
+    compiling anything — and print no result line."""
+    import os
+    import subprocess
+    import sys
+    import time
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    t0 = time.monotonic()
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py")], cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode not in (0, None)
+    assert out.stdout.strip() == ""  # no JSON, no leg ran
+    assert "no TPU" in out.stderr
+    assert time.monotonic() - t0 < 60

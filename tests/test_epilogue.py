@@ -133,6 +133,56 @@ class TestKernels:
         np.testing.assert_array_equal(np.asarray(s), np.ones((4, 1), np.float32))
 
 
+def _tpu_module_text(fn, *specs):
+    """StableHLO of ``fn`` lowered for the TPU platform from this CPU
+    host — runs Pallas's TPU lowering (not Mosaic's own passes, which
+    only the chip runs: chip_smoke.py)."""
+    return jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *specs).mlir_module()
+
+
+_S = jax.ShapeDtypeStruct
+_PAL = np.zeros((256, 4), np.uint8)
+#: every epilogue kernel at the shape its production caller uses
+_TPU_LOWERING_CASES = {
+    "nms_sweep_k256": (
+        lambda *a: ep.nms_sweep(*a, iou_threshold=0.5, threshold=0.5),
+        [_S((256,), jnp.float32)] * 5),
+    "class_reduce_1917x90": (ep.class_reduce, [_S((1917, 90), jnp.float32)]),
+    "segment_colorize_257x257x21": (
+        lambda x: ep.segment_colorize(x, _PAL),
+        [_S((257, 257, 21), jnp.float32)]),
+    "segment_colorize_pre_argmaxed": (
+        lambda x: ep.segment_colorize(x, _PAL, pre_argmaxed=True),
+        [_S((257, 257), jnp.float32)]),
+    **{f"dequant_gelu_requant_f{f}_r{r}": (
+        ep.dequant_gelu_requant,
+        [_S((r, f), jnp.int32), _S((r, 1), jnp.float32),
+         _S((f,), jnp.float32)])
+       for f in (4 * 1024, 4 * 4096) for r in (8, 2048)},
+    "dequant_gelu_requant_slot_vmap": (
+        jax.vmap(ep.dequant_gelu_requant, in_axes=(0, 0, None)),
+        [_S((8, 1, 4096), jnp.int32), _S((8, 1, 1), jnp.float32),
+         _S((4096,), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TPU_LOWERING_CASES))
+def test_kernel_lowers_for_tpu(case):
+    """A Pallas-TPU lowering refusal (e.g. a value-level dynamic_slice)
+    is caught here without a chip, and the TPU program really carries
+    the Mosaic call rather than the jnp reference."""
+    fn, specs = _TPU_LOWERING_CASES[case]
+    assert "tpu_custom_call" in _tpu_module_text(fn, *specs)
+
+
+def test_kernel_lowers_reference_off_tpu():
+    """The same entry point placed on a CPU lowers the jnp reference:
+    selection follows the lowering platform, not the process default."""
+    fn, specs = _TPU_LOWERING_CASES["nms_sweep_k256"]
+    assert "tpu_custom_call" not in jax.jit(fn).lower(*specs).as_text()
+
+
 class TestMlpMatmul:
     def test_quantized_fused_matches_unfused(self):
         from nnstreamer_tpu.ops import int8

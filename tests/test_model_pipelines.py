@@ -334,32 +334,6 @@ def test_batched_serving_frames_per_tensor(tmp_path):
         assert len(b.meta["label_scores"]) == batch
 
 
-def test_synthesized_init_matches_flax_shapes():
-    """Accelerator-path init (eval_shape + host synthesis) must produce the
-    exact param pytree structure/shapes/dtypes flax init would."""
-    import jax
-
-    from nnstreamer_tpu.models.mobilenet_v2 import MobileNetV2
-    from nnstreamer_tpu.models.zoo import synthesize_variables
-
-    model = MobileNetV2(num_classes=5, width=0.25, dtype=np.float32)
-    key = jax.random.PRNGKey(0)
-    dummy = np.zeros((1, 32, 32, 3), np.float32)
-    real = model.init(key, dummy)
-    shapes = jax.eval_shape(lambda k: model.init(k, dummy), key)
-    synth = synthesize_variables(shapes, 0)
-    real_flat = jax.tree_util.tree_flatten_with_path(real)[0]
-    synth_flat = jax.tree_util.tree_flatten_with_path(synth)[0]
-    assert len(real_flat) == len(synth_flat)
-    for (rp, rv), (sp, sv) in zip(real_flat, synth_flat):
-        assert rp == sp
-        assert np.shape(rv) == np.shape(sv)
-        assert np.asarray(rv).dtype == np.asarray(sv).dtype
-    # kernels have sane scale (not all-zero), norms are identity-ish
-    out = jax.jit(model.apply)(synth, dummy)
-    assert np.all(np.isfinite(np.asarray(out)))
-
-
 def test_get_model_memoizes_pure_specs(tmp_path):
     from nnstreamer_tpu.models.zoo import get_model
 

@@ -93,29 +93,6 @@ def test_router_metrics_collection():
     assert counts.sum() == 16  # every token routed
 
 
-def test_synthesized_init_has_nonzero_experts():
-    """The accelerator-backend init path (eval_shape + synthesize) must not
-    zero the router/expert stacks — that would silently make every MoE
-    layer a no-op on real TPU serving."""
-    from nnstreamer_tpu.models.moe_transformer import MoEStreamTransformer
-    from nnstreamer_tpu.models.zoo import synthesize_variables
-
-    model = MoEStreamTransformer(layers=2, dim=32, heads=4, n_experts=4,
-                                 dtype=jnp.float32)
-    shapes = jax.eval_shape(
-        lambda k: model.init(k, jnp.zeros((1, 16, 32), jnp.float32)),
-        jax.random.PRNGKey(0))
-    synth = synthesize_variables(shapes, 0)
-    moe = synth["params"]["moe_block_1"]
-    for name in ("router", "w1", "w2"):
-        arr = np.asarray(moe[name])
-        assert np.abs(arr).max() > 0, f"{name} synthesized to zeros"
-    out = model.apply({"params": synth["params"]},
-                      jnp.asarray(np.random.default_rng(0).normal(
-                          size=(1, 16, 32)).astype(np.float32)))
-    assert np.isfinite(np.asarray(out)).all()
-
-
 def test_ep_infer_rejects_indivisible_batch():
     from nnstreamer_tpu.models.moe_transformer import make_ep_infer
     from nnstreamer_tpu.models.zoo import get_model

@@ -136,3 +136,36 @@ def test_flash_bf16_inputs_tolerance():
         block_q=16, block_k=16)).astype(np.float32)
     ref = np.asarray(reference_attention(qf, kf, vf, causal=True))
     np.testing.assert_allclose(out, ref, rtol=5e-2, atol=3e-2)
+
+
+def _flash_residuals(q, k, v):
+    from nnstreamer_tpu.ops.pallas.flash_attention import flash_attention
+
+    return flash_attention(q, k, v, return_residuals=True)
+
+
+def _flash(q, k, v):
+    from nnstreamer_tpu.ops.pallas.flash_attention import flash_attention
+
+    return flash_attention(q, k, v)
+
+
+@pytest.mark.parametrize("fn,n_args,shape,dtype", [
+    (_flash, 3, (8, 32, 2048, 128), "bfloat16"),  # round-5 roofline shape
+    (_flash, 3, (1, 16, 8192, 64), "bfloat16"),   # long context, padded d
+    (_flash_residuals, 3, (1, 16, 8192, 64), "bfloat16"),
+    (pp.normalize_u8, 1, (1, 224, 224, 3), "uint8"),
+    (lambda x: pp.quantize_affine(x, 1 / 127.5, 128), 1,
+     (1, 224, 224, 3), "float32"),
+])
+def test_kernel_lowers_for_tpu(fn, n_args, shape, dtype):
+    """Lower for the TPU platform from this CPU host at the production
+    shapes: catches a Pallas-TPU lowering refusal without a chip, and
+    pins that the TPU program carries the Mosaic call (Mosaic's own
+    passes only run on the chip: chip_smoke.py)."""
+    import jax
+
+    spec = jax.ShapeDtypeStruct(shape, dtype)
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *[spec] * n_args).mlir_module()
+    assert "tpu_custom_call" in text

@@ -367,15 +367,25 @@ class TestExporterProfileRoute:
             assert "aggregator" in ei.value.read().decode()
 
 
+#: stands in for a chip: the peak tables key on ``device_kind`` alone
+_V5E = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+
+
 class TestEngineGauges:
-    def test_lm_engine_run_exposes_mfu_family(self, prof, global_metrics):
+    def test_lm_engine_run_exposes_mfu_family(self, prof, global_metrics,
+                                              monkeypatch):
         """Acceptance: after an LMEngine run with profiling on,
-        /metrics carries the nnstpu_profile_mfu family for engine=lm."""
+        /metrics carries the nnstpu_profile_mfu family for engine=lm —
+        on a device the peak tables know."""
         from nnstreamer_tpu.models import causal_lm
         from nnstreamer_tpu.serving import LMEngine
 
         obs_metrics.enable()
         profile.enable()
+        monkeypatch.setattr(  # this CPU has no peak: borrow a v5e's
+            profile.Profiler, "_peaks",
+            lambda self: (probes.chip_peak_flops(_V5E),
+                          probes.chip_peak_hbm_bw(_V5E)))
         params = causal_lm.init_causal_lm(
             jax.random.PRNGKey(7), 97, 32, 4, 2, 64)
         eng = LMEngine(params, 4, 64, n_slots=2, chunk=4)
@@ -394,30 +404,49 @@ class TestEngineGauges:
             if ln.startswith('nnstpu_profile_mfu_ratio{engine="lm"}')))
         assert 0.0 <= mfu <= 1.0
 
+    def test_no_peak_no_utilization_gauge(self, prof, global_metrics):
+        """A device outside the peak tables (this CPU) reports achieved
+        FLOP/s but no MFU or roofline ratio: there is no peak to divide
+        by, and a made-up one would publish a CPU run as utilization."""
+        obs_metrics.enable()
+        profile.enable()
+        profile.profiler()._update_util("nopeak", 1e9, 1e6, 0.5)
+        with start_exporter(port=0) as exp:
+            text = urllib.request.urlopen(exp.url, timeout=5) \
+                .read().decode()
+        assert 'nnstpu_profile_achieved_flops{engine="nopeak"}' in text
+        assert 'nnstpu_profile_mfu_ratio{engine="nopeak"}' not in text
+        assert 'nnstpu_profile_roofline_ratio{engine="nopeak"}' not in text
+        assert "mfu=" not in profile.profiler().report()
+
 
 class TestProbesRoofline:
     def test_peak_tables_and_ridge(self, prof):
-        dev = jax.devices()[0]
-        assert probes.chip_peak_flops(dev) > 0
-        assert probes.chip_peak_hbm_bw(dev) > 0
-        ridge = probes.ridge_intensity(dev)
-        assert ridge == pytest.approx(
-            probes.chip_peak_flops(dev) / probes.chip_peak_hbm_bw(dev))
-        assert ridge > 0
+        assert probes.chip_peak_flops(_V5E) == 197e12
+        assert probes.chip_peak_hbm_bw(_V5E) == 819e9
+        assert probes.ridge_intensity(_V5E) == pytest.approx(
+            197e12 / 819e9)
+
+    def test_unknown_device_is_an_error(self, prof):
+        """No default peak: utilization on a device the tables do not
+        list (the test CPU) raises instead of borrowing v5e's numbers."""
+        with pytest.raises(probes.UnknownDeviceError, match="no peak"):
+            probes.chip_peak_flops(jax.devices()[0])
+        with pytest.raises(probes.UnknownDeviceError):
+            probes.mfu(1e6, 30.0, jax.devices()[0])
 
     def test_pipeline_util_is_honest_alias_and_bounded(self, prof):
         """Satellite: the renamed bench lane's backing helper. The old
         adaptive_batch16_mfu=0.000965 reading was this quantity —
         end-to-end utilization, tiny because the chip idles between
         frames — not device MFU."""
-        dev = jax.devices()[0]
-        assert probes.pipeline_util(1e6, 30.0, dev) == pytest.approx(
-            probes.mfu(1e6, 30.0, dev))
+        assert probes.pipeline_util(1e6, 30.0, _V5E) == pytest.approx(
+            probes.mfu(1e6, 30.0, _V5E))
         # a pipeline can never use more than the chip: bounded by 1
         # for any rate up to peak/flops_per_frame
-        peak = probes.chip_peak_flops(dev)
-        assert 0.0 < probes.pipeline_util(1e6, 30.0, dev) <= 1.0
-        assert probes.pipeline_util(1e6, peak / 1e6, dev) \
+        peak = probes.chip_peak_flops(_V5E)
+        assert 0.0 < probes.pipeline_util(1e6, 30.0, _V5E) <= 1.0
+        assert probes.pipeline_util(1e6, peak / 1e6, _V5E) \
             == pytest.approx(1.0)
 
 
