@@ -31,7 +31,9 @@ Exposition: ``GET /debug/traces`` (summaries, ``?min_ms=`` filter),
 ``GET /debug/traces/<trace_id>`` (full span tree) and
 ``GET /debug/pipeline`` (live topology + per-element span stats, the
 DOT-dump analog) on the obs exporter. ``nns-launch --trace`` and
-``PipelineTracer`` consume the same store. Stdlib only.
+``PipelineTracer`` consume the same store. Stdlib only, but for
+``phase``, which writes through ``jax.profiler.TraceAnnotation`` where
+jax can be imported (looked up on first use, never at import).
 """
 
 from __future__ import annotations
@@ -48,7 +50,7 @@ __all__ = [
     "Span", "SpanContext", "SpanStore", "CTX_META_KEY", "ROOT_META_KEY",
     "TRACE_META_KEY", "ctx_from_wire", "current_context", "disable",
     "enable", "enabled", "element_stats", "element_stats_report",
-    "live_pipelines", "pipeline_topology", "register_pipeline",
+    "live_pipelines", "phase", "pipeline_topology", "register_pipeline",
     "stamp_buffer", "start_span", "store",
 ]
 
@@ -639,6 +641,92 @@ def disable() -> None:
 def start_span(name: str, parent: Optional[SpanContext] = None,
                attrs: Optional[Dict[str, Any]] = None):
     return _STORE.start_span(name, parent=parent, attrs=attrs)
+
+
+#: ``jax.profiler.TraceAnnotation`` once a phase has looked for it; False
+#: where jax cannot be imported
+_ANNOTATION: Any = None
+
+
+def _annotation() -> Any:
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except ImportError:
+            _ANNOTATION = False
+    return _ANNOTATION
+
+
+class phase:
+    """One host phase of an engine iteration, timed once and written
+    three ways. ``with phase(stats, "serving.decode_wait", parent=step)``
+    reads ``time.monotonic_ns()`` on entry and on exit (``start_ns`` /
+    ``end_ns``: callers that report the same interval elsewhere take
+    these stamps and read no clock of their own) and
+
+    * always adds the elapsed seconds to ``stats["decode_wait_s"]`` (the
+      name without its layer, plus ``_s``);
+    * always brackets the body with a ``jax.profiler.TraceAnnotation``:
+      a ``Span``'s stamps are ``monotonic_ns`` and a profiler trace's are
+      relative to its session, so only an event written through the
+      profiler lies on the device ops' timeline. It costs a few hundred
+      nanoseconds while no session is live and appears in the
+      ``.xplane.pb`` of whichever session is;
+    * while tracing is enabled records a ``Span``: a phase without a
+      ``parent`` roots a trace of its own (thinned by ``sample_every``
+      like any buffer-rate root), one with a parent is its child, and
+      records only if the parent did. Tracing off makes no ``Span``.
+    """
+
+    __slots__ = ("name", "start_ns", "end_ns", "_stats", "_key", "_parent",
+                 "_note", "_span")
+
+    def __init__(self, stats: Dict[str, Any], name: str,
+                 parent: Optional["phase"] = None) -> None:
+        self.name = name
+        self._stats = stats
+        self._key = name.partition(".")[2] + "_s"
+        self._parent = parent
+        self._note = None
+        self._span: Optional[Span] = None
+        self.start_ns = self.end_ns = 0
+
+    def set_attribute(self, key: str, value: Any) -> None:
+        """On the ``Span``, where one is being recorded."""
+        if self._span is not None:
+            self._span.attrs[key] = value
+
+    def __enter__(self) -> "phase":
+        if _STORE._enabled:
+            parent = self._parent
+            if parent is None:
+                if _STORE.should_sample():
+                    self._span = _STORE.start_span(self.name)
+            elif parent._span is not None:
+                self._span = _STORE.start_span(
+                    self.name, parent=parent._span.context)
+        self.start_ns = time.monotonic_ns()
+        note = _annotation()
+        if note:
+            self._note = note(self.name)
+            self._note.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        self.end_ns = time.monotonic_ns()
+        self._stats[self._key] += (self.end_ns - self.start_ns) / 1e9
+        if self._span is not None:
+            if exc_type is not None:
+                self._span.attrs["error"] = True
+            self._span.end()
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e9
 
 
 def stamp_buffer(buf: Any, span_store: SpanStore, source: str):

@@ -51,6 +51,7 @@ engine-backed worker, generated sequences flowing back per request.
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
 import time
@@ -65,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import tune as _tune
+from ..core.log import logger
 from ..models import causal_lm
 from ..obs import diag as _diag
 from ..obs import events as _events
@@ -78,6 +80,9 @@ from ..ops.int8 import stack_shape
 from ..resilience import policy as _rp
 from . import sampling
 from .kv_cache import PagedKVCache
+
+
+log = logger("serving")
 
 
 def _env_int(name: str) -> Optional[int]:
@@ -104,10 +109,22 @@ ROLES = ("unified", "prefill", "decode")
 #: re-prefill absorb path instead of a page shipment)
 SESSION_PATHS_LIMIT = 256
 
+#: the phases of one iteration (``serving.<name>`` spans, ``<name>_s``
+#: counters; docs/observability.md has the table of what each covers)
+STEP_PHASES = ("step", "admit", "admit_host", "prefill_dispatch",
+               "slot_insert", "first_token_wait", "decode_dispatch",
+               "decode_wait", "retire")
+_PHASE_KEYS = tuple(f"{name}_s" for name in STEP_PHASES)
+
 #: weak registry of every constructed engine — `nns-launch` walks it at
 #: exit to print per-engine KV summaries without threading a handle
 #: through the pipeline graph
 _LIVE_ENGINES: "weakref.WeakSet[LMEngine]" = weakref.WeakSet()
+
+
+def _copy_step(rec: Dict[str, Any]) -> Dict[str, Any]:
+    return {**rec, "phases": dict(rec["phases"]),
+            "admitted": list(rec["admitted"]), "gc": list(rec["gc"])}
 
 
 def live_engines() -> List["LMEngine"]:
@@ -561,9 +578,27 @@ class LMEngine:
         # not in the slots x steps = kept + wasted chunk invariant
         self.stats = {"prefills": 0, "decode_steps": 0,
                       "slot_steps": 0, "wasted_slot_steps": 0,
-                      "tokens_out": 0, "wall_s": 0.0,
+                      "tokens_out": 0,
                       "spec_iterations": 0, "spec_drafted": 0,
-                      "spec_accepted": 0}
+                      "spec_accepted": 0,
+                      # iterations run and decode dispatches made (a
+                      # chunk or a verify window)
+                      "iterations": 0, "chunks": 0,
+                      # wall of the dispatch phases that used an
+                      # executable for the first time (compile, or its
+                      # load from the cache)
+                      "first_use_s": 0.0,
+                      # submit -> slot granted, summed and the longest
+                      "admission_wait_s": 0.0,
+                      "admission_wait_max_s": 0.0}
+        # <phase>_s: seconds in each phase, added by obs/tracing.phase
+        self.stats.update((key, 0.0) for key in _PHASE_KEYS)
+        # one record an iteration: the last STEP_RING of them, and the
+        # STEP_KEEP_SLOWEST slowest of the engine's life that used no
+        # executable for the first time (a compile is slow by design)
+        self._steps: deque = deque(maxlen=self.STEP_RING)
+        self._slow_steps: List[Dict[str, Any]] = []
+        self._steps_lock = threading.Lock()
         # sched.DeviceEngine tenancy (enroll()/unenroll()); None means
         # step_iteration runs direct — the usual zero-overhead gate
         self._sched_tenant = None
@@ -575,6 +610,13 @@ class LMEngine:
     #: distinguishes engine kinds in the metric series; the TP engine
     #: overrides to "tp"
     _engine_label = "lm"
+
+    #: iterations kept by recent_steps() / slowest_steps()
+    STEP_RING = 1024
+    STEP_KEEP_SLOWEST = 8
+    #: an iteration that compiled nothing and lasted longer than this is
+    #: logged as a stall with its record (seconds)
+    STEP_STALL_S = 1.0
 
     def _init_metrics(self) -> None:
         """Register the serving metric families (obs subsystem). Handles
@@ -609,7 +651,9 @@ class LMEngine:
             "nnstpu_serving_prefill_compiles_total",
             "First-use prefill buckets (each is one XLA compile)",
             ("engine", "bucket"))
-        self._seen_buckets: set = set()
+        #: executables this engine has used: prefill bucket keys, and
+        #: ("chunk", n) / ("verify", w) for the decode programs
+        self._seen_programs: set = set()
         # gauges sample the MOST RECENTLY constructed engine per label
         ref = weakref.ref(self)
         reg.gauge(
@@ -645,7 +689,7 @@ class LMEngine:
             return {
                 "queued": len(eng._queue),
                 "active": sum(r is not None for r in eng._slot_req),
-                "warmed": bool(eng._seen_buckets),
+                "warmed": bool(eng._seen_programs),
                 "oldest_wait_s": (time.monotonic() - oldest)
                 if oldest is not None else 0.0,
             }
@@ -656,7 +700,7 @@ class LMEngine:
         _health.add_readiness(
             f"engine:{lbl}",
             lambda: (lambda e: None if e is None
-                     else bool(e._seen_buckets))(ref()))
+                     else bool(e._seen_programs))(ref()))
 
     def _alloc_slot_caches(self, n_layers: int, hd: int):
         """Zero per-slot KV stores, (S, L·H, max_len, hd). Overridden by
@@ -808,6 +852,49 @@ class LMEngine:
         return len(self._queue) + sum(
             r is not None for r in self._slot_req)
 
+    def progress(self, rid: int) -> Optional[List[int]]:
+        """The tokens generated so far for request ``rid``, running or
+        finished (a copy; empty while it is queued); None for an id this
+        engine never handed out."""
+        done = self._finished.get(rid)
+        if done is not None:
+            return list(done)
+        slot = self.slot_of(rid)
+        if slot is not None:
+            return list(self._slot_req[slot].out)
+        return [] if any(r.rid == rid for r in self._queue) else None
+
+    def slot_of(self, rid: int) -> Optional[int]:
+        """The slot request ``rid`` occupies now, or None (queued,
+        finished or unknown). The slot a finished request took is in
+        the step record of the iteration that admitted it."""
+        for slot, req in enumerate(self._slot_req):
+            if req is not None and req.rid == rid:
+                return slot
+        return None
+
+    def recent_steps(self) -> List[Dict[str, Any]]:
+        """The records of the last ``STEP_RING`` iterations, oldest
+        first (copies). A record: ``iteration`` (its ordinal, the value
+        of ``stats["iterations"]``), ``start_ns`` (``monotonic_ns``),
+        ``wall_s``, ``cpu_s`` (this thread's CPU time: wall far above
+        it means the thread was blocked or descheduled, wall about equal
+        means Python ran, a collection included), ``phases`` (seconds
+        by phase name), ``admitted`` ([rid, slot] pairs), ``chunk``
+        (decode steps dispatched, 0 for none), ``active`` and ``queued``
+        (slots decoding, requests still waiting), ``gc`` (collections
+        per generation that fell in it) and ``first_use`` (whether an
+        executable was used for the first time)."""
+        with self._steps_lock:
+            return [_copy_step(r) for r in self._steps]
+
+    def slowest_steps(self) -> List[Dict[str, Any]]:
+        """The ``STEP_KEEP_SLOWEST`` slowest iterations of the engine's
+        life that were not first-use iterations, slowest first."""
+        with self._steps_lock:
+            return [_copy_step(r) for r in sorted(
+                self._slow_steps, key=lambda r: -r["wall_s"])]
+
     def step_iteration(self) -> bool:
         """One scheduler iteration: admit into free slots, then one
         decode chunk. Returns True while work remains. When enrolled as
@@ -825,13 +912,51 @@ class LMEngine:
 
     def _step_direct(self) -> bool:
         self._hc.beat()  # watchdog liveness: the scheduler is turning
-        t0 = time.monotonic()
-        if self._kv_imports:  # truthiness: free when nothing arrived
-            self.drain_kv_imports()
-        self._admit()
-        self._decode()
-        self.stats["wall_s"] += time.monotonic() - t0
+        st = self.stats
+        st["iterations"] += 1
+        rec: Dict[str, Any] = {
+            "iteration": st["iterations"], "admitted": [], "chunk": 0,
+            "active": 0, "first_use": False}
+        before = [st[key] for key in _PHASE_KEYS]
+        gc0 = [g["collections"] for g in gc.get_stats()]
+        cpu0 = time.thread_time_ns()
+        with _tracing.phase(st, "serving.step") as step:
+            step.set_attribute("iteration", rec["iteration"])
+            if self._kv_imports:  # truthiness: free when nothing arrived
+                self.drain_kv_imports()
+            self._admit(step, rec)
+            self._decode(step, rec)
+        rec["cpu_s"] = (time.thread_time_ns() - cpu0) / 1e9
+        rec["start_ns"] = step.start_ns
+        rec["wall_s"] = step.seconds
+        rec["phases"] = {name: st[key] - b for name, key, b
+                         in zip(STEP_PHASES, _PHASE_KEYS, before)}
+        rec["queued"] = len(self._queue)
+        rec["gc"] = [g["collections"] - g0
+                     for g, g0 in zip(gc.get_stats(), gc0)]
+        self._keep_step(rec)
         return self.pending() > 0
+
+    def _keep_step(self, rec: Dict[str, Any]) -> None:
+        slow = self._slow_steps
+        with self._steps_lock:
+            self._steps.append(rec)
+            if not rec["first_use"]:
+                slow.append(rec)
+                if len(slow) > self.STEP_KEEP_SLOWEST:
+                    slow.remove(min(slow, key=lambda r: r["wall_s"]))
+        if rec["wall_s"] > self.STEP_STALL_S and not rec["first_use"]:
+            # the engine names its own stalled phase: which one stood
+            # still, whether the thread ran (cpu_s), and whether a
+            # collection fell in it (gc, by generation)
+            log.warning("%s: iteration %d stood still for %.3f s: %s",
+                        self._engine_label, rec["iteration"],
+                        rec["wall_s"], rec)
+            _events.record(
+                "serving.step_stall",
+                f"{self._engine_label}: iteration {rec['iteration']} "
+                f"took {rec['wall_s']:.3f} s", severity="warning",
+                engine=self._engine_label, step=_copy_step(rec))
 
     # -- sched.DeviceEngine tenancy ---------------------------------------- #
     def enroll(self, scheduler: Any, *, name: Optional[str] = None,
@@ -1044,133 +1169,159 @@ class LMEngine:
 
     # -- scheduler internals ---------------------------------------------- #
 
-    def _admit(self) -> None:
+    def _admit(self, step: "_tracing.phase", rec: Dict[str, Any]) -> None:
         if self.gang and any(r is not None for r in self._slot_req):
             return  # static batching: wait for the whole gang to finish
-        for slot in range(self.n_slots):
-            if self._slot_req[slot] is not None or not self._queue:
-                continue
-            req = self._queue.popleft()
-            while req is not None and req.deadline is not None \
-                    and req.deadline.expired():
-                # expired while queued: shed and give the slot to the
-                # next request that can still meet its deadline
-                self._shed_request(req, "deadline expired in queue")
-                req = self._queue.popleft() if self._queue else None
-            if req is None:
-                continue
-            plan = None
-            if self._kv is not None:
-                plan = self._paged_plan(req)
-                if plan is None:
-                    # the pool cannot cover this request's page
-                    # reservation yet: requeue at the FRONT (FIFO — no
-                    # starvation by smaller latecomers) and stop
-                    # admitting; pages free as active streams retire
-                    self._queue.appendleft(req)
-                    break
-            if req.wait_span is not None:
-                req.wait_span.end()
-            t = int(req.prompt.size)
-            hit = self._paged_admit(slot, req, plan) \
-                if self._kv is not None else 0
-            ts = t - hit  # suffix tokens the prefill must still compute
-            tb = self._bucket(t) if self._kv is None \
-                else min(self._bucket(ts), self._m_slot)
-            padded = np.zeros((1, tb), np.int32)
-            padded[0, :ts] = req.prompt[hit:]
-            skey = sampling.seed_key(req.seed)
-            temp = jnp.float32(req.temperature)
-            tk, tp = jnp.int32(req.top_k), jnp.float32(req.top_p)
-            # paged executables are distinct from contiguous ones (and
-            # the prefix-hit suffix prefill from the no-hit install), so
-            # they warm separate bucket entries / compile counters
-            bkey: Any = tb if self._kv is None else ("kv", hit > 0, tb)
-            blabel = str(tb) if self._kv is None or not hit else f"kv{tb}"
-            first_use = bkey not in self._seen_buckets
-            pspan = cspan = _tracing.NOOP_SPAN
-            if req.span is not None:
+        if not self._queue or all(r is not None for r in self._slot_req):
+            return
+        st = self.stats
+        # every stream stands still for as long as this phase lasts
+        with _tracing.phase(st, "serving.admit", parent=step) as admit:
+            for slot in range(self.n_slots):
+                if self._slot_req[slot] is not None or not self._queue:
+                    continue
+                with _tracing.phase(st, "serving.admit_host",
+                                    parent=admit) as host:
+                    req = self._queue.popleft()
+                    while req is not None and req.deadline is not None \
+                            and req.deadline.expired():
+                        # expired while queued: shed and give the slot to
+                        # the next request that can still meet its deadline
+                        self._shed_request(req, "deadline expired in queue")
+                        req = self._queue.popleft() if self._queue else None
+                    if req is None:
+                        continue
+                    plan = None
+                    if self._kv is not None:
+                        plan = self._paged_plan(req)
+                        if plan is None:
+                            # the pool cannot cover this request's page
+                            # reservation yet: requeue at the FRONT (FIFO —
+                            # no starvation by smaller latecomers) and stop
+                            # admitting; pages free as active streams retire
+                            self._queue.appendleft(req)
+                            break
+                    if req.wait_span is not None:
+                        req.wait_span.end()
+                    waited = host.start_ns / 1e9 - req.t_submit
+                    st["admission_wait_s"] += waited
+                    if waited > st["admission_wait_max_s"]:
+                        st["admission_wait_max_s"] = waited
+                    t = int(req.prompt.size)
+                    hit = self._paged_admit(slot, req, plan) \
+                        if self._kv is not None else 0
+                    ts = t - hit  # suffix tokens the prefill must compute
+                    tb = self._bucket(t) if self._kv is None \
+                        else min(self._bucket(ts), self._m_slot)
+                    padded = np.zeros((1, tb), np.int32)
+                    padded[0, :ts] = req.prompt[hit:]
+                    skey = sampling.seed_key(req.seed)
+                    temp = jnp.float32(req.temperature)
+                    tk, tp = jnp.int32(req.top_k), jnp.float32(req.top_p)
+                    sl = jnp.int32(slot)
+                    # paged executables are distinct from contiguous ones
+                    # (and the prefix-hit suffix prefill from the no-hit
+                    # install), so they warm separate bucket entries /
+                    # compile counters
+                    bkey: Any = tb if self._kv is None \
+                        else ("kv", hit > 0, tb)
+                    blabel = str(tb) if self._kv is None or not hit \
+                        else f"kv{tb}"
+                    first_use = bkey not in self._seen_programs
+                    pspan = cspan = _tracing.NOOP_SPAN
+                    if req.span is not None:
+                        if first_use:
+                            # the jit call returns only after trace+compile
+                            # on a new static shape; the dispatch itself is
+                            # async, so ending right after _prefill_into
+                            # bounds the compile
+                            cspan = _tracing.start_span(
+                                "serving.compile", parent=req.span.context,
+                                attrs={"bucket": tb, "kernel": "prefill"})
+                        pspan = _tracing.start_span(
+                            "serving.prefill", parent=req.span.context,
+                            attrs={"bucket": tb, "slot": slot})
+                        if req.session is not None \
+                                and req.session in self._restored_sessions:
+                            # first prefill after a checkpoint splice — it
+                            # rides the imported radix pages; diag bills it
+                            # as restore (cheap) rather than re_prefill
+                            self._restored_sessions.discard(req.session)
+                            pspan.set_attribute("restore", True)
+                        elif req.session is not None \
+                                and req.session in self._reprefill_sessions:
+                            # post-absorb recompute, not fresh work — the
+                            # diag critical path bills this span as
+                            # re_prefill
+                            self._reprefill_sessions.discard(req.session)
+                            pspan.set_attribute("re_prefill", True)
+                    # obs/quality confidence tap: one None check selects
+                    # the conf-variant prefill, which also returns the
+                    # first-token logits' (entropy, top1, margin) for the
+                    # retire path
+                    want_conf = _quality.QUALITY_HOOK is not None
+                # returns once the prefill is enqueued (after trace and
+                # compile on a first use), not when the device has run it
+                with _tracing.phase(st, "serving.prefill_dispatch",
+                                    parent=admit) as pd:
+                    if self._kv is None:
+                        first = self._prefill_into(
+                            slot, padded, t, skey, temp, tk, tp,
+                            want_conf=want_conf)
+                    else:
+                        first = self._prefill_paged(
+                            slot, padded, hit, ts, skey, temp, tk, tp,
+                            want_conf=want_conf)
+                if want_conf:
+                    first, req.conf = first
+                cspan.end()
+                st["prefills"] += 1
+                lbl = self._engine_label
+                self._m_prefills.labels(lbl, blabel).inc()
                 if first_use:
-                    # the jit call returns only after trace+compile on a
-                    # new static shape; the dispatch itself is async, so
-                    # ending right after _prefill_into bounds the compile
-                    cspan = _tracing.start_span(
-                        "serving.compile", parent=req.span.context,
-                        attrs={"bucket": tb, "kernel": "prefill"})
-                pspan = _tracing.start_span(
-                    "serving.prefill", parent=req.span.context,
-                    attrs={"bucket": tb, "slot": slot})
-                if req.session is not None \
-                        and req.session in self._restored_sessions:
-                    # first prefill after a checkpoint splice — it
-                    # rides the imported radix pages; diag bills it as
-                    # restore (cheap) rather than re_prefill (full)
-                    self._restored_sessions.discard(req.session)
-                    pspan.set_attribute("restore", True)
-                elif req.session is not None \
-                        and req.session in self._reprefill_sessions:
-                    # post-absorb recompute, not fresh work — the diag
-                    # critical path bills this span as re_prefill
-                    self._reprefill_sessions.discard(req.session)
-                    pspan.set_attribute("re_prefill", True)
-            tp0 = time.monotonic_ns() \
-                if (_profile.ENGINE_HOOK is not None
-                    or _slo.ENGINE_SLO_HOOK is not None) else 0
-            # obs/quality confidence tap: one None check selects the
-            # conf-variant prefill, which also returns the first-token
-            # logits' (entropy, top1, margin) for the retire path
-            want_conf = _quality.QUALITY_HOOK is not None
-            if self._kv is None:
-                first = self._prefill_into(
-                    slot, padded, t, skey, temp, tk, tp,
-                    want_conf=want_conf)
-            else:
-                first = self._prefill_paged(
-                    slot, padded, hit, ts, skey, temp, tk, tp,
-                    want_conf=want_conf)
-            if want_conf:
-                first, req.conf = first
-            cspan.end()
-            self.stats["prefills"] += 1
-            lbl = self._engine_label
-            self._m_prefills.labels(lbl, blabel).inc()
-            if first_use:
-                self._seen_buckets.add(bkey)
-                self._m_compiles.labels(lbl, blabel).inc()
-            self._m_streams.labels(lbl, "admitted").inc()
-            sl = jnp.int32(slot)
-            self._tokens = _slot_insert(
-                self._tokens, first.reshape(1, 1), sl)
-            self._skeys = _slot_insert(self._skeys, skey, sl)
-            self._temp = _slot_insert(self._temp, temp, sl)
-            self._topk = _slot_insert(self._topk, tk, sl)
-            self._topp = _slot_insert(self._topp, tp, sl)
-            req.out.append(int(first))
-            # TTFT after the int() materialization: the prefill dispatch
-            # is async, so the first token only exists for the caller
-            # once that D2H read completes
-            self._m_ttft.observe(time.monotonic() - req.t_submit)
-            pspan.end()  # prefill span covers through first-token D2H
-            if _profile.ENGINE_HOOK is not None:
-                # the int(first) D2H above synced the prefill, so the
-                # interval is device-bound; first_use intervals are
-                # compile-dominated and recorded as such
-                _profile.ENGINE_HOOK.record_engine(
-                    self, "prefill", tp0, time.monotonic_ns(),
-                    tokens=t, steps=1, compiled=first_use,
-                    bucket=blabel, slot=slot)
-            shook = _slo.ENGINE_SLO_HOOK
-            if shook is not None:
-                shook.record_engine_phase(
-                    self._slo_tenant(), "prefill",
-                    (time.monotonic_ns() - tp0) / 1e9)
-            if req.span is not None:
-                req.decode_span = _tracing.start_span(
-                    "serving.decode", parent=req.span.context,
-                    attrs={"slot": slot})
-            self._pos_host[slot] = t
-            self._slot_req[slot] = req
-            self._retire_if_done(slot, req)
+                    self._seen_programs.add(bkey)
+                    self._m_compiles.labels(lbl, blabel).inc()
+                    st["first_use_s"] += pd.seconds
+                    rec["first_use"] = True
+                self._m_streams.labels(lbl, "admitted").inc()
+                with _tracing.phase(st, "serving.slot_insert", parent=admit):
+                    self._tokens = _slot_insert(
+                        self._tokens, first.reshape(1, 1), sl)
+                    self._skeys = _slot_insert(self._skeys, skey, sl)
+                    self._temp = _slot_insert(self._temp, temp, sl)
+                    self._topk = _slot_insert(self._topk, tk, sl)
+                    self._topp = _slot_insert(self._topp, tp, sl)
+                # blocked on the device: the prefill and the inserts
+                # finishing, then one D2H
+                with _tracing.phase(st, "serving.first_token_wait",
+                                    parent=admit) as fw:
+                    req.out.append(int(first))
+                # TTFT after the int() materialization: the prefill
+                # dispatch is async, so the first token only exists for
+                # the caller once that D2H read completes
+                self._m_ttft.observe(fw.end_ns / 1e9 - req.t_submit)
+                pspan.end()  # prefill span covers through first-token D2H
+                if _profile.ENGINE_HOOK is not None:
+                    # the int(first) D2H above synced the prefill, so the
+                    # interval is device-bound; first_use intervals are
+                    # compile-dominated and recorded as such
+                    _profile.ENGINE_HOOK.record_engine(
+                        self, "prefill", pd.start_ns, fw.end_ns,
+                        tokens=t, steps=1, compiled=first_use,
+                        bucket=blabel, slot=slot)
+                shook = _slo.ENGINE_SLO_HOOK
+                if shook is not None:
+                    shook.record_engine_phase(
+                        self._slo_tenant(), "prefill",
+                        (fw.end_ns - pd.start_ns) / 1e9)
+                if req.span is not None:
+                    req.decode_span = _tracing.start_span(
+                        "serving.decode", parent=req.span.context,
+                        attrs={"slot": slot})
+                rec["admitted"].append([req.rid, slot])
+                self._pos_host[slot] = t
+                self._slot_req[slot] = req
+                self._retire_if_done(slot, req)
 
     def _prefill_into(self, slot: int, padded, true_len: int, skey,
                       temp, tk, tp, want_conf: bool = False):
@@ -1284,10 +1435,11 @@ class LMEngine:
                 pid = kv.lease_alloc(lease)
                 self._table_host[s, len(lease.pages) - 1] = pid
 
-    def _decode(self) -> None:
+    def _decode(self, step: "_tracing.phase", rec: Dict[str, Any]) -> None:
         active = [s for s, r in enumerate(self._slot_req) if r is not None]
         if not active:
             return
+        rec["active"] = len(active)
         # capacity headroom is PER-REQUEST capacity: max_len contiguous,
         # the kv_slot_pages * page_size view bound under paging. The old
         # max_len comparison would either let speculation NaN-poison a
@@ -1313,7 +1465,7 @@ class LMEngine:
             # served strictly better by chunked decode
             if self._kv is not None:
                 self._ensure_pages(active, self.spec_draft + 1)
-            self._decode_speculative(active)
+            self._decode_speculative(active, step, rec)
             return
         # cap the chunk so no ACTIVE slot decodes past cache capacity
         # (an overflowing row NaN-poisons itself by contract); submit()'s
@@ -1332,40 +1484,61 @@ class LMEngine:
             n = 1 << (n.bit_length() - 1)
         if self._kv is not None:
             self._ensure_pages(active, n)
-        t0 = time.monotonic()
-        outs = np.asarray(self._run_chunk(n))  # (S, n)
-        self._m_tok_lat.observe((time.monotonic() - t0) / n)
+        st = self.stats
+        rec["chunk"] = n
+        # the call returns once the chunk is enqueued (after trace and
+        # compile on a first use); the readback blocks until the device
+        # has run it, then copies (S, n) tokens to the host
+        with _tracing.phase(st, "serving.decode_dispatch",
+                            parent=step) as dd:
+            outs = self._run_chunk(n)
+        with _tracing.phase(st, "serving.decode_wait", parent=step) as dw:
+            outs = np.asarray(outs)
+        self._note_dispatch(("chunk", n), dd, rec)
+        self._m_tok_lat.observe((dw.end_ns - dd.start_ns) / 1e9 / n)
         if _profile.ENGINE_HOOK is not None:
             # np.asarray blocked on the chunk: wall ≈ device time; the
             # occupancy sample drives the Perfetto serving counter lane
             _profile.ENGINE_HOOK.record_engine(
-                self, "decode", int(t0 * 1e9), time.monotonic_ns(),
+                self, "decode", dd.start_ns, dw.end_ns,
                 tokens=n * len(active), steps=n, active=len(active),
                 queued=len(self._queue), slots=self.n_slots)
         shook = _slo.ENGINE_SLO_HOOK
         if shook is not None:
             shook.record_engine_phase(
-                self._slo_tenant(), "decode", time.monotonic() - t0)
-        for s in range(self.n_slots):
-            self._pos_host[s] += n  # device pos advances for EVERY slot
-        self.stats["decode_steps"] += n
-        self.stats["slot_steps"] += n * len(active)
-        for slot in active:
-            req = self._slot_req[slot]
-            for i in range(n):
-                if req.done or len(req.out) >= req.max_new:
-                    # invariant: slots x steps = kept tokens + wasted
-                    # (bench waste_frac reads this stat directly)
-                    self.stats["wasted_slot_steps"] += 1
-                    continue
-                tok = int(outs[slot, i])
-                req.out.append(tok)
-                if req.eos is not None and tok == req.eos:
-                    req.done = True  # tail of the chunk counts as waste
-            self._retire_if_done(slot, req)
-        # slot-steps spent by empty slots decoding garbage
-        self.stats["wasted_slot_steps"] += n * (
-            self.n_slots - len(active))
+                self._slo_tenant(), "decode",
+                (dw.end_ns - dd.start_ns) / 1e9)
+        # host bookkeeping with nothing on the device
+        with _tracing.phase(st, "serving.retire", parent=step):
+            for s in range(self.n_slots):
+                self._pos_host[s] += n  # device pos advances for EVERY slot
+            st["decode_steps"] += n
+            st["slot_steps"] += n * len(active)
+            for slot in active:
+                req = self._slot_req[slot]
+                for i in range(n):
+                    if req.done or len(req.out) >= req.max_new:
+                        # invariant: slots x steps = kept tokens + wasted
+                        # (bench waste_frac reads this stat directly)
+                        st["wasted_slot_steps"] += 1
+                        continue
+                    tok = int(outs[slot, i])
+                    req.out.append(tok)
+                    if req.eos is not None and tok == req.eos:
+                        req.done = True  # tail of the chunk counts as waste
+                self._retire_if_done(slot, req)
+            # slot-steps spent by empty slots decoding garbage
+            st["wasted_slot_steps"] += n * (self.n_slots - len(active))
+
+    def _note_dispatch(self, key: Any, dispatch: "_tracing.phase",
+                       rec: Dict[str, Any]) -> None:
+        """Count one decode dispatch, and its wall as first use where
+        this engine had not used that executable (``key``) before."""
+        self.stats["chunks"] += 1
+        if key not in self._seen_programs:
+            self._seen_programs.add(key)
+            self.stats["first_use_s"] += dispatch.seconds
+            rec["first_use"] = True
 
     def _run_chunk(self, n: int):
         """Run ``n`` decode steps over all slots, updating the carried
@@ -1402,7 +1575,9 @@ class LMEngine:
         return _verify_chunk(self.params, tokens_in, self._kc, self._vc,
                              self._pos, n_heads=self.n_heads)
 
-    def _decode_speculative(self, active: List[int]) -> None:
+    def _decode_speculative(self, active: List[int],
+                            step: "_tracing.phase",
+                            rec: Dict[str, Any]) -> None:
         """One speculative iteration: host-drafted prompt-lookup tokens
         verified in one dispatch; per-slot acceptance rolls pos back
         past rejected drafts (lm_verify_window's overwrite-before-
@@ -1411,50 +1586,56 @@ class LMEngine:
         drafts = np.zeros((self.n_slots, g), np.int32)
         for s in active:
             drafts[s] = self._draft_tokens(self._slot_req[s], g)
-        tokens_in = jnp.concatenate(
-            [self._tokens[:, 0], jnp.asarray(drafts)], axis=1)  # (S, 1+g)
-        t0 = time.monotonic()
-        (self._tokens, self._kc, self._vc, self._pos, outs, m) = \
-            self._run_verify(tokens_in)
-        outs = np.asarray(outs)
-        m = np.asarray(m)
+        st = self.stats
+        rec["chunk"] = g + 1
+        with _tracing.phase(st, "serving.decode_dispatch",
+                            parent=step) as dd:
+            tokens_in = jnp.concatenate(
+                [self._tokens[:, 0], jnp.asarray(drafts)],
+                axis=1)  # (S, 1+g)
+            (self._tokens, self._kc, self._vc, self._pos, outs, m) = \
+                self._run_verify(tokens_in)
+        with _tracing.phase(st, "serving.decode_wait", parent=step) as dw:
+            outs = np.asarray(outs)
+            m = np.asarray(m)
+        self._note_dispatch(("verify", g + 1), dd, rec)
+        wall = (dw.end_ns - dd.start_ns) / 1e9
         # per-token latency of the verify dispatch: wall over the mean
         # ACCEPTED tokens across active slots (that is what a consumer
         # of this stream experienced)
         accepted = float(np.mean(m[active])) if active else 1.0
-        self._m_tok_lat.observe(
-            (time.monotonic() - t0) / max(accepted, 1.0))
+        self._m_tok_lat.observe(wall / max(accepted, 1.0))
         if _profile.ENGINE_HOOK is not None:
             _profile.ENGINE_HOOK.record_engine(
-                self, "verify", int(t0 * 1e9), time.monotonic_ns(),
+                self, "verify", dd.start_ns, dw.end_ns,
                 tokens=int(np.sum(m[active])) if active else 0, steps=1,
                 active=len(active), queued=len(self._queue),
                 slots=self.n_slots, draft=g)
         shook = _slo.ENGINE_SLO_HOOK
         if shook is not None:
-            shook.record_engine_phase(
-                self._slo_tenant(), "verify", time.monotonic() - t0)
-        for s in range(self.n_slots):
-            # unlike chunks, per-slot advance is data-dependent — the
-            # mirror updates from the fetched acceptance counts
-            self._pos_host[s] += int(m[s])
-        self.stats["spec_iterations"] += 1
-        for slot in active:
-            req = self._slot_req[slot]
-            took = 0
-            for i in range(int(m[slot])):
-                if req.done or len(req.out) >= req.max_new:
-                    break
-                tok = int(outs[slot, i])
-                req.out.append(tok)
-                took += 1
-                if req.eos is not None and tok == req.eos:
-                    req.done = True
-            self.stats["spec_drafted"] += g
-            # tokens beyond the first are the speculation win: they
-            # would each have cost a dispatch under chunk=1 decode
-            self.stats["spec_accepted"] += max(0, took - 1)
-            self._retire_if_done(slot, req)
+            shook.record_engine_phase(self._slo_tenant(), "verify", wall)
+        with _tracing.phase(st, "serving.retire", parent=step):
+            for s in range(self.n_slots):
+                # unlike chunks, per-slot advance is data-dependent — the
+                # mirror updates from the fetched acceptance counts
+                self._pos_host[s] += int(m[s])
+            st["spec_iterations"] += 1
+            for slot in active:
+                req = self._slot_req[slot]
+                took = 0
+                for i in range(int(m[slot])):
+                    if req.done or len(req.out) >= req.max_new:
+                        break
+                    tok = int(outs[slot, i])
+                    req.out.append(tok)
+                    took += 1
+                    if req.eos is not None and tok == req.eos:
+                        req.done = True
+                st["spec_drafted"] += g
+                # tokens beyond the first are the speculation win: they
+                # would each have cost a dispatch under chunk=1 decode
+                st["spec_accepted"] += max(0, took - 1)
+                self._retire_if_done(slot, req)
         if _tune.TUNE_HOOK is not None:
             self._retune_spec_draft()
 

@@ -250,10 +250,12 @@ _NAME_RE = re.compile(
 
 #: start_span("name"... — both module-level and store-method calls —
 #: plus add_span("name"... (the diag engine's synthetic-span insertion
-#: path takes the same literal first argument); \b keeps e.g.
+#: path takes the same literal first argument) and phase(stats, "name"...
+#: (obs/tracing.phase: the name is the second argument); \b keeps e.g.
 #: ``restart_spanner(`` from matching
 _SPAN_CALL_RE = re.compile(
-    r"\b(?:start_span|add_span)\(\s*[\"']([^\"']+)[\"']")
+    r"\b(?:(?:start_span|add_span)\(\s*|phase\(\s*[\w.]+\s*,\s*)"
+    r"[\"']([^\"']+)[\"']")
 
 _SPAN_NAME_RE = re.compile(
     r"^(?P<layer>[a-z]+)\.(?P<op>[a-z][a-z0-9_]*)$")

@@ -110,6 +110,32 @@ def test_lint_catches_span_violations(tmp_path):
     assert lint.check_spans() == []
 
 
+def test_lint_reads_phase_call_sites(tmp_path):
+    """obs/tracing.phase names a span in its second argument; the lint
+    holds those names to the same rule, and finds the engine's nine."""
+    sys.path.insert(0, str(REPO_ROOT / "scripts"))
+    try:
+        import check_metric_names as lint
+    finally:
+        sys.path.pop(0)
+    bad = tmp_path / "bad_phases.py"
+    bad.write_text(
+        'with _tracing.phase(st, "serving.decode_wait", parent=step):\n'
+        'with tracing.phase(self.stats, "serving.DecodeWait"):\n'
+        'with phase(st,\n          "webui.render", parent=admit) as p:\n'
+        'emphase(st, "not a span")\n')
+    problems = lint.check_spans(tmp_path)
+    assert len(problems) == 2
+    assert any("'serving.DecodeWait'" in p for p in problems)
+    assert any("layer 'webui'" in p for p in problems)
+    engine = {name for path, _, name in lint.iter_span_sites()
+              if path.name == "lm_engine.py"}
+    assert {"serving.step", "serving.admit", "serving.admit_host",
+            "serving.prefill_dispatch", "serving.slot_insert",
+            "serving.first_token_wait", "serving.decode_dispatch",
+            "serving.decode_wait", "serving.retire"} <= engine
+
+
 def test_lint_catches_event_violations(tmp_path):
     sys.path.insert(0, str(REPO_ROOT / "scripts"))
     try:
