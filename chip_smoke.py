@@ -43,7 +43,10 @@ CFG = {
     "frames": 64,            # headline pipeline, CLI and graph.Pipeline
     "ref_frames": 8,         # of which compared with the CPU float32 model
     "aux_frames": 4,         # SSD / DeepLab / PoseNet
-    "lm_dims": (8192, 1024, 16, 8),   # V, D, H, L — bench.py's _LM_DIMS
+    # V, D, H, L: bench.py's _LM_DIMS at half its heads, so that a head is
+    # 128 wide like the benchmark's model and the serving legs' decode
+    # steps take pallas.decode_attention (a head of 64 takes the dense form)
+    "lm_dims": (8192, 1024, 8, 8),
     "lm_max_len": 1024,
     "lm_slots": 8,
     "lm_prompts": (64, 96, 128, 200, 384, 512),
@@ -52,6 +55,10 @@ CFG = {
     "flash": ((8, 32, 2048, 128), (1, 16, 8192, 64)),
     "dgr_f": (4 * 1024, 4 * 4096),
     "dgr_rows": (8, 2048),
+    # the benchmark's decode step: slots, heads, max_len, head size; two
+    # layers of the store stand for its 24
+    "decode_attn": (8, 16, 2048, 128),
+    "decode_attn_layers": 2,
 }
 
 
@@ -438,6 +445,75 @@ def _flash_check(got, want):
         rtol=5e-2, atol=3e-2)
 
 
+#: widest gap allowed between the decode-attention kernel's output and the
+#: dense form, in units of the output's largest value. The kernel's float32
+#: VPU arithmetic reads some 1e-7; the same attention with each product
+#: made of three bf16 products (what precision "high" is) has to read over
+#: the limit, and does
+_DECODE_ATTN_LIMIT = 2e-6
+
+
+def _three_pass_attention(q, k, v, pos):
+    """Dense decode attention over (S, H, max_len, hd) rows ``<= pos``
+    with every product as ``hi*hi + hi*lo + lo*hi`` of bf16 halves."""
+    def split(x):
+        hi = x.astype(jnp.bfloat16).astype(jnp.float32)
+        return hi, (x - hi).astype(jnp.bfloat16).astype(jnp.float32)
+
+    def dot3(eq, x, y):
+        (xh, xl), (yh, yl) = split(x), split(y)
+        with jax.default_matmul_precision("float32"):
+            return (jnp.einsum(eq, xh, yh) + jnp.einsum(eq, xh, yl)
+                    + jnp.einsum(eq, xl, yh))
+
+    s = dot3("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    live = jnp.arange(k.shape[2]) <= pos[:, None, None, None]
+    p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
+    return dot3("bhqk,bhkd->bhqd", p, v)
+
+
+def _decode_attention_kernel(clock: Clock, rng) -> None:
+    """``pallas.decode_attention`` at the benchmark's decode shape against
+    the dense masked form: lengths at 1, a block's edge and either side of
+    it, the last row, and a slot that holds no request."""
+    from nnstreamer_tpu.ops.pallas import decode_attention as da
+
+    s, h, m, hd = CFG["decode_attn"]
+    layers = CFG["decode_attn_layers"]
+    li = layers - 1
+    bk = da.kv_block(m)
+    pos = jnp.asarray([1, bk - 1, bk, bk + 1, 1000, m - 1, 77, 2 * bk],
+                      jnp.int32)
+    active = np.asarray([True] * 6 + [False, True])
+    normal = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape).astype(np.float32))
+    args = [normal(s, h, 1, hd), normal(s, h, 1, hd), normal(s, h, 1, hd),
+            normal(s, layers, h, m, hd), normal(s, layers, h, m, hd),
+            jnp.int32(li), pos, jnp.asarray(active)]
+
+    def dense(*a):
+        with jax.default_matmul_precision("float32"):
+            return da.window_attention_reference(*a, layer_axis=1)
+
+    def check(got, want):
+        _exact(got[1:], want[1:])       # the stores: new rows, bit for bit
+        o = np.asarray(want[0])
+        three = np.asarray(jax.jit(_three_pass_attention)(
+            args[0], want[1][:, li], want[2][:, li], pos))
+        gap = float(np.abs(np.asarray(got[0]) - o).max() / np.abs(o).max())
+        control = float(np.abs(three - o)[active].max() / np.abs(o).max())
+        require(gap <= _DECODE_ATTN_LIMIT,
+                f"decode_attention vs dense: gap {gap:.2e}")
+        require(control > _DECODE_ATTN_LIMIT,
+                f"the limit lets three bf16 passes through: {control:.2e}")
+        return {"gap": float(f"{gap:.2e}"),
+                "gap_of_three_bf16_passes": float(f"{control:.2e}")}
+
+    _kernel(clock, "decode_attention_s{}h{}L{}d{}".format(s, h, m, hd),
+            functools.partial(da.decode_attention, layer_axis=1), dense,
+            args, check)
+
+
 def kernels_leg(clock: Clock) -> dict:
     from nnstreamer_tpu.ops.pallas import epilogue as ep
     from nnstreamer_tpu.ops.pallas import preprocess as pp
@@ -520,6 +596,8 @@ def kernels_leg(clock: Clock) -> dict:
         _kernel(clock, f"flash_attention_residuals_{tag}", flash_residuals,
                 dense, qkv, _flash_check)
 
+    _decode_attention_kernel(clock, rng)
+
     frame = jnp.asarray(rng.integers(0, 256, (1, 224, 224, 3))
                         .astype(np.uint8))
     _kernel(clock, "normalize_u8_224", pp.normalize_u8,
@@ -535,7 +613,7 @@ def kernels_leg(clock: Clock) -> dict:
 
 
 # --------------------------------------------------------------------------- #
-# leg: serving — LMEngine at V8192·d1024·H16·L8, 8 slots
+# leg: serving — LMEngine at V8192·d1024·H8·L8, 8 slots
 # --------------------------------------------------------------------------- #
 # Float32 weights: the causal_lm family pins float32 matmul precision because
 # its contract is exactness between execution forms (prefill / decode / paged /

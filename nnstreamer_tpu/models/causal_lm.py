@@ -38,6 +38,8 @@ from ..core.types import TensorsInfo
 from ..ops.int8 import matmul_any as _mm
 from ..ops.int8 import mlp_matmul as _mlp
 from ..ops.int8 import quantize_weight, stack_shape
+from ..ops.pallas.decode_attention import (decode_attention,
+                                           window_attention_reference)
 from .zoo import ModelBundle, register_model
 
 
@@ -67,7 +69,7 @@ def quantize_lm_params(params: Dict[str, Any]) -> Dict[str, Any]:
     (wqkv/wo/w1/w2) become int8 payloads + per-output-channel scales
     (ops/int8.quantize_weight); embeddings and norms stay float. Every
     execution form — forward, prefill (dense/flash/ring), decode step,
-    verify window, vmapped slots — consumes the quantized tree through
+    verify window, batched slots — consumes the quantized tree through
     the same ``matmul_any`` sites, so this one transform turns the whole
     family int8 with no flag-threading; the scanned layer stacks slice
     into per-layer quantized dicts transparently. TPU v5e runs the int8
@@ -326,41 +328,58 @@ def lm_verify_window(params: Dict[str, jax.Array], tokens: jax.Array,
 
 
 def _lm_verify_window(params, tokens, kcache, vcache, pos, n_heads):
+    """Streams in step: one position for the whole batch, the flat
+    transport layout ``(L·B·H, max_len, hd)``."""
     n_layers = stack_shape(params["wqkv"])[0]
     b, w = tokens.shape
-    d_model = params["embed"].shape[1]
-    hd = d_model // n_heads
-    max_len = kcache.shape[-2]
+    max_len, hd = kcache.shape[-2:]
     p = jnp.asarray(pos).reshape(())
+    shape5 = (n_layers, b, n_heads, max_len, hd)
+    logits, kc, vc = _lm_window(
+        params, tokens, kcache.reshape(shape5), vcache.reshape(shape5), p,
+        None, n_heads, layer_axis=0)
+    return (logits, kc.reshape(kcache.shape), vc.reshape(vcache.shape),
+            (p + w).reshape(1).astype(jnp.int32))
 
-    kc = kcache.reshape(n_layers, b, n_heads, max_len, hd)
-    vc = vcache.reshape(n_layers, b, n_heads, max_len, hd)
-    x = params["embed"][tokens] + \
-        jax.lax.dynamic_slice_in_dim(params["pos_embed"], p, w)[None]
-    # row j sees columns <= p+j (its own slot included, later rows' not)
-    live = (jnp.arange(max_len)[None, :] <=
-            (p + jnp.arange(w))[:, None])[None, None]   # (1,1,W,max_len)
+
+def _lm_window(params, tokens, kc, vc, pos, active, n_heads, layer_axis):
+    """The ONE decode / verify body: a window of W tokens a stream, over
+    the 5-D store with its layers on ``layer_axis`` (0: ``(L, B, H,
+    max_len, hd)``, streams in step; 1: ``(B, L, H, max_len, hd)``, a store
+    a slot). ``pos`` is () for streams in step or (B,) per stream;
+    ``active`` (B,) bool or None marks the streams that hold a request.
+    Returns (logits (B, W, vocab), kc, vc).
+
+    A window of one token goes through ``ops/pallas.decode_attention``:
+    on a TPU the kernel reads the rows ``< pos`` of the streams that are
+    active and writes each new row in place; elsewhere, and for a wider
+    window, the dense masked form runs (its per-stream write is a masked
+    rewrite of the store, its read the whole ``max_len`` axis)."""
+    w = tokens.shape[1]
+    max_len = kc.shape[-2]
+    if pos.ndim == 0:
+        pe = jax.lax.dynamic_slice_in_dim(params["pos_embed"], pos, w)[None]
+    else:
+        pe = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(
+            params["pos_embed"], p, w))(pos)
+    x = params["embed"][tokens] + pe
 
     def block(carry, layer):
-        # the cache rides the CARRY, not the scan ys: a ys-threaded cache
-        # makes XLA rewrite all L·B·H·max_len slots every token, while a
-        # carried buffer takes in-place dynamic_update_slice writes of
-        # just the new (B, H, W, hd) slots per layer — the difference is
-        # ~half the per-step HBM traffic at serving shapes
+        # the store rides the CARRY, not the scan ys: a ys-threaded store
+        # makes XLA rewrite all L·B·H·max_len rows every token, while a
+        # carried buffer is updated in place, by the kernel's one-row DMA
+        # or the dense form's dynamic_update_slice
         h, kc, vc = carry
         wqkv, wo, w1, w2, ln1, ln2, li = layer
         a = _ln(h, ln1)
-        q, k, v = jnp.split(_mm(a, wqkv), 3, axis=-1)      # (B, W, D)
-        q = _split_heads(q, n_heads)                       # (B, H, W, hd)
-        k = _split_heads(k, n_heads)[None].astype(kc.dtype)
-        v = _split_heads(v, n_heads)[None].astype(vc.dtype)
-        kc = jax.lax.dynamic_update_slice(kc, k, (li, 0, 0, p, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v, (li, 0, 0, p, 0))
-        kc_l = jax.lax.dynamic_index_in_dim(kc, li, 0, keepdims=False)
-        vc_l = jax.lax.dynamic_index_in_dim(vc, li, 0, keepdims=False)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, kc_l) / math.sqrt(hd)
-        s = jnp.where(live, s, -1e30)
-        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), vc_l)
+        q, k, v = (_split_heads(z, n_heads)                # (B, H, W, hd)
+                   for z in jnp.split(_mm(a, wqkv), 3, axis=-1))
+        if w == 1:
+            o, kc, vc = decode_attention(
+                q, k, v, kc, vc, li, pos, active, layer_axis=layer_axis)
+        else:
+            o, kc, vc = window_attention_reference(
+                q, k, v, kc, vc, li, pos, active, layer_axis=layer_axis)
         o = o.transpose(0, 2, 1, 3).reshape(h.shape)
         h = h + _mm(o, wo)
         m = _ln(h, ln2)
@@ -368,38 +387,44 @@ def _lm_verify_window(params, tokens, kcache, vcache, pos, n_heads):
 
     (x, kc, vc), _ = jax.lax.scan(
         block, (x, kc, vc),
-        (params["wqkv"], params["wo"], params["w1"],
-         params["w2"], params["ln1"], params["ln2"],
-         jnp.arange(n_layers, dtype=jnp.int32)),
+        (*_layer_stack(params),
+         jnp.arange(kc.shape[layer_axis], dtype=jnp.int32)),
         # full unroll: step ops are tiny (B·W rows), so the win is XLA
         # prefetching the next layer's weights while this one runs;
         # n_layers is small and static, compile cost is bounded
         unroll=True)
-    logits = _ln(x, params["lnf"]) @ params["embed"].T   # (B, W, vocab)
     # cache overflow (window past capacity) surfaces as NaN logits, not
-    # as a silent clamped overwrite of the last slots — lm_decode_step doc
-    logits = jnp.where(p + w > max_len, jnp.nan, logits)
-    flat = (n_layers * b * n_heads, max_len, hd)
-    return (logits, kc.reshape(flat), vc.reshape(flat),
-            (p + w).reshape(1).astype(jnp.int32))
+    # as a silent clamped overwrite of the last slots — lm_decode_step
+    # doc. The hidden row is poisoned, (B, W, D), and the unembedding
+    # carries the NaN to every logit of that stream
+    over = (pos + w > max_len).reshape(-1, 1, 1)
+    x = jnp.where(over, jnp.nan, _ln(x, params["lnf"]))
+    return x @ params["embed"].T, kc, vc                 # (B, W, vocab)
 
 
 def lm_verify_window_slots(params: Dict[str, jax.Array], tokens: jax.Array,
                            kcaches: jax.Array, vcaches: jax.Array,
-                           poss: jax.Array, n_heads: int
+                           poss: jax.Array, n_heads: int,
+                           active: "jax.Array | None" = None
                            ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                       jax.Array]:
-    """Verify windows for S independent streams at per-slot positions
-    (``jax.vmap`` of :func:`lm_verify_window`, the same construction as
-    lm_decode_step_slots). tokens: (S, W); caches with a leading slot
-    axis; poss: (S, 1). Returns (logits (S, W, vocab), caches',
-    poss+W)."""
+    """Verify windows for S independent streams at per-slot positions:
+    the S slots are the batch of the one shared body, each row with its
+    own position. tokens: (S, W); caches with a leading slot axis,
+    ``(S, L·H, max_len, hd)``; poss: (S, 1); active: (S,) bool, the slots
+    that hold a request (None: all). A slot that is not active costs no
+    K/V read on a TPU and writes nothing; its logits are not meaningful.
+    Returns (logits (S, W, vocab), caches', poss+W)."""
+    s, w = tokens.shape
+    lh, max_len, hd = kcaches.shape[1:]
+    shape5 = (s, lh // n_heads, n_heads, max_len, hd)
     with jax.default_matmul_precision(_PRECISION):
-        step = lambda tok, kc, vc, pos: _lm_verify_window(  # noqa: E731
-            params, tok[None], kc, vc, pos, n_heads)
-        logits, kc, vc, pos = jax.vmap(step)(
-            tokens, kcaches, vcaches, poss)
-        return logits[:, 0], kc, vc, pos
+        logits, kc, vc = _lm_window(
+            params, tokens, kcaches.reshape(shape5),
+            vcaches.reshape(shape5), poss[:, 0], active, n_heads,
+            layer_axis=1)
+    return (logits, kc.reshape(kcaches.shape), vc.reshape(vcaches.shape),
+            poss + w)
 
 
 def lm_prefill_masked(params: Dict[str, jax.Array], tokens: jax.Array,
@@ -429,26 +454,28 @@ def lm_prefill_masked(params: Dict[str, jax.Array], tokens: jax.Array,
 
 def lm_decode_step_slots(params: Dict[str, jax.Array], tokens: jax.Array,
                          kcaches: jax.Array, vcaches: jax.Array,
-                         poss: jax.Array, n_heads: int
+                         poss: jax.Array, n_heads: int,
+                         active: "jax.Array | None" = None
                          ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                     jax.Array]:
     """One decode step for S INDEPENDENT streams at per-slot positions.
 
-    The continuous-batching primitive: ``jax.vmap`` of the single-stream
-    ``lm_decode_step`` over a leading slot axis, so each slot carries its
-    own cache, write position, and liveness mask while the matmuls batch
-    onto the MXU. Per-slot cache writes lower to one batched scatter.
-    Exactness with the single-stream path is by construction (same
-    program under vmap; tests/test_lm_serving.py pins it).
+    The continuous-batching primitive: the S slots run as the batch of
+    the single-stream body, each with its own cache, write position and
+    liveness, while the matmuls batch onto the MXU. On a TPU a slot's
+    attention reads the rows it holds and its new row is written in
+    place (``ops/pallas.decode_attention``). Exactness with the
+    single-stream path is by construction (one body;
+    tests/test_lm_serving.py pins it).
 
     tokens: (S, 1, 1) int32; kcaches/vcaches: (S, layers·heads, max_len,
-    head_dim); poss: (S, 1) int32. Returns (logits (S, 1, vocab),
-    kcaches', vcaches', poss+1). Slots past capacity NaN-poison their own
-    row only. Exactly the W=1 case of :func:`lm_verify_window_slots`
-    (one shared vmap wrapper; only the token layout differs).
+    head_dim); poss: (S, 1) int32; active: (S,) bool or None. Returns
+    (logits (S, 1, vocab), kcaches', vcaches', poss+1). Slots past
+    capacity NaN-poison their own row only. Exactly the W=1 case of
+    :func:`lm_verify_window_slots`.
     """
     return lm_verify_window_slots(
-        params, tokens[:, :, 0], kcaches, vcaches, poss, n_heads)
+        params, tokens[:, :, 0], kcaches, vcaches, poss, n_heads, active)
 
 
 # --------------------------------------------------------------------------- #
@@ -456,7 +483,7 @@ def lm_decode_step_slots(params: Dict[str, jax.Array], tokens: jax.Array,
 #
 # The paged kernels do NOT reimplement attention. Each step GATHERS a
 # slot's pages into the exact flat per-slot cache layout the contiguous
-# kernels consume, runs the ONE shared `_lm_verify_window` body, and
+# kernels consume, runs the ONE shared `_lm_window` body, and
 # SCATTERS back only the pages the step could have touched. Exactness
 # paged-vs-contiguous is therefore by construction, not by a parallel
 # implementation (tests/test_kv_paging.py pins it bit-for-bit).
@@ -476,7 +503,7 @@ def _paged_view(pool, table):
 
     pool: (n_pages+1, L·H, ps, hd); table: (B,) int32 page ids. Returns
     (L·H, B·ps, hd) — exactly the single-slot transport layout with
-    max_len = B·ps, so `_lm_verify_window` runs on it unchanged (it
+    max_len = B·ps, so `_lm_window` runs on it unchanged (it
     reads capacity from the cache shape). Table rows past the request's
     allocation hold the null page (id 0): their zeros are garbage the
     causal `live` mask never attends.
@@ -575,25 +602,22 @@ def lm_verify_window_paged(params: Dict[str, jax.Array], tokens: jax.Array,
                            ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                       jax.Array]:
     """Verify windows for S slots against paged caches: gather each
-    slot's view, run the same vmapped `_lm_verify_window` step as
-    :func:`lm_verify_window_slots`, scatter back the touched pages.
+    slot's view, run :func:`lm_verify_window_slots` on the views,
+    scatter back the touched pages.
     tokens: (S, W); tables: (S, B); poss: (S, 1). Returns (logits
     (S, W, vocab), kpool', vpool', poss+W). Slots past their view
     capacity B·ps NaN-poison their own row, same contract as the
     contiguous form."""
-    with jax.default_matmul_precision(_PRECISION):
-        kviews = paged_view_slots(kpool, tables)
-        vviews = paged_view_slots(vpool, tables)
-        step = lambda tok, kc, vc, pos: _lm_verify_window(  # noqa: E731
-            params, tok[None], kc, vc, pos, n_heads)
-        logits, kviews, vviews, poss2 = jax.vmap(step)(
-            tokens, kviews, vviews, poss)
-        nt = paged_touch_span(tokens.shape[1], kpool.shape[2],
-                              tables.shape[1])
-        p0s = poss[:, 0]
-        kpool = paged_update_slots(kpool, kviews, tables, p0s, nt)
-        vpool = paged_update_slots(vpool, vviews, tables, p0s, nt)
-        return logits[:, 0], kpool, vpool, poss2
+    kviews = paged_view_slots(kpool, tables)
+    vviews = paged_view_slots(vpool, tables)
+    logits, kviews, vviews, poss2 = lm_verify_window_slots(
+        params, tokens, kviews, vviews, poss, n_heads)
+    nt = paged_touch_span(tokens.shape[1], kpool.shape[2],
+                          tables.shape[1])
+    p0s = poss[:, 0]
+    kpool = paged_update_slots(kpool, kviews, tables, p0s, nt)
+    vpool = paged_update_slots(vpool, vviews, tables, p0s, nt)
+    return logits, kpool, vpool, poss2
 
 
 def lm_decode_step_paged(params: Dict[str, jax.Array], tokens: jax.Array,

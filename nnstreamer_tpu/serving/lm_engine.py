@@ -1,13 +1,13 @@
 """Continuous batching for causal-LM generation.
 
 The TPU-native answer to LM serving throughput: S fixed cache slots, one
-compiled batched decode step (``lm_decode_step_slots`` — vmap of the
-single-stream step), and a host-side iteration-level scheduler that
-admits queued prompts into free slots the moment they open. Decode work
-never waits for a whole batch to finish (the static-batch failure mode):
-a stream that completes frees its slot at the next iteration boundary
-and the next prompt prefills into it while the other slots keep
-decoding.
+compiled batched decode step (``lm_decode_step_slots`` — the slots as
+the batch of the single-stream body), and a host-side iteration-level
+scheduler that admits queued prompts into free slots the moment they
+open. Decode work never waits for a whole batch to finish (the
+static-batch failure mode): a stream that completes frees its slot at
+the next iteration boundary and the next prompt prefills into it while
+the other slots keep decoding.
 
 XLA-shaped design decisions:
 - **Static shapes everywhere.** The slot axis S, cache capacity
@@ -77,6 +77,7 @@ from ..obs import quality as _quality
 from ..obs import slo as _slo
 from ..obs import tracing as _tracing
 from ..ops.int8 import stack_shape
+from ..ops.pallas import decode_attention
 from ..resilience import policy as _rp
 from . import sampling
 from .kv_cache import PagedKVCache
@@ -192,16 +193,17 @@ def _slot_insert(store, value, slot):
         (slot,) + (0,) * value.ndim)
 
 
-def _chunk_scan(params, tokens, kc, vc, pos, skeys, temp, top_k, top_p,
-                n_heads, n_steps):
+def _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
+                top_p, n_heads, n_steps):
     """The n_steps decode scan over per-slot caches — ONE body shared by
     the contiguous chunk and the paged chunk (which runs it on gathered
     page views; the step kernels read capacity from the cache shape, so
-    the body is layout-agnostic)."""
+    the body is layout-agnostic). ``active`` (S,) bool: the slots that
+    hold a request; the others are neither read nor written."""
     def one(carry, _):
         tokens, kc, vc, pos = carry
         logits, kc, vc, pos = causal_lm.lm_decode_step_slots(
-            params, tokens, kc, vc, pos, n_heads)
+            params, tokens, kc, vc, pos, n_heads, active)
 
         # pos is post-step = tokens consumed; keys derive from (seed,
         # consumed) only, so sampling is batch-composition-independent
@@ -225,27 +227,27 @@ def _chunk_scan(params, tokens, kc, vc, pos, skeys, temp, top_k, top_p,
 
 @partial(jax.jit, static_argnames=("n_heads", "n_steps"),
          donate_argnums=(1, 2, 3, 4))
-def _decode_chunk(params, tokens, kc, vc, pos, skeys, temp, top_k, top_p,
-                  n_heads, n_steps):
-    return _chunk_scan(params, tokens, kc, vc, pos, skeys, temp, top_k,
-                       top_p, n_heads, n_steps)
+def _decode_chunk(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
+                  top_p, n_heads, n_steps):
+    return _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp,
+                       top_k, top_p, n_heads, n_steps)
 
 
 @partial(jax.jit, static_argnames=("n_heads", "n_steps"),
          donate_argnums=(1, 2, 3, 5))
-def _decode_chunk_paged(params, tokens, kpool, vpool, tables, pos, skeys,
-                        temp, top_k, top_p, n_heads, n_steps):
+def _decode_chunk_paged(params, tokens, kpool, vpool, tables, pos, active,
+                        skeys, temp, top_k, top_p, n_heads, n_steps):
     """Paged decode chunk: gather each slot's pages into a contiguous
-    view ONCE per chunk, run the shared scan on the views (in-place
-    dynamic_update_slice writes per step, same as contiguous), scatter
-    back only the pages an n_steps window can touch. The gather/scatter
-    cost amortizes over the whole chunk, not per token."""
+    view ONCE per chunk, run the shared scan on the views (each step
+    writes its rows in place, same as contiguous), scatter back only the
+    pages an n_steps window can touch. The gather/scatter cost amortizes
+    over the whole chunk, not per token."""
     kviews = causal_lm.paged_view_slots(kpool, tables)
     vviews = causal_lm.paged_view_slots(vpool, tables)
     p0s = pos[:, 0]
     tokens, kviews, vviews, pos, outs = _chunk_scan(
-        params, tokens, kviews, vviews, pos, skeys, temp, top_k, top_p,
-        n_heads, n_steps)
+        params, tokens, kviews, vviews, pos, active, skeys, temp, top_k,
+        top_p, n_heads, n_steps)
     nt = causal_lm.paged_touch_span(
         n_steps, kpool.shape[2], tables.shape[1])
     kpool = causal_lm.paged_update_slots(kpool, kviews, tables, p0s, nt)
@@ -578,6 +580,9 @@ class LMEngine:
         # not in the slots x steps = kept + wasted chunk invariant
         self.stats = {"prefills": 0, "decode_steps": 0,
                       "slot_steps": 0, "wasted_slot_steps": 0,
+                      # store rows the chunks' steps were asked to read
+                      # (of decode_steps x slots x max_len at most)
+                      "kv_rows_attended": 0,
                       "tokens_out": 0,
                       "spec_iterations": 0, "spec_drafted": 0,
                       "spec_accepted": 0,
@@ -1510,6 +1515,7 @@ class LMEngine:
                 (dw.end_ns - dd.start_ns) / 1e9)
         # host bookkeeping with nothing on the device
         with _tracing.phase(st, "serving.retire", parent=step):
+            st["kv_rows_attended"] += self._kv_rows_asked(active, n)
             for s in range(self.n_slots):
                 self._pos_host[s] += n  # device pos advances for EVERY slot
             st["decode_steps"] += n
@@ -1545,21 +1551,33 @@ class LMEngine:
         device state; returns the (S, n) generated tokens. The second
         device-layout hook a mesh-sharded engine overrides (the paged
         branch never reaches a TP engine — it pins kv_page_size=0)."""
+        # the slots that hold a request: the others are not attended
+        active = np.fromiter((r is not None for r in self._slot_req),
+                             bool, self.n_slots)
         if self._kv is not None:
             kv = self._kv
             (self._tokens, kv.kpool, kv.vpool, self._pos, outs) = \
                 _decode_chunk_paged(
                     self.params, self._tokens, kv.kpool, kv.vpool,
-                    jnp.asarray(self._table_host), self._pos,
+                    jnp.asarray(self._table_host), self._pos, active,
                     self._skeys, self._temp, self._topk, self._topp,
                     n_heads=self.n_heads, n_steps=n)
             return outs
         self._tokens, self._kc, self._vc, self._pos, outs = \
             _decode_chunk(self.params, self._tokens, self._kc,
-                          self._vc, self._pos, self._skeys,
+                          self._vc, self._pos, active, self._skeys,
                           self._temp, self._topk, self._topp,
                           n_heads=self.n_heads, n_steps=n)
         return outs
+
+    def _kv_rows_asked(self, active: List[int], n: int) -> int:
+        """K/V rows of the store the ``n`` steps of a chunk are asked to
+        read: each active slot's position at each step, rounded up to the
+        attention kernel's blocks. A mesh-sharded engine, whose body
+        reads the whole store, overrides it."""
+        return sum(decode_attention.rows_read(self._pos_host[s] + j,
+                                              self._m_slot)
+                   for s in active for j in range(n))
 
     def _run_verify(self, tokens_in):
         """Device kernel hook for one speculative verify iteration —

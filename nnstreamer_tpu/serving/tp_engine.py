@@ -288,6 +288,11 @@ class TPLMEngine(LMEngine):
                     self._topp)
         return outs
 
+    def _kv_rows_asked(self, active, n: int) -> int:
+        # parallel/tp_decode.tp_window_step attends the whole max_len
+        # axis of every slot (ROADMAP C3)
+        return n * self.n_slots * self.max_len
+
     def _run_verify(self, tokens_in):
         with jax.default_matmul_precision("float32"):
             return _tp_verify_fn(self.mesh, self.axis, self.n_heads,

@@ -281,3 +281,82 @@ def test_stats_account_for_waste(params):
     assert st["slot_steps"] >= st["tokens_out"] - st["prefills"]
     assert eng.n_slots * st["decode_steps"] == \
         (st["tokens_out"] - st["prefills"]) + st["wasted_slot_steps"]
+
+
+def forward_generate(params, prompt, max_new):
+    """The oracle of oracles: greedy tokens from the full causal forward,
+    one ``lm_forward`` a token, no cache at all."""
+    seq = [int(t) for t in prompt]
+    out = []
+    for _ in range(max_new):
+        logits = causal_lm.lm_forward(
+            params, jnp.asarray(np.asarray(seq, np.int32)[None]), H)
+        out.append(int(jnp.argmax(logits[0, -1])))
+        seq.append(out[-1])
+    return out
+
+
+def single_stream_state(params, prompt, n_decode):
+    """K/V rows the single-stream path leaves: unpadded prefill, then
+    ``n_decode`` one-token steps. Returns (kcache, vcache, rows)."""
+    logits, kc, vc, pos = causal_lm.lm_prefill(
+        params, jnp.asarray(np.asarray(prompt, np.int32)[None]), H, MAXLEN)
+    tok = int(jnp.argmax(logits[0]))
+    for _ in range(n_decode):
+        logits, kc, vc, pos = causal_lm.lm_decode_step(
+            params, jnp.asarray([[tok]], jnp.int32), kc, vc, pos, H)
+        tok = int(jnp.argmax(logits[0]))
+    return np.asarray(kc), np.asarray(vc), int(pos[0])
+
+
+def test_mixed_lengths_empty_slot_and_midchunk_finish(params):
+    """Slots at different lengths, one slot that never holds a request
+    and one request that finishes in the middle of a chunk: every stream
+    decodes token for token like ``lm_forward``, the rows left in the
+    stores are the single-stream path's, and the empty slot's store is
+    never written."""
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(0, V, n).astype(np.int32) for n in (3, 11, 20)]
+    new = [10, 6, 12]            # 6: one from the prefill, 4 + 1 decoded
+    eng = LMEngine(params, H, MAXLEN, n_slots=4, chunk=4)
+    rids = [eng.submit(p, max_new=m) for p, m in zip(prompts, new)]
+    eng.step_iteration()
+    slots = [eng.slot_of(rid) for rid in rids]
+    assert sorted(slots) == [0, 1, 2]
+    res = eng.run()
+    for rid, p, m in zip(rids, prompts, new):
+        assert res[rid] == forward_generate(params, p, m)
+    kcs, vcs = np.asarray(eng._kc), np.asarray(eng._vc)
+    for slot, p, m in zip(slots, prompts, new):
+        # the last token was never fed back: prompt + (m - 1) rows
+        kc1, vc1, rows = single_stream_state(params, p, m - 1)
+        assert rows == p.size + m - 1
+        np.testing.assert_allclose(kcs[slot, :, :rows], kc1[:, :rows],
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(vcs[slot, :, :rows], vc1[:, :rows],
+                                   rtol=1e-5, atol=1e-6)
+    assert not kcs[3].any() and not vcs[3].any()
+
+
+def test_kv_rows_attended_counts_block_rounded_lengths():
+    """``stats['kv_rows_attended']``: each active slot's position at each
+    step of each chunk, rounded up to the attention kernel's block; an
+    empty slot counts nothing, a request that ends mid-chunk counts to
+    the chunk's end."""
+    from nnstreamer_tpu.ops.pallas import decode_attention as da
+
+    max_len = 320
+    assert da.kv_block(max_len) == 160
+    p = causal_lm.init_causal_lm(jax.random.PRNGKey(3), V, D, H, L, max_len)
+    rng = np.random.default_rng(4)
+    eng = LMEngine(p, H, max_len, n_slots=3, chunk=4)
+    assert eng.stats["kv_rows_attended"] == 0
+    eng.submit(rng.integers(0, V, 150).astype(np.int32), max_new=21)
+    eng.submit(rng.integers(0, V, 10).astype(np.int32), max_new=3)
+    eng.run()
+    # the long request: 20 steps at positions 150..169, of which 150..160
+    # read one block and 161..169 two; the short one holds its slot for
+    # the first chunk's 4 steps (positions 10..13), one block each
+    assert eng.stats["decode_steps"] == 20
+    assert eng.stats["kv_rows_attended"] \
+        == 11 * 160 + 9 * 320 + 4 * 160

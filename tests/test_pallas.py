@@ -169,3 +169,152 @@ def test_kernel_lowers_for_tpu(fn, n_args, shape, dtype):
     text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
         *[spec] * n_args).mlir_module()
     assert "tpu_custom_call" in text
+
+
+# --------------------------------------------------------------------------- #
+# decode attention (ops/pallas/decode_attention.py): the kernel through the
+# Pallas interpreter against the dense masked form, at toy sizes
+# --------------------------------------------------------------------------- #
+
+_DA_MAX_LEN, _DA_BLOCK = 64, 16   # four blocks a stream
+
+
+def _decode_case(pos, active, layer_axis=1, li=1, n_layers=2, heads=2,
+                 max_len=_DA_MAX_LEN, hd=8, seed=0):
+    import jax
+    import jax.numpy as jnp
+
+    b = len(pos)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    shape = (b, n_layers, heads, max_len, hd) if layer_axis == 1 \
+        else (n_layers, b, heads, max_len, hd)
+    kc, vc = (jax.random.normal(k, shape) for k in ks[:2])
+    q, kn, vn = (jax.random.normal(k, (b, heads, 1, hd)) for k in ks[2:])
+    return (q, kn, vn, kc, vc, jnp.int32(li), jnp.asarray(pos, jnp.int32),
+            None if active is None else jnp.asarray(active, bool))
+
+
+def _assert_kernel_is_dense(args, layer_axis=1, block=_DA_BLOCK):
+    from nnstreamer_tpu.ops.pallas import decode_attention as da
+
+    want = da.window_attention_reference(*args, layer_axis=layer_axis)
+    got = da._decode_pallas(*args, layer_axis=layer_axis, block=block,
+                            interpret=True)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-6, atol=2e-6)
+    # the stores: the new rows written, nothing else touched
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
+    return got
+
+
+@pytest.mark.parametrize("pos", [
+    0,                                  # an empty store: the new row alone
+    1,
+    _DA_BLOCK - 1, _DA_BLOCK, _DA_BLOCK + 1,    # a block's edge
+    3 * _DA_BLOCK + 5,
+    _DA_MAX_LEN - 1,                    # the last row a stream may write
+])
+def test_decode_attention_lengths(pos):
+    """Every stream at one length: ``pos`` store rows and the new one."""
+    _assert_kernel_is_dense(_decode_case([pos] * 3, [True] * 3))
+
+
+@pytest.mark.parametrize("pos,active", [
+    ([1, 17, 63, 40], [True, True, True, True]),       # mixed lengths
+    ([33, 5, 16, 47], [True, False, True, True]),      # a slot of length 0
+    ([9, 9, 9, 9], [False, True, False, False]),       # gaps on both sides
+    ([7, 30, 2, 11], [False, False, False, False]),    # nobody home
+])
+def test_decode_attention_mixed_slots(pos, active):
+    args = _decode_case(pos, active)
+    o, kc, vc = _assert_kernel_is_dense(args)
+    vn = np.asarray(args[2])
+    for b, act in enumerate(active):
+        if not act:
+            # a stream without a request: no row written, its own value
+            # row handed back
+            np.testing.assert_array_equal(np.asarray(o)[b], vn[b])
+            np.testing.assert_array_equal(np.asarray(kc)[b],
+                                          np.asarray(args[3])[b])
+
+
+@pytest.mark.parametrize("layer_axis,li", [(0, 0), (0, 1), (1, 0)])
+def test_decode_attention_layouts(layer_axis, li):
+    """Streams in step (layers first, a scalar position, no active set)
+    and a store a slot (slots first): the same kernel finds its rows."""
+    import jax.numpy as jnp
+
+    if layer_axis == 0:
+        args = _decode_case([21] * 3, None, layer_axis=0, li=li)
+        args = args[:6] + (jnp.int32(21), None)
+    else:
+        args = _decode_case([21, 4, 50], [True] * 3, li=li)
+    _assert_kernel_is_dense(args, layer_axis=layer_axis)
+
+
+def test_decode_attention_block_choice_and_fallback():
+    """The block divides ``max_len``; where no multiple of 8 does, the head
+    size is not whole lanes or the store is not float32, the dense form
+    runs whatever the platform."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.ops.pallas import decode_attention as da
+
+    assert da.kv_block(2048) == 256 and da.kv_block(64) == 64
+    assert da.kv_block(96) == 96 and da.kv_block(640) == 160
+    assert da.kv_block(10) == 0
+    assert da.rows_read(0, 2048) == 256 and da.rows_read(256, 2048) == 256
+    assert da.rows_read(257, 2048) == 512 and da.rows_read(9, 10) == 10
+
+    fn = functools.partial(da.decode_attention, layer_axis=1,
+                           interpret=True)
+
+    def takes_kernel(args):
+        return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+    lanes = _decode_case([3, 9], [True, True], hd=128)
+    assert takes_kernel(lanes)
+    want = da.window_attention_reference(*lanes, layer_axis=1)
+    np.testing.assert_allclose(np.asarray(fn(*lanes)[0]),
+                               np.asarray(want[0]), rtol=2e-6, atol=2e-6)
+    assert not takes_kernel(_decode_case([3, 9], [True, True], hd=64))
+    assert not takes_kernel(
+        _decode_case([3, 9], [True, True], hd=128, max_len=10))
+    half = tuple(a.astype(jnp.bfloat16) if i in (3, 4) else a
+                 for i, a in enumerate(lanes))
+    assert not takes_kernel(half)
+    short = _decode_case([3, 9], [True, True], max_len=10)
+    got = fn(*short)
+    want = da.window_attention_reference(*short, layer_axis=1)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-6, atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+def test_decode_attention_lowers_for_tpu():
+    """The production shape (8 slots, 24 layers x 16 heads, 2048 x 128,
+    float32) lowers for the TPU platform with the Mosaic call in it and
+    the stores aliased in and out."""
+    import jax
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.ops.pallas import decode_attention as da
+
+    s, layers, h, m, hd = 8, 24, 16, 2048, 128
+    row = jax.ShapeDtypeStruct((s, h, 1, hd), jnp.float32)
+    store = jax.ShapeDtypeStruct((s, layers, h, m, hd), jnp.float32)
+
+    def fn(q, kn, vn, kc, vc, li, pos, active):
+        return da.decode_attention(q, kn, vn, kc, vc, li, pos, active,
+                                   layer_axis=1)
+
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        row, row, row, store, store, jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((s,), jnp.int32),
+        jax.ShapeDtypeStruct((s,), jnp.bool_)).mlir_module()
+    assert "tpu_custom_call" in text
+    assert "output_operand_alias" in text
