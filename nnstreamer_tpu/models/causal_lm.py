@@ -39,6 +39,7 @@ from ..ops.int8 import matmul_any as _mm
 from ..ops.int8 import mlp_matmul as _mlp
 from ..ops.int8 import quantize_weight, stack_shape
 from ..ops.pallas.decode_attention import (decode_attention,
+                                           lane_window_attention,
                                            window_attention_reference)
 from .zoo import ModelBundle, register_model
 
@@ -342,7 +343,8 @@ def _lm_verify_window(params, tokens, kcache, vcache, pos, n_heads):
             (p + w).reshape(1).astype(jnp.int32))
 
 
-def _lm_window(params, tokens, kc, vc, pos, active, n_heads, layer_axis):
+def _lm_window(params, tokens, kc, vc, pos, active, n_heads, layer_axis,
+               lane=None):
     """The ONE decode / verify body: a window of W tokens a stream, over
     the 5-D store with its layers on ``layer_axis`` (0: ``(L, B, H,
     max_len, hd)``, streams in step; 1: ``(B, L, H, max_len, hd)``, a store
@@ -354,8 +356,20 @@ def _lm_window(params, tokens, kc, vc, pos, active, n_heads, layer_axis):
     on a TPU the kernel reads the rows ``< pos`` of the streams that are
     active and writes each new row in place; elsewhere, and for a wider
     window, the dense masked form runs (its per-stream write is a masked
-    rewrite of the store, its read the whole ``max_len`` axis)."""
+    rewrite of the store, its read the whole ``max_len`` axis).
+
+    ``lane`` (one-token windows over a store a slot only) adds one window
+    of P prompt rows of ONE stream to the step: ``(tokens (P,), slot,
+    pos0, count)``, the prompt's tokens ``pos0 .. pos0 + P - 1`` of which
+    the first ``count`` are real. The P rows ride the B decode rows through
+    the same norms and matmuls, so each weight is read once for both;
+    their attention and their in-place K/V write are
+    ``lane_window_attention``'s, and the stream they belong to has to be
+    one that is not active. The logits then carry one row more, (B + 1, 1,
+    vocab): the last is the lane's row ``count - 1``, the prompt's last
+    token where the window is the prompt's last."""
     w = tokens.shape[1]
+    b = tokens.shape[0]
     max_len = kc.shape[-2]
     if pos.ndim == 0:
         pe = jax.lax.dynamic_slice_in_dim(params["pos_embed"], pos, w)[None]
@@ -363,6 +377,15 @@ def _lm_window(params, tokens, kc, vc, pos, active, n_heads, layer_axis):
         pe = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(
             params["pos_embed"], p, w))(pos)
     x = params["embed"][tokens] + pe
+    if lane is not None:
+        if w != 1 or layer_axis != 1:
+            raise ValueError("a prompt lane rides one-token windows over "
+                             "a store a slot")
+        ltok, lslot, lpos0, lcnt = lane
+        # the lane's rows as P more one-token windows under the decode rows
+        x = jnp.concatenate(
+            [x, (params["embed"][ltok] + jax.lax.dynamic_slice_in_dim(
+                params["pos_embed"], lpos0, ltok.shape[0]))[:, None]])
 
     def block(carry, layer):
         # the store rides the CARRY, not the scan ys: a ys-threaded store
@@ -374,7 +397,14 @@ def _lm_window(params, tokens, kc, vc, pos, active, n_heads, layer_axis):
         a = _ln(h, ln1)
         q, k, v = (_split_heads(z, n_heads)                # (B, H, W, hd)
                    for z in jnp.split(_mm(a, wqkv), 3, axis=-1))
-        if w == 1:
+        if lane is not None:
+            o, kc, vc = decode_attention(
+                q[:b], k[:b], v[:b], kc, vc, li, pos, active,
+                layer_axis=layer_axis)
+            ol, kc, vc = lane_window_attention(
+                q[b:], k[b:], v[b:], kc, vc, li, lslot, lpos0)
+            o = jnp.concatenate([o, ol])
+        elif w == 1:
             o, kc, vc = decode_attention(
                 q, k, v, kc, vc, li, pos, active, layer_axis=layer_axis)
         else:
@@ -398,6 +428,12 @@ def _lm_window(params, tokens, kc, vc, pos, active, n_heads, layer_axis):
     # doc. The hidden row is poisoned, (B, W, D), and the unembedding
     # carries the NaN to every logit of that stream
     over = (pos + w > max_len).reshape(-1, 1, 1)
+    if lane is not None:
+        # one row of the lane is unembedded, beside the decode rows: the
+        # embedding is read once for both
+        x = jnp.concatenate(
+            [x[:b], jax.lax.dynamic_index_in_dim(x, b + lcnt - 1, 0)])
+        over = jnp.concatenate([over, jnp.zeros((1, 1, 1), bool)])
     x = jnp.where(over, jnp.nan, _ln(x, params["lnf"]))
     return x @ params["embed"].T, kc, vc                 # (B, W, vocab)
 
@@ -405,7 +441,7 @@ def _lm_window(params, tokens, kc, vc, pos, active, n_heads, layer_axis):
 def lm_verify_window_slots(params: Dict[str, jax.Array], tokens: jax.Array,
                            kcaches: jax.Array, vcaches: jax.Array,
                            poss: jax.Array, n_heads: int,
-                           active: "jax.Array | None" = None
+                           active: "jax.Array | None" = None, lane=None
                            ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                       jax.Array]:
     """Verify windows for S independent streams at per-slot positions:
@@ -414,6 +450,8 @@ def lm_verify_window_slots(params: Dict[str, jax.Array], tokens: jax.Array,
     ``(S, L·H, max_len, hd)``; poss: (S, 1); active: (S,) bool, the slots
     that hold a request (None: all). A slot that is not active costs no
     K/V read on a TPU and writes nothing; its logits are not meaningful.
+    ``lane`` (W = 1 only) is ``_lm_window``'s: a window of prompt rows of
+    one inactive slot, and one more row of logits for it.
     Returns (logits (S, W, vocab), caches', poss+W)."""
     s, w = tokens.shape
     lh, max_len, hd = kcaches.shape[1:]
@@ -422,7 +460,7 @@ def lm_verify_window_slots(params: Dict[str, jax.Array], tokens: jax.Array,
         logits, kc, vc = _lm_window(
             params, tokens, kcaches.reshape(shape5),
             vcaches.reshape(shape5), poss[:, 0], active, n_heads,
-            layer_axis=1)
+            layer_axis=1, lane=lane)
     return (logits, kc.reshape(kcaches.shape), vc.reshape(vcaches.shape),
             poss + w)
 
@@ -455,7 +493,7 @@ def lm_prefill_masked(params: Dict[str, jax.Array], tokens: jax.Array,
 def lm_decode_step_slots(params: Dict[str, jax.Array], tokens: jax.Array,
                          kcaches: jax.Array, vcaches: jax.Array,
                          poss: jax.Array, n_heads: int,
-                         active: "jax.Array | None" = None
+                         active: "jax.Array | None" = None, lane=None
                          ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                     jax.Array]:
     """One decode step for S INDEPENDENT streams at per-slot positions.
@@ -473,9 +511,16 @@ def lm_decode_step_slots(params: Dict[str, jax.Array], tokens: jax.Array,
     (logits (S, 1, vocab), kcaches', vcaches', poss+1). Slots past
     capacity NaN-poison their own row only. Exactly the W=1 case of
     :func:`lm_verify_window_slots`.
+
+    ``lane``: ``(tokens (P,), slot, pos0, count)``, a window of P prompt
+    rows of one slot that is not active, prefilled inside this step (one
+    pass over the weights for both; ``_lm_window``). Its K/V rows land in
+    the slot's store at ``pos0 .. pos0 + P - 1`` and the logits get one
+    more row, (S + 1, 1, vocab): the lane's row ``count - 1``.
     """
     return lm_verify_window_slots(
-        params, tokens[:, :, 0], kcaches, vcaches, poss, n_heads, active)
+        params, tokens[:, :, 0], kcaches, vcaches, poss, n_heads, active,
+        lane)
 
 
 # --------------------------------------------------------------------------- #

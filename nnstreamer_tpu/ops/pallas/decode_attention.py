@@ -117,6 +117,58 @@ def window_attention_reference(q, k_new, v_new, kc, vc, li, pos,
     return o, kc2, vc2
 
 
+def lane_bounds(max_len: int, rows: int) -> tuple:
+    """The store prefixes a prompt window's attention may read: powers of
+    two from 256 rows (the window's width where that is more) up to
+    ``max_len``, and ``max_len`` itself."""
+    out, b = [], max(rows, 256)
+    while b < max_len:
+        out.append(b)
+        b *= 2
+    return tuple(out) + (max_len,)
+
+
+def lane_window_attention(q, k_new, v_new, kc, vc, li, slot, pos0):
+    """One layer's attention of a window of P prompt rows of ONE stream,
+    with the store updated: the window's rows are written in place at
+    ``store[slot, li, :, pos0:pos0+P]`` and row j attends the stream's
+    columns ``<= pos0 + j``, read from the smallest prefix of
+    :func:`lane_bounds` that holds ``pos0 + P`` rows, not ``max_len``.
+
+    q, k_new, v_new: (P, H, 1, hd), the rows as a batch of one-token
+    windows (the layout of the decode rows they ride with); kc, vc: the
+    store a slot, ``(S, L, H, max_len, hd)``; li, slot, pos0: scalars,
+    ``pos0 + P <= max_len`` (the caller keeps windows aligned to P).
+    Returns (o (P, H, 1, hd), kc, vc)."""
+    p, h, _, hd = q.shape
+    max_len = kc.shape[-2]
+    pos0 = jnp.asarray(pos0, jnp.int32)
+    rows = lambda z: z[:, :, 0].transpose(1, 0, 2)          # (H, P, hd)
+    at = (slot, li, 0, pos0, 0)
+    kc = jax.lax.dynamic_update_slice(
+        kc, rows(k_new)[None, None].astype(kc.dtype), at)
+    vc = jax.lax.dynamic_update_slice(
+        vc, rows(v_new)[None, None].astype(vc.dtype), at)
+    bounds = lane_bounds(max_len, p)
+
+    def attend(bound):
+        def dense(qh, kc, vc):
+            size = (1, 1, h, bound, hd)
+            kb = jax.lax.dynamic_slice(kc, (slot, li, 0, 0, 0), size)[0, 0]
+            vb = jax.lax.dynamic_slice(vc, (slot, li, 0, 0, 0), size)[0, 0]
+            s = jnp.einsum("hqd,hkd->hqk", qh, kb) / math.sqrt(hd)
+            live = jnp.arange(bound)[None, :] \
+                <= (pos0 + jnp.arange(p))[:, None]
+            s = jnp.where(live[None], s, _NEG_INF)
+            return jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, axis=-1), vb)
+        return dense
+
+    # the first prefix that holds the window's last row
+    which = sum((pos0 + p > b).astype(jnp.int32) for b in bounds[:-1])
+    o = jax.lax.switch(which, [attend(b) for b in bounds], rows(q), kc, vc)
+    return o.transpose(1, 0, 2)[:, :, None].astype(q.dtype), kc, vc
+
+
 def _decode_kernel(row0_ref, pos_ref, write_ref, n_items_ref, item_b_ref,
                    item_blk_ref, q_ref, kn_ref, vn_ref, kc_hbm, vc_hbm,
                    o_ref, kc_out, vc_out, kbuf, vbuf, sem, wsem, m_scr,

@@ -14,10 +14,17 @@ XLA-shaped design decisions:
   ``max_len``, and chunk sizes are compile-time constants; per-slot
   write positions and liveness are traced VALUES (masks/scatters), so
   the whole serving loop reuses a handful of cached executables.
-- **Bucketed prefill.** Prompts are right-padded to a power-of-two
-  bucket and prefilled with ``lm_prefill_masked`` — one compile per
-  bucket, exact by masking (padded K/V slots are provably overwritten
-  before any step can attend to them).
+- **Prefill inside the decode step.** The contiguous engine carries a
+  waiting prompt through its decode chunks, a window of ``LANE_ROWS``
+  rows a step under the decode rows (the prompt lane: ``_chunk_scan``,
+  ``causal_lm._lm_window``): a decode step is bound by HBM and a prefill
+  by the MXU, and in one pass they share each weight's one read.
+  Admission is host work and stops no stream.
+- **Bucketed prefill** for the engines that keep a whole-prompt program
+  (paged, mesh-sharded, gang, speculative). Prompts are right-padded to
+  a power-of-two bucket and prefilled with ``lm_prefill_masked`` — one
+  compile per bucket, exact by masking (padded K/V slots are provably
+  overwritten before any step can attend to them).
 - **Chunked decode.** Between scheduler interventions the engine runs
   ``chunk`` decode steps as ONE jitted ``lax.scan`` (greedy argmax fed
   back on-device), so host round-trips per generated token are 1/chunk.
@@ -193,17 +200,50 @@ def _slot_insert(store, value, slot):
         (slot,) + (0,) * value.ndim)
 
 
+#: prompt rows a decode step of the contiguous engine carries beside its
+#: decode rows (the lane's width, P). Chosen on the chip from the mixed
+#: step's microbenchmark at the production shape and three widths' runs
+#: of the benchmark's cell (PERF.md §6, PR 29)
+LANE_ROWS = 64
+
+
 def _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
-                top_p, n_heads, n_steps):
+                top_p, n_heads, n_steps, lane=None):
     """The n_steps decode scan over per-slot caches — ONE body shared by
     the contiguous chunk and the paged chunk (which runs it on gathered
     page views; the step kernels read capacity from the cache shape, so
     the body is layout-agnostic). ``active`` (S,) bool: the slots that
-    hold a request; the others are neither read nor written."""
-    def one(carry, _):
-        tokens, kc, vc, pos = carry
-        logits, kc, vc, pos = causal_lm.lm_decode_step_slots(
-            params, tokens, kc, vc, pos, n_heads, active)
+    are decoding; the others are neither read nor written.
+
+    With ``lane``, ``(plan (n_steps, 4 + P) int32, count () int32)``, the
+    first ``count`` steps run (a traced number: ONE executable serves every
+    length of a lane chunk, and whatever prompts wait) and each also
+    prefills one window of P prompt rows: a row of the plan is ``[slot,
+    pos0, count, last, tokens...]``, the prompt tokens ``pos0 .. pos0 + P -
+    1`` (``count`` of them real) of the request that holds ``slot`` and is
+    not decoding yet. A step holds S decode rows and P prompt rows in one
+    pass over the weights (``causal_lm._lm_window``); the window's K/V rows
+    are written in place into the slot's store. Where the window is the
+    prompt's ``last``, the first token is sampled from its last real row
+    under the slot's own key and controls (``fold_in(skey, true_len)``, as
+    the whole-prompt prefill draws it), takes the slot's place in that
+    step's ``outs``, ``pos`` becomes ``true_len`` and the slot decodes from
+    the next step of the same loop: ``active`` rides the carry.
+    Returns (tokens, kc, vc, pos, outs (S, n_steps), conf (n_steps, 3)):
+    ``conf`` is the confidence triple of a ``last`` step's first-token
+    logits, None without a lane; the columns of ``outs`` and rows of
+    ``conf`` past ``count`` are zero."""
+    def one(carry, step):
+        tokens, kc, vc, pos, active = carry
+        if step is None:
+            logits, kc, vc, pos = causal_lm.lm_decode_step_slots(
+                params, tokens, kc, vc, pos, n_heads, active)
+        else:
+            slot, pos0, count, last = step[0], step[1], step[2], step[3] > 0
+            logits, kc, vc, pos = causal_lm.lm_decode_step_slots(
+                params, tokens, kc, vc, pos, n_heads, active,
+                lane=(step[4:], slot, pos0, count))
+            lane_row, logits = logits[-1, 0], logits[:-1]
 
         # pos is post-step = tokens consumed; keys derive from (seed,
         # consumed) only, so sampling is batch-composition-independent
@@ -218,19 +258,52 @@ def _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
         # an all-greedy batch (the default) skips the sampler's
         # full-vocab top_k/softmax/cumsum in the decode hot loop
         nxt = jax.lax.cond(jnp.all(temp <= 0.0), greedy, sampled, logits)
-        return (nxt[:, None, None], kc, vc, pos), nxt
+        if step is None:
+            return (nxt[:, None, None], kc, vc, pos, active), (nxt, None)
+        true_len = pos0 + count
+        first = jax.lax.cond(
+            last & (temp[slot] > 0.0),
+            lambda row: sampling.sample_row(
+                row, jax.random.fold_in(skeys[slot], true_len), temp[slot],
+                top_k[slot], top_p[slot]),
+            lambda row: jnp.argmax(row, -1).astype(jnp.int32), lane_row)
+        conf = jax.lax.cond(last, _conf_from_row,
+                            lambda row: jnp.zeros((3,), jnp.float32),
+                            lane_row)
+        join = last & (jnp.arange(nxt.shape[0]) == slot)
+        nxt = jnp.where(join, first, nxt)
+        pos = jnp.where(join[:, None], true_len, pos)
+        return (nxt[:, None, None], kc, vc, pos, active | join), (nxt, conf)
 
-    (tokens, kc, vc, pos), outs = jax.lax.scan(
-        one, (tokens, kc, vc, pos), None, length=n_steps)
-    return tokens, kc, vc, pos, outs.T  # outs (S, n_steps)
+    if lane is None:
+        (tokens, kc, vc, pos, _), (outs, _) = jax.lax.scan(
+            one, (tokens, kc, vc, pos, active), None, length=n_steps)
+        return tokens, kc, vc, pos, outs.T, None  # outs (S, n_steps)
+
+    plan, count = lane
+
+    def lane_step(i, carry):
+        state, outs, confs = carry
+        state, (nxt, conf) = one(state, plan[i])
+        return state, outs.at[i].set(nxt), confs.at[i].set(conf)
+
+    (tokens, kc, vc, pos, _), outs, confs = jax.lax.fori_loop(
+        0, count, lane_step,
+        ((tokens, kc, vc, pos, active),
+         jnp.zeros((n_steps, tokens.shape[0]), jnp.int32),
+         jnp.zeros((n_steps, 3), jnp.float32)))
+    return tokens, kc, vc, pos, outs.T, confs
 
 
 @partial(jax.jit, static_argnames=("n_heads", "n_steps"),
          donate_argnums=(1, 2, 3, 4))
 def _decode_chunk(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
-                  top_p, n_heads, n_steps):
+                  top_p, lane=None, *, n_heads, n_steps):
+    """``_chunk_scan`` over the contiguous stores: an executable a step
+    count without a prompt lane (``lane`` None), and one with a lane for
+    every count up to ``n_steps``."""
     return _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp,
-                       top_k, top_p, n_heads, n_steps)
+                       top_k, top_p, n_heads, n_steps, lane)
 
 
 @partial(jax.jit, static_argnames=("n_heads", "n_steps"),
@@ -245,7 +318,7 @@ def _decode_chunk_paged(params, tokens, kpool, vpool, tables, pos, active,
     kviews = causal_lm.paged_view_slots(kpool, tables)
     vviews = causal_lm.paged_view_slots(vpool, tables)
     p0s = pos[:, 0]
-    tokens, kviews, vviews, pos, outs = _chunk_scan(
+    tokens, kviews, vviews, pos, outs, _ = _chunk_scan(
         params, tokens, kviews, vviews, pos, active, skeys, temp, top_k,
         top_p, n_heads, n_steps)
     nt = causal_lm.paged_touch_span(
@@ -386,6 +459,7 @@ class _Request:
     # span parents admission-wait / prefill / compile / decode children
     span: Any = None            # serving.request — submit → retire
     wait_span: Any = None       # serving.admission_wait — submit → admit
+    prefill_span: Any = None    # serving.prefill, while the lane holds it
     decode_span: Any = None     # serving.decode — admit → retire
 
 
@@ -395,7 +469,10 @@ class LMEngine:
     params/n_heads/max_len as for `models.causal_lm`; ``n_slots`` is the
     decode batch (slot) count; ``chunk`` the decode steps per scheduler
     iteration. ``bucket`` maps a prompt length to its padded prefill
-    length (defaults to power-of-two buckets capped at max_len).
+    length (defaults to power-of-two buckets capped at max_len) on the
+    engines that prefill a whole prompt; the contiguous engine prefills
+    through the prompt lane of its decode chunks and pads a prompt's last
+    window only.
 
     Paged KV cache (serving/kv_cache.py): ``kv_page_size`` > 0 swaps
     the per-slot contiguous stores for a shared page pool of
@@ -539,12 +616,30 @@ class LMEngine:
         if self._kv is None:
             self._kc, self._vc = self._alloc_slot_caches(L, hd)
         self._pos = jnp.zeros((n_slots, 1), jnp.int32)
+        #: whether prompts are prefilled inside the decode chunks, a
+        #: window of LANE_ROWS rows a step (`_chunk_scan`), or whole and
+        #: padded by the engine's own prefill programs. It follows from
+        #: what the engine is: the lane is the contiguous single-device
+        #: engine's, chunked and continuous, over a store that whole
+        #: windows tile
+        self._lane = (self._lane_capable and self._kv is None and not gang
+                      and spec_draft == 0 and max_len % LANE_ROWS == 0)
+        #: slot -> the prompt tokens already given to the lane, for the
+        #: slots whose request is still being prefilled, in the order
+        #: they were admitted
+        self._lane_at: Dict[int, int] = {}
+        #: the lane's rows of the chunk last run (`_plan_lane`), and the
+        #: confidence triples its program returned
+        self._lane_plan = self._lane_conf = None
         # per-slot sampling controls (traced values — greedy and sampled
-        # streams share one executable; see serving/sampling.py)
-        self._skeys = jnp.zeros((n_slots, 2), jnp.uint32)
-        self._temp = jnp.zeros((n_slots,), jnp.float32)
-        self._topk = jnp.zeros((n_slots,), jnp.int32)
-        self._topp = jnp.ones((n_slots,), jnp.float32)
+        # streams share one executable; see serving/sampling.py). A lane
+        # engine keeps them on the host and hands them to every chunk;
+        # the others keep them on the device and insert at admission
+        xp = np if self._lane else jnp
+        self._skeys = xp.zeros((n_slots, 2), xp.uint32)
+        self._temp = xp.zeros((n_slots,), xp.float32)
+        self._topk = xp.zeros((n_slots,), xp.int32)
+        self._topp = xp.ones((n_slots,), xp.float32)
         # host-side scheduler state (incl. a per-slot position mirror:
         # positions are deterministic — true_len at admission, +n per
         # chunk — so the capacity cap never needs a blocking D2H read)
@@ -583,6 +678,10 @@ class LMEngine:
                       # store rows the chunks' steps were asked to read
                       # (of decode_steps x slots x max_len at most)
                       "kv_rows_attended": 0,
+                      # the prompt lane: decode steps that carried prompt
+                      # rows, the rows they offered (LANE_ROWS a step)
+                      # and the true prompt tokens among them
+                      "lane_steps": 0, "lane_rows": 0, "lane_tokens": 0,
                       "tokens_out": 0,
                       "spec_iterations": 0, "spec_drafted": 0,
                       "spec_accepted": 0,
@@ -615,6 +714,10 @@ class LMEngine:
     #: distinguishes engine kinds in the metric series; the TP engine
     #: overrides to "tp"
     _engine_label = "lm"
+
+    #: whether this class's chunk program can carry a prompt lane; an
+    #: engine with a decode body of its own says no
+    _lane_capable = True
 
     #: iterations kept by recent_steps() / slowest_steps()
     STEP_RING = 1024
@@ -887,9 +990,12 @@ class LMEngine:
         means Python ran, a collection included), ``phases`` (seconds
         by phase name), ``admitted`` ([rid, slot] pairs), ``chunk``
         (decode steps dispatched, 0 for none), ``active`` and ``queued``
-        (slots decoding, requests still waiting), ``gc`` (collections
-        per generation that fell in it) and ``first_use`` (whether an
-        executable was used for the first time)."""
+        (slots decoding, requests still waiting), ``lane_steps``,
+        ``lane_rows`` and ``lane_tokens`` (those of the steps that carried
+        prompt rows, the rows offered and the prompt tokens among them),
+        ``gc`` (collections per generation that fell in it) and
+        ``first_use`` (whether an executable was used for the first
+        time)."""
         with self._steps_lock:
             return [_copy_step(r) for r in self._steps]
 
@@ -902,7 +1008,9 @@ class LMEngine:
 
     def step_iteration(self) -> bool:
         """One scheduler iteration: admit into free slots, then one
-        decode chunk. Returns True while work remains. When enrolled as
+        decode chunk (with prompts in the lane: a chunk that carries their
+        windows, and behind the last one's first token the decode chunk).
+        Returns True while work remains. When enrolled as
         a sched.DeviceEngine tenant, the iteration runs under the
         engine's deficit-round-robin fair share so serving steps and
         pipeline batches interleave on one chip."""
@@ -921,7 +1029,8 @@ class LMEngine:
         st["iterations"] += 1
         rec: Dict[str, Any] = {
             "iteration": st["iterations"], "admitted": [], "chunk": 0,
-            "active": 0, "first_use": False}
+            "active": 0, "first_use": False,
+            "lane_steps": 0, "lane_rows": 0, "lane_tokens": 0}
         before = [st[key] for key in _PHASE_KEYS]
         gc0 = [g["collections"] for g in gc.get_stats()]
         cpu0 = time.thread_time_ns()
@@ -1175,12 +1284,24 @@ class LMEngine:
     # -- scheduler internals ---------------------------------------------- #
 
     def _admit(self, step: "_tracing.phase", rec: Dict[str, Any]) -> None:
+        """Take requests from the head of the queue into the free slots,
+        lowest slot first. A request holds its slot (``_slot_req``) from
+        here on.
+
+        On an engine with a prompt lane this is host work only: the
+        slot's sampling controls are noted in the host's arrays, the slot
+        is entered in ``_lane_at`` with nothing prefilled, and the chunks
+        that follow carry the prompt through the lane, a window a step
+        (``_plan_lane``); no device program is dispatched and nothing is
+        read back, so no stream stands still for an admission. The other
+        engines (paged, mesh-sharded, gang, speculative) run their
+        whole-prompt prefill program to its end here, and every stream
+        stands still for it."""
         if self.gang and any(r is not None for r in self._slot_req):
             return  # static batching: wait for the whole gang to finish
         if not self._queue or all(r is not None for r in self._slot_req):
             return
         st = self.stats
-        # every stream stands still for as long as this phase lasts
         with _tracing.phase(st, "serving.admit", parent=step) as admit:
             for slot in range(self.n_slots):
                 if self._slot_req[slot] is not None or not self._queue:
@@ -1212,6 +1333,9 @@ class LMEngine:
                     st["admission_wait_s"] += waited
                     if waited > st["admission_wait_max_s"]:
                         st["admission_wait_max_s"] = waited
+                    if self._lane:
+                        self._admit_to_lane(slot, req, rec)
+                        continue
                     t = int(req.prompt.size)
                     hit = self._paged_admit(slot, req, plan) \
                         if self._kv is not None else 0
@@ -1233,33 +1357,16 @@ class LMEngine:
                     blabel = str(tb) if self._kv is None or not hit \
                         else f"kv{tb}"
                     first_use = bkey not in self._seen_programs
-                    pspan = cspan = _tracing.NOOP_SPAN
-                    if req.span is not None:
-                        if first_use:
-                            # the jit call returns only after trace+compile
-                            # on a new static shape; the dispatch itself is
-                            # async, so ending right after _prefill_into
-                            # bounds the compile
-                            cspan = _tracing.start_span(
-                                "serving.compile", parent=req.span.context,
-                                attrs={"bucket": tb, "kernel": "prefill"})
-                        pspan = _tracing.start_span(
-                            "serving.prefill", parent=req.span.context,
-                            attrs={"bucket": tb, "slot": slot})
-                        if req.session is not None \
-                                and req.session in self._restored_sessions:
-                            # first prefill after a checkpoint splice — it
-                            # rides the imported radix pages; diag bills it
-                            # as restore (cheap) rather than re_prefill
-                            self._restored_sessions.discard(req.session)
-                            pspan.set_attribute("restore", True)
-                        elif req.session is not None \
-                                and req.session in self._reprefill_sessions:
-                            # post-absorb recompute, not fresh work — the
-                            # diag critical path bills this span as
-                            # re_prefill
-                            self._reprefill_sessions.discard(req.session)
-                            pspan.set_attribute("re_prefill", True)
+                    cspan = _tracing.NOOP_SPAN
+                    if req.span is not None and first_use:
+                        # the jit call returns only after trace+compile
+                        # on a new static shape; the dispatch itself is
+                        # async, so ending right after _prefill_into
+                        # bounds the compile
+                        cspan = _tracing.start_span(
+                            "serving.compile", parent=req.span.context,
+                            attrs={"bucket": tb, "kernel": "prefill"})
+                    pspan = self._prefill_span(req, slot, tb)
                     # obs/quality confidence tap: one None check selects
                     # the conf-variant prefill, which also returns the
                     # first-token logits' (entropy, top1, margin) for the
@@ -1327,6 +1434,47 @@ class LMEngine:
                 self._pos_host[slot] = t
                 self._slot_req[slot] = req
                 self._retire_if_done(slot, req)
+
+    def _prefill_span(self, req: "_Request", slot: int, bucket: int):
+        """The ``serving.prefill`` span of a traced request, admission to
+        first token, tagged where the prefill repeats or restores work."""
+        if req.span is None:
+            return _tracing.NOOP_SPAN
+        pspan = _tracing.start_span(
+            "serving.prefill", parent=req.span.context,
+            attrs={"bucket": bucket, "slot": slot})
+        if req.session is not None \
+                and req.session in self._restored_sessions:
+            # first prefill after a checkpoint splice — it rides the
+            # imported radix pages; diag bills it as restore (cheap)
+            # rather than re_prefill
+            self._restored_sessions.discard(req.session)
+            pspan.set_attribute("restore", True)
+        elif req.session is not None \
+                and req.session in self._reprefill_sessions:
+            # post-absorb recompute, not fresh work — the diag critical
+            # path bills this span as re_prefill
+            self._reprefill_sessions.discard(req.session)
+            pspan.set_attribute("re_prefill", True)
+        return pspan
+
+    def _admit_to_lane(self, slot: int, req: "_Request",
+                       rec: Dict[str, Any]) -> None:
+        """Give ``slot`` to ``req`` with nothing of its prompt prefilled:
+        host work only. The slot's sampling controls go into the host's
+        arrays (a slot that is not decoding yet does not use them)."""
+        self._skeys[slot] = sampling.seed_key_host(req.seed)
+        self._temp[slot] = req.temperature
+        self._topk[slot] = req.top_k
+        self._topp[slot] = req.top_p
+        self._lane_at[slot] = 0
+        req.prefill_span = self._prefill_span(req, slot, LANE_ROWS)
+        self.stats["prefills"] += 1
+        lbl = self._engine_label
+        self._m_prefills.labels(lbl, "lane").inc()
+        self._m_streams.labels(lbl, "admitted").inc()
+        rec["admitted"].append([req.rid, slot])
+        self._slot_req[slot] = req
 
     def _prefill_into(self, slot: int, padded, true_len: int, skey,
                       temp, tk, tp, want_conf: bool = False):
@@ -1441,10 +1589,13 @@ class LMEngine:
                 self._table_host[s, len(lease.pages) - 1] = pid
 
     def _decode(self, step: "_tracing.phase", rec: Dict[str, Any]) -> None:
-        active = [s for s, r in enumerate(self._slot_req) if r is not None]
-        if not active:
+        holding = [s for s, r in enumerate(self._slot_req) if r is not None]
+        if not holding:
             return
-        rec["active"] = len(active)
+        # a slot whose prompt is still in the lane holds a request and
+        # does not decode yet
+        active = [s for s in holding if s not in self._lane_at]
+        rec["active"] = max(rec["active"], len(active))
         # capacity headroom is PER-REQUEST capacity: max_len contiguous,
         # the kv_slot_pages * page_size view bound under paging. The old
         # max_len comparison would either let speculation NaN-poison a
@@ -1452,7 +1603,8 @@ class LMEngine:
         # page-pool headroom is NOT a gate — admission reserved every
         # active request's full page budget, so _ensure_pages below
         # always succeeds
-        headroom = self._m_slot - max(self._pos_host[s] for s in active)
+        headroom = self._m_slot - max(
+            (self._pos_host[s] for s in active), default=0)
         if self.spec_draft > 0 and headroom >= self.spec_draft + 1 \
                 and all(self._slot_req[s].temperature <= 0.0
                         for s in active) \
@@ -1477,10 +1629,21 @@ class LMEngine:
         # `prompt + max_new - 1 <= max_len` guard keeps cap >= 1 for
         # every active slot, so this never clamps to a forced overflow
         cap = headroom
-        remaining = max(r.max_new - len(r.out) for r in self._slot_req
-                        if r is not None)
+        lane = bool(self._lane_at)
+        if lane:
+            # the lane sets the length: a lane chunk has a window in
+            # every step and ends where the prompts that wait end (the
+            # decode chunk that follows is a dispatch of its own, below).
+            # Its step count is a traced value of ONE executable,
+            # whatever the prompts' lengths and however many wait
+            remaining = sum(-(-(int(self._slot_req[s].prompt.size) - at)
+                              // LANE_ROWS)
+                            for s, at in self._lane_at.items())
+        else:
+            remaining = max(r.max_new - len(r.out) for r in self._slot_req
+                            if r is not None)
         n = max(1, min(self.chunk, cap, remaining))
-        if n < self.chunk:
+        if n < self.chunk and not lane:
             # floor TAILS to a power of two: chunk length is a static
             # shape, so every distinct n is its own executable — pow2
             # tails bound the cache at log2(chunk) entries instead of
@@ -1490,16 +1653,28 @@ class LMEngine:
         if self._kv is not None:
             self._ensure_pages(active, n)
         st = self.stats
-        rec["chunk"] = n
+        rec["chunk"] += n
         # the call returns once the chunk is enqueued (after trace and
         # compile on a first use); the readback blocks until the device
         # has run it, then copies (S, n) tokens to the host
+        key = ("lane", self.chunk) if lane else ("chunk", n)
+        cspan = _tracing.NOOP_SPAN
+        if lane and key not in self._seen_programs:
+            # the lane's program on its first use: the request at the
+            # lane's head waits for the trace and compile, as a request
+            # waits for its bucket's on the whole-prompt engines
+            head = self._slot_req[next(iter(self._lane_at))]
+            if head.span is not None:
+                cspan = _tracing.start_span(
+                    "serving.compile", parent=head.span.context,
+                    attrs={"bucket": LANE_ROWS, "kernel": "lane"})
         with _tracing.phase(st, "serving.decode_dispatch",
                             parent=step) as dd:
             outs = self._run_chunk(n)
+        cspan.end()
         with _tracing.phase(st, "serving.decode_wait", parent=step) as dw:
             outs = np.asarray(outs)
-        self._note_dispatch(("chunk", n), dd, rec)
+        self._note_dispatch(key, dd, rec)
         self._m_tok_lat.observe((dw.end_ns - dd.start_ns) / 1e9 / n)
         if _profile.ENGINE_HOOK is not None:
             # np.asarray blocked on the chunk: wall ≈ device time; the
@@ -1507,7 +1682,9 @@ class LMEngine:
             _profile.ENGINE_HOOK.record_engine(
                 self, "decode", dd.start_ns, dw.end_ns,
                 tokens=n * len(active), steps=n, active=len(active),
-                queued=len(self._queue), slots=self.n_slots)
+                queued=len(self._queue), slots=self.n_slots,
+                # a prefill through the lane has no interval of its own
+                lane_steps=n if lane else 0)
         shook = _slo.ENGINE_SLO_HOOK
         if shook is not None:
             shook.record_engine_phase(
@@ -1520,21 +1697,96 @@ class LMEngine:
                 self._pos_host[s] += n  # device pos advances for EVERY slot
             st["decode_steps"] += n
             st["slot_steps"] += n * len(active)
-            for slot in active:
+            # the step from which each slot decodes: the chunk's first,
+            # or the one after its prompt's last window
+            starts = dict.fromkeys(active, 0)
+            if lane:
+                starts.update(self._join_lane(n, outs, dw.end_ns, rec))
+            kept = 0
+            for slot, start in starts.items():
                 req = self._slot_req[slot]
-                for i in range(n):
+                for i in range(start, n):
                     if req.done or len(req.out) >= req.max_new:
-                        # invariant: slots x steps = kept tokens + wasted
-                        # (bench waste_frac reads this stat directly)
-                        st["wasted_slot_steps"] += 1
-                        continue
+                        break  # the tail of the chunk counts as waste
                     tok = int(outs[slot, i])
                     req.out.append(tok)
+                    kept += 1
                     if req.eos is not None and tok == req.eos:
-                        req.done = True  # tail of the chunk counts as waste
+                        req.done = True
                 self._retire_if_done(slot, req)
-            # slot-steps spent by empty slots decoding garbage
-            st["wasted_slot_steps"] += n * (self.n_slots - len(active))
+            # invariant: slots x steps = kept tokens + wasted (bench
+            # waste_frac reads this stat directly): wasted are the steps
+            # of empty and still-prefilling slots and those past a
+            # request's end
+            st["wasted_slot_steps"] += n * self.n_slots - kept
+        if lane and len(starts) > len(active) and not self._lane_at:
+            # the lane's last waiting prompt ended and its first token is
+            # out: the decode chunk behind it runs in this iteration too,
+            # as the chunk behind a whole-prompt prefill does, so that a
+            # stream's first token comes with its first chunk of tokens
+            self._decode(step, rec)
+
+    def _plan_lane(self, n: int) -> np.ndarray:
+        """The lane's rows for the ``n`` steps of the chunk about to run,
+        (chunk, 4 + LANE_ROWS) int32 as ``_chunk_scan`` reads them (rows
+        past ``n`` are not run): the prompts that wait in ``_lane_at``
+        follow each other in the order they were admitted, a window of
+        LANE_ROWS tokens a step, aligned to that width from the prompt's
+        start. Advances ``_lane_at``; ``_decode`` sizes ``n`` so that
+        every step has a window."""
+        plan = np.zeros((self.chunk, 4 + LANE_ROWS), np.int32)
+        step = 0
+        for slot, at in self._lane_at.items():
+            prompt = self._slot_req[slot].prompt
+            t = int(prompt.size)
+            while at < t and step < n:
+                count = min(LANE_ROWS, t - at)
+                plan[step, :4] = (slot, at, count, at + count == t)
+                plan[step, 4:4 + count] = prompt[at:at + count]
+                at += count
+                step += 1
+            self._lane_at[slot] = at
+            if step == n:
+                break
+        return plan
+
+    def _join_lane(self, n: int, outs: np.ndarray, end_ns: int,
+                   rec: Dict[str, Any]) -> Dict[int, int]:
+        """After a chunk of ``n`` steps with a lane: count what the lane
+        carried, and hand each prompt that ended in it its first token
+        (``outs[slot, step]``). Returns {slot: the step from which it
+        decoded}, the slots that joined the active set inside the chunk."""
+        st = self.stats
+        plan = self._lane_plan
+        for key, value in (("lane_steps", n), ("lane_rows", n * LANE_ROWS),
+                           ("lane_tokens", int(plan[:, 2].sum()))):
+            st[key] += value
+            rec[key] = value
+        starts = {}
+        for j in map(int, np.flatnonzero(plan[:, 3])):
+            slot = int(plan[j, 0])
+            req = self._slot_req[slot]
+            del self._lane_at[slot]
+            req.out.append(int(outs[slot, j]))
+            self._m_ttft.observe(end_ns / 1e9 - req.t_submit)
+            if req.eos is not None and req.out[0] == req.eos:
+                req.done = True
+            req.prefill_span.end()
+            req.prefill_span = None
+            if _quality.QUALITY_HOOK is not None:
+                req.conf = self._lane_conf[j]
+            if req.span is not None:
+                req.decode_span = _tracing.start_span(
+                    "serving.decode", parent=req.span.context,
+                    attrs={"slot": slot})
+            # it decoded the n - 1 - j steps after, from its prompt's end
+            after = n - 1 - j
+            self._pos_host[slot] = int(req.prompt.size)
+            st["slot_steps"] += after
+            st["kv_rows_attended"] += self._kv_rows_asked([slot], after)
+            self._pos_host[slot] += after
+            starts[slot] = j + 1
+        return starts
 
     def _note_dispatch(self, key: Any, dispatch: "_tracing.phase",
                        rec: Dict[str, Any]) -> None:
@@ -1548,12 +1800,15 @@ class LMEngine:
 
     def _run_chunk(self, n: int):
         """Run ``n`` decode steps over all slots, updating the carried
-        device state; returns the (S, n) generated tokens. The second
+        device state; returns the (S, n) generated tokens ((S, chunk), of
+        which the first ``n`` columns count, where the steps carried the
+        prompt lane). The second
         device-layout hook a mesh-sharded engine overrides (the paged
         branch never reaches a TP engine — it pins kv_page_size=0)."""
-        # the slots that hold a request: the others are not attended
-        active = np.fromiter((r is not None for r in self._slot_req),
-                             bool, self.n_slots)
+        # the slots that decode: the others are not attended
+        active = np.fromiter(
+            (r is not None and s not in self._lane_at
+             for s, r in enumerate(self._slot_req)), bool, self.n_slots)
         if self._kv is not None:
             kv = self._kv
             (self._tokens, kv.kpool, kv.vpool, self._pos, outs) = \
@@ -1563,11 +1818,16 @@ class LMEngine:
                     self._skeys, self._temp, self._topk, self._topp,
                     n_heads=self.n_heads, n_steps=n)
             return outs
-        self._tokens, self._kc, self._vc, self._pos, outs = \
-            _decode_chunk(self.params, self._tokens, self._kc,
-                          self._vc, self._pos, active, self._skeys,
-                          self._temp, self._topk, self._topp,
-                          n_heads=self.n_heads, n_steps=n)
+        # with prompts waiting, the chunk's steps each carry a window
+        lane = None
+        if self._lane_at:
+            self._lane_plan = self._plan_lane(n)
+            lane = (self._lane_plan, np.int32(n))
+        (self._tokens, self._kc, self._vc, self._pos, outs,
+         self._lane_conf) = _decode_chunk(
+            self.params, self._tokens, self._kc, self._vc, self._pos,
+            active, self._skeys, self._temp, self._topk, self._topp, lane,
+            n_heads=self.n_heads, n_steps=self.chunk if lane else n)
         return outs
 
     def _kv_rows_asked(self, active: List[int], n: int) -> int:
@@ -1782,10 +2042,14 @@ class LMEngine:
                     while len(self._session_paths) > SESSION_PATHS_LIMIT:
                         self._session_paths.popitem(last=False)
                 self._table_host[slot] = 0
-            if req.temperature > 0.0:
+            if req.temperature > 0.0 and self._lane:
                 # restore greedy defaults so a finished sampled stream
-                # doesn't keep the all-greedy fast path (and the
-                # speculation gate) disabled for the slots that remain
+                # doesn't keep the all-greedy fast path disabled for the
+                # slots that remain
+                self._temp[slot], self._topk[slot] = 0.0, 0
+                self._topp[slot] = 1.0
+            elif req.temperature > 0.0:
+                # the same on the device (and for the speculation gate)
                 sl = jnp.int32(slot)
                 self._temp = _slot_insert(self._temp, jnp.float32(0.0), sl)
                 self._topk = _slot_insert(self._topk, jnp.int32(0), sl)

@@ -29,8 +29,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-__all__ = ["sample_row", "sample_logits", "seed_key", "step_keys"]
+__all__ = ["sample_row", "sample_logits", "seed_key", "seed_key_host",
+           "step_keys"]
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -38,6 +40,14 @@ def seed_key(seed: int) -> jax.Array:
     stores/slots into plain device arrays, which the engine's
     ``_slot_insert`` scatter requires)."""
     return jax.random.PRNGKey(seed)
+
+
+def seed_key_host(seed: int) -> np.ndarray:
+    """:func:`seed_key`'s two words computed on the host, with no device
+    program: the seed's high and low 32 bits (the high word is 0 unless
+    64-bit types are enabled, as ``PRNGKey`` has it)."""
+    hi = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([hi, seed & 0xFFFFFFFF], np.uint32)
 
 
 def step_keys(seed_keys: jax.Array, consumed: jax.Array) -> jax.Array:

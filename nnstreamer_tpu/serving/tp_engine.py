@@ -211,6 +211,8 @@ class TPLMEngine(LMEngine):
     #: serving metrics series carry engine="tp" so single-device and
     #: mesh-sharded engines are separable on one scrape endpoint
     _engine_label = "tp"
+    #: its chunk body is parallel/tp_decode's: prompts are prefilled whole
+    _lane_capable = False
 
     def __init__(self, params: Dict[str, Any], n_heads: int, max_len: int,
                  mesh: Mesh, axis: str = "model", **kw) -> None:

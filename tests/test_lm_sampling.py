@@ -162,3 +162,78 @@ def test_sampled_eos_stops_stream(params):
     eos = ref[len(ref) // 2]  # a token the sampled stream will emit
     got = solo_run(params, prompt, 24, eos=eos, temperature=1.1, seed=3)
     assert got == ref[:ref.index(eos) + 1]
+
+
+# -- the prompt lane: the first token is drawn inside the decode chunk ----- #
+
+from nnstreamer_tpu.serving.lm_engine import LANE_ROWS  # noqa: E402
+
+LANE_MAXLEN = 2 * LANE_ROWS
+LANE_MODES = {
+    "greedy": dict(),
+    "temperature": dict(temperature=1.0, seed=10),
+    "top_k": dict(temperature=0.7, top_k=8, seed=11),
+    "top_p": dict(temperature=1.3, top_p=0.9, seed=2**31 + 12),
+}
+
+
+@pytest.fixture(scope="module")
+def lane_params():
+    return causal_lm.init_causal_lm(
+        jax.random.PRNGKey(5), V, D, H, L, LANE_MAXLEN)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 41, 2**31 - 1, 2**31, 2**32 - 1,
+                                  2**32 + 5, -1, -12345])
+def test_seed_key_host_is_seed_key(seed):
+    """The key an admission through the lane writes into the host's array
+    is the key ``PRNGKey`` makes on the device."""
+    np.testing.assert_array_equal(
+        sampling.seed_key_host(seed), np.asarray(sampling.seed_key(seed)))
+
+
+@pytest.mark.parametrize("mode", sorted(LANE_MODES))
+@pytest.mark.parametrize("t", [9, LANE_ROWS, LANE_ROWS + 17])
+def test_lane_sampled_tokens_match_whole_prompt_path(lane_params, t, mode):
+    """A prompt prefilled through the lane draws the tokens the
+    whole-prompt prefill program draws: the first from
+    ``fold_in(seed key, prompt length)``, the rest by the same schedule."""
+    prompt = np.random.default_rng(t).integers(0, V, t).astype(np.int32)
+    got = {}
+    for kind, kw in (("lane", {}), ("whole", {"gang": True})):
+        eng = LMEngine(lane_params, H, LANE_MAXLEN, n_slots=2, chunk=4, **kw)
+        assert eng._lane == (kind == "lane")
+        rid = eng.submit(prompt, 8, **LANE_MODES[mode])
+        got[kind] = eng.run()[rid]
+    assert got["lane"] == got["whole"] and len(got["lane"]) == 8
+
+
+def test_lane_sampled_streams_join_a_sampled_batch(lane_params):
+    """Sampled and greedy prompts that follow each other through the lane
+    while a sampled stream decodes: every stream's tokens are its lone
+    run's through the whole-prompt path, and a finished sampled stream
+    leaves its slot greedy."""
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, V, n).astype(np.int32)
+               for n in (6, LANE_ROWS + 3, 20, LANE_ROWS)]
+    modes = [LANE_MODES[m] for m in ("top_p", "temperature", "greedy",
+                                     "top_k")]
+    new = [20, 6, 9, 7]
+
+    def lone(p, m, mode):
+        eng = LMEngine(lane_params, H, LANE_MAXLEN, n_slots=1, chunk=1,
+                       gang=True)
+        rid = eng.submit(p, m, **mode)
+        return eng.run()[rid]
+
+    eng = LMEngine(lane_params, H, LANE_MAXLEN, n_slots=3, chunk=4)
+    rids = [eng.submit(prompts[0], new[0], **modes[0])]
+    eng.step_iteration()
+    eng.step_iteration()
+    rids += [eng.submit(p, m, **mode)
+             for p, m, mode in zip(prompts[1:], new[1:], modes[1:])]
+    res = eng.run()
+    for rid, p, m, mode in zip(rids, prompts, new, modes):
+        assert res[rid] == lone(p, m, mode)
+    assert not eng._temp.any() and not eng._topk.any() \
+        and (eng._topp == 1.0).all()

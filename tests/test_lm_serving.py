@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from nnstreamer_tpu.models import causal_lm
 from nnstreamer_tpu.serving import LMEngine, next_pow2_bucket
+from nnstreamer_tpu.serving.lm_engine import LANE_ROWS
 
 V, D, H, L, MAXLEN = 97, 32, 4, 2, 64
 
@@ -112,7 +113,9 @@ def test_max_new_one_retires_at_admission(params):
     rid = eng.submit(prompt, max_new=1)
     res = eng.run()
     assert res[rid] == isolated_generate(params, prompt, 1)
-    assert eng.stats["decode_steps"] == 0
+    # the one step is the lane's: it carried the prompt and gave the token
+    assert eng.stats["decode_steps"] == eng.stats["lane_steps"] == 1
+    assert eng.stats["slot_steps"] == 0
 
 
 def test_capacity_boundary(params):
@@ -219,8 +222,11 @@ def test_nonpow2_chunk_kept_at_steady_state(params):
     rid = eng.submit(prompt, max_new=14)  # 1 prefill + 13 decode
     got = eng.run()[rid]
     assert got == isolated_generate(params, prompt, 14)
-    # 13 remaining -> chunks of 6, 6, then tail 1 (pow2): 3 iterations
-    assert eng.stats["decode_steps"] == 13
+    # the prompt's one lane step and the chunk behind it in the first
+    # iteration, then of 13 remaining a chunk of 6 and the tail 1 (pow2)
+    assert eng.stats["decode_steps"] == 1 + 13
+    assert [r["chunk"] for r in eng.recent_steps()] == [1 + 6, 6, 1]
+    assert eng._seen_programs == {("lane", 6), ("chunk", 6), ("chunk", 1)}
 
 
 def test_host_pos_mirror_tracks_device(params):
@@ -321,7 +327,8 @@ def test_mixed_lengths_empty_slot_and_midchunk_finish(params):
     eng = LMEngine(params, H, MAXLEN, n_slots=4, chunk=4)
     rids = [eng.submit(p, max_new=m) for p, m in zip(prompts, new)]
     eng.step_iteration()
-    slots = [eng.slot_of(rid) for rid in rids]
+    took = dict(map(tuple, eng.recent_steps()[0]["admitted"]))
+    slots = [took[rid] for rid in rids]
     assert sorted(slots) == [0, 1, 2]
     res = eng.run()
     for rid, p, m in zip(rids, prompts, new):
@@ -356,7 +363,12 @@ def test_kv_rows_attended_counts_block_rounded_lengths():
     eng.run()
     # the long request: 20 steps at positions 150..169, of which 150..160
     # read one block and 161..169 two; the short one holds its slot for
-    # the first chunk's 4 steps (positions 10..13), one block each
-    assert eng.stats["decode_steps"] == 20
+    # its first chunk's 4 steps (positions 10..13), one block each. The
+    # prompts take their windows' lane steps first, and the long request
+    # decodes in the last of them, beside the short prompt's window: a
+    # slot that is being prefilled reads nothing
+    lane_steps = -(-150 // LANE_ROWS) + 1
+    assert eng.stats["lane_steps"] == lane_steps
+    assert eng.stats["decode_steps"] == lane_steps + 19
     assert eng.stats["kv_rows_attended"] \
         == 11 * 160 + 9 * 320 + 4 * 160

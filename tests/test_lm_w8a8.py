@@ -256,3 +256,40 @@ def test_serving_engine_runs_quantized(qparams):
                 k, v, pos, H)
             want.append(int(np.asarray(jnp.argmax(logits, -1))[0]))
         assert res[rid] == want
+
+
+@pytest.fixture(scope="module")
+def lane_qparams():
+    from nnstreamer_tpu.serving.lm_engine import LANE_ROWS
+
+    return causal_lm.quantize_lm_params(causal_lm.init_causal_lm(
+        jax.random.PRNGKey(2), V, D, H, L, 2 * LANE_ROWS))
+
+
+@pytest.mark.parametrize("windows,more", [(0, 7), (1, 0), (1, 14)])
+def test_serving_engine_lane_runs_quantized(lane_qparams, windows, more):
+    """The prompt lane goes through the same ``matmul_any`` / ``mlp_matmul``
+    sites as the decode rows it rides with: an activation row has its own
+    int8 grid, so a quantized tree's tokens through the lane are the
+    whole-prompt path's, and the rows it leaves are the quantized
+    ``lm_prefill``'s."""
+    from nnstreamer_tpu.serving.lm_engine import LANE_ROWS, LMEngine
+
+    max_len = 2 * LANE_ROWS
+    t = windows * LANE_ROWS + more    # under, one, and over one window
+    prompt = np.random.default_rng(t).integers(0, V, t).astype(np.int32)
+    got = {}
+    for kind, kw in (("lane", {}), ("whole", {"gang": True})):
+        eng = LMEngine(lane_qparams, H, max_len, n_slots=2, chunk=4, **kw)
+        assert eng._lane == (kind == "lane")
+        rid = eng.submit(prompt, max_new=6)
+        got[kind] = eng.run()[rid]
+        if kind == "lane":
+            kc, vc = np.asarray(eng._kc)[0], np.asarray(eng._vc)[0]
+    assert got["lane"] == got["whole"]
+    _, k, v, _ = causal_lm.lm_prefill(
+        lane_qparams, jnp.asarray(prompt[None]), H, max_len)
+    np.testing.assert_allclose(kc[:, :t], np.asarray(k)[:, :t],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(vc[:, :t], np.asarray(v)[:, :t],
+                               rtol=1e-5, atol=1e-5)

@@ -392,7 +392,9 @@ class TestEngineGauges:
         rid = eng.submit(np.arange(1, 6, dtype=np.int32), max_new=6)
         assert len(eng.run()[rid]) == 6
         recs = profile.profiler().records("engine")
-        assert {r["label"] for r in recs} >= {"lm.prefill", "lm.decode"}
+        # the prompt rode the first chunk's lane: no prefill interval
+        assert {r["label"] for r in recs} == {"lm.decode"}
+        assert [r["args"]["lane_steps"] for r in recs][:2] == [1, 0]
         with start_exporter(port=0) as exp:
             text = urllib.request.urlopen(exp.url, timeout=5) \
                 .read().decode()

@@ -25,7 +25,7 @@ from nnstreamer_tpu.obs import events as obs_events
 from nnstreamer_tpu.obs import profile as obs_profile
 from nnstreamer_tpu.obs import tracing
 from nnstreamer_tpu.serving import LMEngine, TPLMEngine
-from nnstreamer_tpu.serving.lm_engine import STEP_PHASES
+from nnstreamer_tpu.serving.lm_engine import LANE_ROWS, STEP_PHASES
 
 V, D, H, L, MAXLEN = 37, 32, 4, 1, 64
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -34,6 +34,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 STEP_CHILDREN = ("admit", "decode_dispatch", "decode_wait", "retire")
 ADMIT_CHILDREN = ("admit_host", "prefill_dispatch", "slot_insert",
                   "first_token_wait")
+#: the engine kinds: "lane" prefills inside its decode chunks, so its
+#: admission is host work; "whole" (a store that windows of LANE_ROWS do
+#: not tile) runs a whole-prompt prefill program at admission
+KINDS = ("lane", "whole")
 
 
 @pytest.fixture(scope="module")
@@ -42,10 +46,22 @@ def params():
         jax.random.PRNGKey(3), V, D, H, L, MAXLEN)
 
 
-def _engine(params, **kw):
+def _engine(params, kind="lane", **kw):
     kw.setdefault("n_slots", 2)
     kw.setdefault("chunk", 4)
-    return LMEngine(params, H, MAXLEN, **kw)
+    eng = LMEngine(params, H, MAXLEN if kind == "lane" else MAXLEN - 16,
+                   **kw)
+    assert eng._lane == (kind == "lane" and not kw.get("spec_draft"))
+    return eng
+
+
+def _admit_children(kind):
+    return ADMIT_CHILDREN[:1] if kind == "lane" else ADMIT_CHILDREN
+
+
+def _phases(kind):
+    return tuple(p for p in STEP_PHASES if p not in ADMIT_CHILDREN[1:]) \
+        if kind == "lane" else STEP_PHASES
 
 
 def _prompt(n, start=1):
@@ -96,18 +112,21 @@ def test_counts_match_what_was_driven(params):
     st = eng.stats
     assert st["iterations"] == n
     assert st["prefills"] == 3
-    # every iteration of this run had a stream to decode
-    assert st["chunks"] == n
+    # every iteration of this run had a stream to decode, and the two
+    # that took prompts into the lane (two at once, then the third) ran
+    # the lane's chunk and the decode chunk behind it
+    assert st["chunks"] == n + 2
     assert st["decode_steps"] == sum(r["chunk"] for r in eng.recent_steps())
     # an idle iteration counts, and dispatches nothing
     eng.step_iteration()
     assert eng.stats["iterations"] == n + 1
-    assert eng.stats["chunks"] == n
+    assert eng.stats["chunks"] == n + 2
     assert "wall_s" not in st
 
 
-def test_phase_counters_are_nonnegative_and_monotone(params):
-    eng = _engine(params)
+@pytest.mark.parametrize("kind", KINDS)
+def test_phase_counters_are_nonnegative_and_monotone(params, kind):
+    eng = _engine(params, kind)
     seen = {k: 0.0 for k in eng.stats if k.endswith("_s")}
     assert {f"{p}_s" for p in STEP_PHASES} <= set(seen)
     for i in range(4):
@@ -116,7 +135,9 @@ def test_phase_counters_are_nonnegative_and_monotone(params):
             for k, was in seen.items():
                 assert eng.stats[k] >= was >= 0.0, k
                 seen[k] = eng.stats[k]
-    assert all(eng.stats[f"{p}_s"] > 0.0 for p in STEP_PHASES)
+    # an admission through the lane dispatches, inserts and awaits nothing
+    assert {p for p in STEP_PHASES if eng.stats[f"{p}_s"] > 0.0} \
+        == set(_phases(kind))
 
 
 def test_children_sum_to_no_more_than_their_parent(params):
@@ -248,9 +269,10 @@ def test_tracing_off_records_no_span_and_no_annotation(params):
     assert eng.stats["step_s"] > 0.0
 
 
+@pytest.mark.parametrize("kind", KINDS)
 def test_one_iteration_is_one_trace_with_the_phases_as_children(
-        params, tracing_on):
-    eng = _engine(params)
+        params, tracing_on, kind):
+    eng = _engine(params, kind)
     eng.submit(_prompt(5), max_new=6)
     n = _drive(eng)
     steps = [t for t in tracing_on.summaries()
@@ -262,12 +284,15 @@ def test_one_iteration_is_one_trace_with_the_phases_as_children(
                     for roots in trees}
     assert sorted(by_iteration) == list(range(1, n + 1))
     first = by_iteration[1]
-    assert [c["name"] for c in first["children"]] == [
-        "serving.admit", "serving.decode_dispatch", "serving.decode_wait",
-        "serving.retire"]
+    chunk = ["serving.decode_dispatch", "serving.decode_wait",
+             "serving.retire"]
+    # through the lane: the chunk that carries the prompt's window, then
+    # the decode chunk behind its first token
+    assert [c["name"] for c in first["children"]] == ["serving.admit"] \
+        + chunk * (2 if kind == "lane" else 1)
     admit = first["children"][0]
     assert [c["name"] for c in admit["children"]] == [
-        f"serving.{p}" for p in ADMIT_CHILDREN]
+        f"serving.{p}" for p in _admit_children(kind)]
     # a later iteration admits nothing: no admit span at all
     assert [c["name"] for c in by_iteration[2]["children"]] == [
         "serving.decode_dispatch", "serving.decode_wait", "serving.retire"]
@@ -276,8 +301,9 @@ def test_one_iteration_is_one_trace_with_the_phases_as_children(
                for t in tracing_on.summaries()) == 1
 
 
-def test_profiler_session_holds_the_spans_nested(params, tmp_path):
-    eng = _engine(params)
+@pytest.mark.parametrize("kind", KINDS)
+def test_profiler_session_holds_the_spans_nested(params, tmp_path, kind):
+    eng = _engine(params, kind)
     eng.submit(_prompt(5), max_new=4)
     eng.run()                                   # compile outside the trace
     eng.submit(_prompt(6), max_new=6)
@@ -296,10 +322,11 @@ def test_profiler_session_holds_the_spans_nested(params, tmp_path):
              for line in plane.lines for e in line.events
              if e.name.startswith("serving.")]
     names = {s[0] for s in spans}
-    assert names == {f"serving.{p}" for p in STEP_PHASES}
+    assert names == {f"serving.{p}" for p in _phases(kind)}
     steps = [s for s in spans if s[0] == "serving.step"]
     waits = [s for s in spans if s[0] == "serving.decode_wait"]
-    assert steps and len(waits) == len(steps)
+    # a wait a step, and one more where the lane's chunk came first
+    assert steps and len(waits) == len(steps) + (kind == "lane")
     for _, a, b in waits:
         assert sum(1 for _, s0, s1 in steps if s0 <= a and b <= s1) == 1
 
@@ -308,8 +335,9 @@ def test_profiler_session_holds_the_spans_nested(params, tmp_path):
 # the records and the stall
 # --------------------------------------------------------------------------- #
 
-def test_records_say_what_each_iteration_did(params):
-    eng = _engine(params)
+@pytest.mark.parametrize("kind", KINDS)
+def test_records_say_what_each_iteration_did(params, kind):
+    eng = _engine(params, kind)
     r0 = eng.submit(_prompt(5), max_new=3)
     r1 = eng.submit(_prompt(9), max_new=12)
     r2 = eng.submit(_prompt(4), max_new=3)      # waits for a slot
@@ -317,11 +345,23 @@ def test_records_say_what_each_iteration_did(params):
     recs = eng.recent_steps()
     assert [r["iteration"] for r in recs] == list(range(1, n + 1))
     assert recs[0]["admitted"] == [[r0, 0], [r1, 1]]
-    assert recs[0]["queued"] == 1 and recs[0]["active"] == 2
+    # the two admitted decode from the first chunk on, or (their prompts
+    # in the lane, one step each) from the second iteration on
+    assert recs[0]["queued"] == 1
+    if kind == "lane":
+        # two lane steps, and the chunk of four behind the first tokens
+        assert [recs[0][k] for k in ("active", "chunk", "lane_steps",
+                                     "lane_rows", "lane_tokens")] \
+            == [2, 2 + 4, 2, 2 * LANE_ROWS, 5 + 9]
+        # the third request takes the first slot that opens, and its
+        # prompt the lane, while the second request decodes
+        assert recs[1]["active"] == 2 and recs[1]["lane_steps"] == 1
+    else:
+        assert recs[0]["active"] == 2 and recs[0]["lane_steps"] == 0
     later = [r["admitted"] for r in recs[1:] if r["admitted"]]
     assert later == [[[r2, 0]]]
     for r in recs:
-        assert r["chunk"] in (1, 2, 4)
+        assert r["chunk"] - r["lane_steps"] in (1, 2, 4)
         assert r["wall_s"] >= 0.0 and r["cpu_s"] >= 0.0
         assert len(r["gc"]) == 3 and all(g >= 0 for g in r["gc"])
         assert set(r["phases"]) == set(STEP_PHASES)
@@ -358,10 +398,11 @@ def test_ring_is_bounded_and_the_slowest_outlive_it(params, monkeypatch):
                if r["iteration"] not in kept and not r["first_use"])
 
 
+@pytest.mark.parametrize("kind", KINDS)
 def test_a_stalled_dispatch_is_named_and_warned_once(
-        params, monkeypatch, caplog, events_on):
-    eng = _engine(params)
-    eng.submit(_prompt(5), max_new=4)
+        params, monkeypatch, caplog, events_on, kind):
+    eng = _engine(params, kind)
+    eng.submit(_prompt(5), max_new=6)
     eng.run()                       # every program this test uses, warm
     run_chunk = LMEngine._run_chunk
     calls = []
@@ -379,7 +420,9 @@ def test_a_stalled_dispatch_is_named_and_warned_once(
         eng.run()
     assert len(calls) >= 3
     worst = eng.slowest_steps()[0]
-    assert worst["iteration"] == start + 2
+    # the second dispatch: the next iteration's, or the chunk behind the
+    # first token in the same one
+    assert worst["iteration"] == start + (1 if kind == "lane" else 2)
     assert worst["wall_s"] > LMEngine.STEP_STALL_S
     assert max(worst["phases"], key=lambda p: worst["phases"][p]
                if p != "step" else -1.0) == "decode_dispatch"
@@ -418,9 +461,12 @@ def test_a_slow_first_use_iteration_is_no_stall(params, monkeypatch, caplog):
     assert not [r for r in caplog.records if "stood still" in r.getMessage()]
 
 
-def test_hooks_take_the_phases_stamps(params, monkeypatch):
+@pytest.mark.parametrize("kind", KINDS)
+def test_hooks_take_the_phases_stamps(params, monkeypatch, kind):
     """The profile hook's decode interval is the dispatch phase's start
-    to the wait phase's end: the same stamps, no clock of its own."""
+    to the wait phase's end: the same stamps, no clock of its own. A
+    prefill through the lane is inside the decode intervals and has none
+    of its own."""
     got = []
 
     class Hook:
@@ -428,18 +474,25 @@ def test_hooks_take_the_phases_stamps(params, monkeypatch):
             got.append((phase, t0_ns, t1_ns))
 
     monkeypatch.setattr(obs_profile, "ENGINE_HOOK", Hook())
-    eng = _engine(params)
+    eng = _engine(params, kind)
     eng.submit(_prompt(5), max_new=6)
     eng.run()
     recs = eng.recent_steps()
     decodes = [g for g in got if g[0] == "decode"]
-    assert len(decodes) == len(recs)
+    assert len(decodes) == eng.stats["chunks"] \
+        == len(recs) + (kind == "lane")
+    if kind == "lane":      # the first iteration's two dispatches
+        recs = [recs[0]] + recs
     for (_, t0, t1), rec in zip(decodes, recs):
         assert rec["start_ns"] <= t0 <= t1 \
             <= rec["start_ns"] + int(rec["wall_s"] * 1e9) + 1
+    for (_, t0, t1), rec in list(zip(decodes, recs))[2:]:
         ph = rec["phases"]
         assert (t1 - t0) / 1e9 >= ph["decode_dispatch"] + ph["decode_wait"] \
             - 1e-9
+    if kind == "lane":
+        assert {g[0] for g in got} == {"decode"}
+        return
     (prefill,) = [g for g in got if g[0] == "prefill"]
     ph = recs[0]["phases"]
     assert (prefill[2] - prefill[1]) / 1e9 >= ph["prefill_dispatch"] \
@@ -451,8 +504,9 @@ def test_hooks_take_the_phases_stamps(params, monkeypatch):
 # read-only views
 # --------------------------------------------------------------------------- #
 
-def test_progress_and_slot_of(params):
-    eng = _engine(params, n_slots=1)
+@pytest.mark.parametrize("kind", KINDS)
+def test_progress_and_slot_of(params, kind):
+    eng = _engine(params, kind, n_slots=1)
     a = eng.submit(_prompt(5), max_new=9)
     b = eng.submit(_prompt(6), max_new=3)
     assert eng.progress(a) == [] and eng.slot_of(a) is None   # queued
@@ -460,9 +514,11 @@ def test_progress_and_slot_of(params):
     eng.step_iteration()
     assert eng.slot_of(a) == 0 and eng.slot_of(b) is None
     so_far = eng.progress(a)
-    assert len(so_far) == 1 + 4                # first token and one chunk
+    # the first token, and with it the chunk behind it
+    first = 1 + 4
+    assert len(so_far) == first
     so_far.append(-1)                          # a copy
-    assert len(eng.progress(a)) == 5
+    assert len(eng.progress(a)) == first
     assert eng.progress(b) == []
     eng.run()
     assert eng.progress(a) == eng.results[a] and len(eng.progress(a)) == 9
