@@ -43,9 +43,9 @@ CFG = {
     "frames": 64,            # headline pipeline, CLI and graph.Pipeline
     "ref_frames": 8,         # of which compared with the CPU float32 model
     "aux_frames": 4,         # SSD / DeepLab / PoseNet
-    # V, D, H, L: bench.py's _LM_DIMS at half its heads, so that a head is
-    # 128 wide like the benchmark's model and the serving legs' decode
-    # steps take pallas.decode_attention (a head of 64 takes the dense form)
+    # V, D, H, L: a head is 128 wide like the benchmark's model, so that
+    # the serving legs' decode steps take pallas.decode_attention (a head
+    # of 64 takes the dense form)
     "lm_dims": (8192, 1024, 8, 8),
     "lm_max_len": 1024,
     "lm_slots": 8,

@@ -24,7 +24,7 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def enable_compile_cache() -> str:
     """Place JAX's persistent compilation cache; call before the first
-    compile (``nns-launch``, ``chip_smoke.py``, ``bench.py``).
+    compile (``nns-launch``, ``chip_smoke.py``).
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and nothing
     is set here. Unset: ``<checkout>/.jax_cache`` — a fixed path, because

@@ -4,8 +4,8 @@ TPU-native serving capability with no reference equivalent: the reference's
 only batching is ``tensor_converter frames-per-tensor``
 (gst/nnstreamer/tensor_converter/tensor_converter.c, frames_per_tensor
 regrouping), which waits unconditionally for N frames and leaves the rest
-of the pipeline batched. On TPU, per-frame H2D transfers through a
-high-RTT link dominate streaming cost (see utils/probes.phase_split), so
+of the pipeline batched. On TPU, a host-to-device transfer and a dispatch
+for every frame cost more than the frame's compute at small shapes, so
 serving wants *dynamic batching*: group whatever frames are queued — up to
 ``max_batch`` — within a ``budget_ms`` latency window, run ONE transfer +
 ONE invoke, then restore the per-frame stream.

@@ -435,9 +435,9 @@ class _NullLock:
 class CheckpointDaemon:
     """Periodic engine snapshotter for one engine.
 
-    ``run_once()`` is the deterministic unit (tests and the bench lane
-    call it directly; ``start()`` wraps it in a timer thread): read the
-    engine's per-session committed-path watermarks, and for every
+    ``run_once()`` is the deterministic unit (tests call it directly;
+    ``start()`` wraps it in a timer thread): read the engine's
+    per-session committed-path watermarks, and for every
     session at least ``min_new_tokens`` past its last checkpoint take a
     read-only snapshot and file it. ``lock`` is the engine's serializer
     (a DisaggWorker passes its ``_elock``) — held only around the two

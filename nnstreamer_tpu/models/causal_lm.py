@@ -677,44 +677,6 @@ def lm_decode_step_paged(params: Dict[str, jax.Array], tokens: jax.Array,
         params, tokens[:, :, 0], kpool, vpool, tables, poss, n_heads)
 
 
-def prefill_flops(batch: int, seq: int, d_model: int, n_layers: int,
-                  vocab: int, d_ff: int = 0) -> float:
-    """Analytic forward FLOPs of one prefill (last-token unembed only).
-
-    XLA's compiled ``cost_analysis()`` counts a ``lax.scan`` body ONCE
-    regardless of trip count (verified empirically: identical "flops"
-    for L=1/2/8 — tests/test_flops_accounting.py), so any layer-scanned
-    model undercounts by ~L and MFU derived from it understates chip
-    utilization by the same factor. Benchmarks use this closed form:
-    per token per layer 2·D·3D (QKV) + 2·D² (proj) + 4·D·d_ff (MLP);
-    causal attention QKᵀ+PV = 2·D·T·(T+1) per layer per sequence;
-    plus the last-token unembed 2·D·V. LN/softmax/gather are omitted
-    (sub-1% at these shapes), making the count slightly conservative.
-    """
-    d_ff = d_ff or 4 * d_model
-    dense = 2 * d_model * 3 * d_model + 2 * d_model * d_model \
-        + 4 * d_model * d_ff
-    attn = 2 * d_model * seq * (seq + 1)
-    return float(batch) * (n_layers * (dense * seq + attn)
-                           + 2 * d_model * vocab)
-
-
-def decode_flops(batch: int, pos0: int, n_steps: int, d_model: int,
-                 n_layers: int, vocab: int, d_ff: int = 0) -> float:
-    """Analytic FLOPs of ``n_steps`` KV-cache decode steps starting at
-    cache position ``pos0`` (step i attends pos0+i+1 keys; each step
-    pays the full per-token dense stack plus one unembed). Same
-    motivation as :func:`prefill_flops` — the generate loop is a scan of
-    a scan, which ``cost_analysis`` undercounts by ~L·n_steps."""
-    d_ff = d_ff or 4 * d_model
-    dense = 2 * d_model * 3 * d_model + 2 * d_model * d_model \
-        + 4 * d_model * d_ff
-    attn = 4 * d_model * (n_steps * (pos0 + 1)
-                          + n_steps * (n_steps - 1) // 2)
-    return float(batch) * (n_layers * (dense * n_steps + attn)
-                           + n_steps * 2 * d_model * vocab)
-
-
 def empty_cache(n_layers: int, batch: int, n_heads: int, max_len: int,
                 head_dim: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """(kcache, vcache, pos) zero state in the flat transport layout."""

@@ -12,8 +12,7 @@ elementary slice belongs to the *deepest* span covering it (ties: the
 latest-starting one — the span that most recently took over the thread
 of control). The covering span's name maps to a segment; names the
 table doesn't know — and the root's own self-time — fall into
-``host_other``, whose share defines the coverage ratio the bench lane
-tracks.
+``host_other``, whose share defines the coverage ratio.
 """
 
 from __future__ import annotations

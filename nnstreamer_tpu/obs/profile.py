@@ -18,8 +18,7 @@ module answers *where the device time went*:
   * **Live MFU / roofline gauges** — per-engine achieved-FLOP/s EWMA
     over ``chip_peak_flops`` and operational intensity over the chip's
     ridge intensity, exported on ``/metrics`` as
-    ``nnstpu_profile_mfu_ratio{engine=...}`` and friends. Until now
-    these numbers existed only in one-shot bench.py runs.
+    ``nnstpu_profile_mfu_ratio{engine=...}`` and friends.
   * **Perfetto timeline** — ``perfetto_trace()`` renders host lanes
     (one per pipeline thread, from SpanStore spans), device lanes (one
     per bundle/kernel label, from profiler records), and serving lanes

@@ -90,7 +90,7 @@ DEFAULT_MAX_TAPS = 64
 # 2k stride-samples bound every tap to thumbnail cost regardless of frame
 # size; the anomaly signals (NaN storms poison whole tensors, dead output
 # is all-constant) and the exponent sketch are insensitive to the cap,
-# and the <=5% overhead gate (bench quality_overhead_ratio) rides on it
+# and the tap's cost on the frame path rides on it
 DEFAULT_SAMPLE_CAP = 2048
 OVERFLOW_TAP = "_overflow"
 ANOMALY_KINDS = ("nan_storm", "dead_output", "drift")

@@ -1,9 +1,9 @@
 """DeviceEngine — one dispatch loop multiplexing many tenants per chip.
 
 The seed architecture ran one pipeline per process with one thread per
-queue, each dispatching to the device one buffer at a time — BENCH_r05
-measured the result: pipeline_util 0.000965, the chip idle 99.9% under
-streaming load. This module centralizes device access instead: every
+queue, each dispatching to the device one buffer at a time, so the chip
+sat idle between one pipeline's dispatches while the others waited their
+turn. This module centralizes device access instead: every
 concurrently-running pipeline (or serving engine) registers as a
 **tenant**, pushes ready work into its own queue, and a single
 per-engine dispatch loop
@@ -13,8 +13,7 @@ per-engine dispatch loop
      bound*: tenants whose head-of-line work has waited longer than
      ``starve_ms`` are force-served round-robin regardless of
      weight/priority, so the worst-case head wait is ``starve_ms`` plus
-     one service lap (the fairness bound tests and the bench acceptance
-     pin);
+     one service lap (the fairness bound the tests pin);
   2. **coalesces** — the lead item's batch pulls same-filter/same-shape
      head runs from every other tenant queue into ONE bucketed device
      batch (``XLAFilter.invoke_coalesced`` reuses the existing
@@ -172,8 +171,7 @@ class Tenant:
         self.deadline_ms = deadline_ms
         self.queue: Deque[_Work] = collections.deque()
         self.deficit = 0.0
-        #: bounded wait samples (seconds) for median/max reporting —
-        #: the bench artifact reads these
+        #: bounded wait samples (seconds) for median/max reporting
         self.waits: Deque[float] = collections.deque(maxlen=4096)
         self.stats: Dict[str, int] = {
             "submitted": 0, "completed": 0, "shed": 0, "errors": 0}
@@ -422,8 +420,8 @@ class DeviceEngine:
         # fairness bound: over-bound heads win outright, served ROUND-
         # ROBIN among themselves — oldest-head-first would let a deep
         # equally-old backlog monopolize relief forever, so the bound
-        # the tests and bench acceptance pin is: any tenant's head-of-
-        # line wait <= starve_s + |tenants| service rounds
+        # the tests pin is: any tenant's head-of-line wait
+        # <= starve_s + |tenants| service rounds
         starved = [t for t in ready
                    if now - t.queue[0].t_enq > self.starve_s]
         if starved:
@@ -651,7 +649,7 @@ class DeviceEngine:
 
     def drain(self, timeout: float = 30.0) -> bool:
         """Block until every queue is empty and in-flight work synced
-        (bench/tests barrier). True on success."""
+        (the tests' barrier). True on success."""
         t0 = time.monotonic()
         while self.pending() > 0:
             if time.monotonic() - t0 > timeout:
@@ -663,8 +661,7 @@ class DeviceEngine:
         return True
 
     def coalesce_stats(self) -> Dict[str, float]:
-        """Width distribution of recent batches — the bench artifact's
-        coalesce-width lane reads the median."""
+        """Width distribution of recent batches."""
         w = sorted(self.widths)
         if not w:
             return {"median": 0.0, "mean": 0.0, "max": 0, "n": 0}

@@ -1,12 +1,12 @@
 """serving.disagg — disaggregated prefill/decode serving over the
 query wire.
 
-BENCH_r05 shows prefill and decode sit on opposite ends of the
-roofline (chunked prefill at 0.62 MFU is compute-bound; decode steps
-are bandwidth-bound), so co-locating both phases on one chip wastes
-whichever resource the current phase doesn't need. This module splits
-them across backends — the DistServe/Mooncake shape, and the same
-split-the-pipeline-across-machines idea as NNStreamer's edge offload
+Prefill and decode sit on opposite ends of the roofline (a whole-prompt
+prefill is bound by the MXU, a decode step by HBM), so co-locating both
+phases on one chip wastes whichever resource the current phase doesn't
+need. This module splits them across backends — the DistServe/Mooncake
+shape, and the same split-the-pipeline-across-machines idea as
+NNStreamer's edge offload
 (PAPERS.md, arXiv:1901.04985) applied to the prefill/decode boundary:
 
 * A **prefill backend** (``LMEngine(role="prefill")``) runs chunked

@@ -2,8 +2,8 @@
 
 The contiguous engine reserves one ``max_len`` KV region per slot, so
 concurrency is capped at ``n_slots`` and every short request strands the
-tail of its reservation (BENCH_r05: waste_frac 0.257 at 8 slots). This
-module replaces the reservation with fixed-size PAGES:
+tail of its reservation. This module replaces the reservation with
+fixed-size PAGES:
 
 - **Page pool.** One device-resident array pair per engine,
   ``(n_pages + 1, L·H, page_size, head_dim)`` in the same flat per-slot
@@ -42,10 +42,10 @@ module replaces the reservation with fixed-size PAGES:
 
 Economics surface as ``serving.kv_*`` metrics (pool gauges + prefix-hit
 / evict counters, linted by scripts/check_metric_names.py) and a host
-``stats`` dict the bench lane reads (hit tokens, COW copies, pages
-peak). Prefix sharing follows the paged-attention / radix-attention
-lineage adapted to this repo's static-shape XLA discipline
-(docs/performance.md "Paged KV cache").
+``stats`` dict (hit tokens, COW copies, pages peak). Prefix sharing
+follows the paged-attention / radix-attention lineage adapted to this
+repo's static-shape XLA discipline (docs/performance.md "Paged KV
+cache").
 """
 
 from __future__ import annotations
@@ -411,7 +411,7 @@ class PagedKVCache:
 
     def prefix_hit_rate(self) -> float:
         """Fraction of prompt tokens served from shared prefix pages —
-        the economic summary the bench lane and exit report surface."""
+        the economic summary the exit report surfaces."""
         return self.stats["hit_tokens"] / max(1, self.stats["prompt_tokens"])
 
     def prefix_digest(self, max_entries: int = 64) -> List[str]:

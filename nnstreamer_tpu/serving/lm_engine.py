@@ -21,7 +21,7 @@ XLA-shaped design decisions:
   by the MXU, and in one pass they share each weight's one read.
   Admission is host work and stops no stream.
 - **Bucketed prefill** for the engines that keep a whole-prompt program
-  (paged, mesh-sharded, gang, speculative). Prompts are right-padded to
+  (paged, mesh-sharded, speculative). Prompts are right-padded to
   a power-of-two bucket and prefilled with ``lm_prefill_masked`` — one
   compile per bucket, exact by masking (padded K/V slots are provably
   overwritten before any step can attend to them).
@@ -488,8 +488,7 @@ class LMEngine:
 
     def __init__(self, params: Dict[str, Any], n_heads: int, max_len: int,
                  n_slots: int = 4, chunk: Optional[int] = None,
-                 bucket=None, gang: bool = False,
-                 spec_draft: int = 0,
+                 bucket=None, spec_draft: int = 0,
                  kv_page_size: Optional[int] = None,
                  kv_pages: Optional[int] = None,
                  kv_slot_pages: Optional[int] = None,
@@ -526,10 +525,6 @@ class LMEngine:
         self.max_len = max_len
         self.n_slots = n_slots
         self.chunk = chunk
-        #: gang=True degrades to STATIC batching (admit only when every
-        #: slot is free) — the baseline continuous batching is measured
-        #: against; exactness is identical, throughput is not
-        self.gang = gang
         #: speculative decoding: draft spec_draft tokens per iteration
         #: by prompt-lookup (trailing n-gram match in the stream's own
         #: history) and verify them in ONE dispatch (_verify_chunk).
@@ -622,7 +617,7 @@ class LMEngine:
         #: what the engine is: the lane is the contiguous single-device
         #: engine's, chunked and continuous, over a store that whole
         #: windows tile
-        self._lane = (self._lane_capable and self._kv is None and not gang
+        self._lane = (self._lane_capable and self._kv is None
                       and spec_draft == 0 and max_len % LANE_ROWS == 0)
         #: slot -> the prompt tokens already given to the lane, for the
         #: slots whose request is still being prefilled, in the order
@@ -669,8 +664,7 @@ class LMEngine:
         # imported pages and diag bills it as restore, not re_prefill
         self._restored_sessions: set = set()
         # decode_steps/slot_steps/wasted_slot_steps account the CHUNK
-        # path only (bench waste_frac reads them; its serving lane runs
-        # chunk mode); speculative iterations are accounted separately
+        # path only; speculative iterations are accounted separately
         # by the spec_* keys — tokens from them are in tokens_out but
         # not in the slots x steps = kept + wasted chunk invariant
         self.stats = {"prefills": 0, "decode_steps": 0,
@@ -1294,11 +1288,9 @@ class LMEngine:
         that follow carry the prompt through the lane, a window a step
         (``_plan_lane``); no device program is dispatched and nothing is
         read back, so no stream stands still for an admission. The other
-        engines (paged, mesh-sharded, gang, speculative) run their
+        engines (paged, mesh-sharded, speculative) run their
         whole-prompt prefill program to its end here, and every stream
         stands still for it."""
-        if self.gang and any(r is not None for r in self._slot_req):
-            return  # static batching: wait for the whole gang to finish
         if not self._queue or all(r is not None for r in self._slot_req):
             return
         st = self.stats
@@ -1714,10 +1706,9 @@ class LMEngine:
                     if req.eos is not None and tok == req.eos:
                         req.done = True
                 self._retire_if_done(slot, req)
-            # invariant: slots x steps = kept tokens + wasted (bench
-            # waste_frac reads this stat directly): wasted are the steps
-            # of empty and still-prefilling slots and those past a
-            # request's end
+            # invariant: slots x steps = kept tokens + wasted: wasted
+            # are the steps of empty and still-prefilling slots and
+            # those past a request's end
             st["wasted_slot_steps"] += n * self.n_slots - kept
         if lane and len(starts) > len(active) and not self._lane_at:
             # the lane's last waiting prompt ended and its first token is
@@ -1928,10 +1919,10 @@ class LMEngine:
     _SPEC_OVERHEAD_ROWS = 4.0
 
     def _retune_spec_draft(self) -> None:
-        """Close the loop the bench only analyzed: pick the draft
-        length whose EXPECTED tokens per verify cost is highest under
-        the observed per-token accept rate. Expected tokens for draft
-        k is the geometric partial sum 1 + a + ... + a^k; cost is the
+        """Pick the draft length whose EXPECTED tokens per verify cost
+        is highest under the observed per-token accept rate. Expected
+        tokens for draft k is the geometric partial sum
+        1 + a + ... + a^k; cost is the
         (k+1)-row verify window plus fixed dispatch overhead. Closed
         form — no sweep, and only reached when speculation is already
         on (spec_draft > 0 gates _decode)."""

@@ -1,23 +1,15 @@
-"""Performance probes: per-phase H2D/compute/D2H splits, FLOPs, MFU.
+"""Chip peaks and XLA's own FLOP count.
 
-The reference exposes per-filter invoke latency / throughput as runtime
-props (tensor_filter.c:366-400, tensor_filter_common.c:967-981) but cannot
-say *where* an invoke's time goes.  A synchronous per-invoke number
-mixes the host↔device round trip with chip time, so these probes measure
-each phase the way streaming pipelines actually run it: **pipelined**, K
-transfers/invokes in flight, reporting the amortized per-frame cost.  A
-separate single synchronous round-trip isolates the round trip itself.
-
+The peak tables (bf16 MXU FLOP/s, HBM bytes/s) are keyed by
+``device_kind``; a device in neither table is an error, not a default.
 ``model_flops`` asks XLA's compiled-cost analysis for the per-invoke FLOP
-count; ``mfu`` relates achieved FLOP/s to the chip's peak (bf16 MXU).
+count. What a run achieves against the peaks is the benchmark's to say
+(``benchmark/``, PERF.md §3), from a device trace.
 """
 
 from __future__ import annotations
 
-import time
-from typing import Any, Callable, Dict, Optional, Sequence
-
-import numpy as np
+from typing import Any, Callable, Dict, Optional
 
 #: per-chip peak dense-matmul FLOP/s used for MFU accounting, keyed by a
 #: substring of jax device_kind. bf16 MXU numbers (public chip specs).
@@ -86,99 +78,3 @@ def model_flops(fn: Callable, *example_args: Any) -> Optional[float]:
         return flops if flops > 0 else None
     except Exception:
         return None
-
-
-def mfu(flops_per_frame: Optional[float], fps: float,
-        device: Any = None) -> Optional[float]:
-    """Model FLOPs utilization: achieved FLOP/s over chip peak. Only an
-    *MFU* when fps is measured over device-busy time (a saturating or
-    synced loop). For an end-to-end pipeline rate — where batching
-    budgets, wire round trips, and host stages sit between frames — use
-    ``pipeline_util``, which is the same ratio under its honest name."""
-    if not flops_per_frame or not np.isfinite(fps):
-        return None
-    return flops_per_frame * fps / chip_peak_flops(device)
-
-
-def pipeline_util(flops_per_frame: Optional[float], fps: float,
-                  device: Any = None) -> Optional[float]:
-    """Fraction of chip peak consumed by a pipeline running end-to-end
-    at ``fps``: (per-frame FLOPs × fps) / peak. Deliberately NOT called
-    MFU: wall-clock fps includes everything that is not the chip
-    (batch-formation budgets, queue waits, host pre/post, wire RTT), so
-    tiny values mean "the chip is mostly idle between frames", not "the
-    model runs inefficiently"."""
-    return mfu(flops_per_frame, fps, device)
-
-
-def _pipelined(run_one: Callable[[int], Any], k: int,
-               finish: Callable[[Sequence[Any]], None]) -> float:
-    """Launch k ops back-to-back, block at the end; per-op seconds."""
-    outs = [run_one(i) for i in range(k)]
-    finish(outs)
-    t0 = time.perf_counter()
-    outs = [run_one(i) for i in range(k)]
-    finish(outs)
-    return (time.perf_counter() - t0) / k
-
-
-def phase_split(fn: Callable, example: Sequence[np.ndarray],
-                device: Any = None, k: int = 32) -> Dict[str, float]:
-    """Amortized per-frame cost of each pipeline phase, in µs:
-
-      * ``rtt_us``     — one synchronous tiny-transfer round trip (the
-        latency floor any per-frame sync point pays);
-      * ``h2d_us``     — pipelined host→device upload of one input frame;
-      * ``compute_us`` — pipelined invoke with inputs already resident;
-      * ``d2h_us``     — pipelined device→host readback of the outputs
-        (async prefetch, then materialize — the decoder's drain path).
-
-    These are throughput costs: what a deep streaming pipeline pays per
-    frame, not what a lone blocking call observes.
-    """
-    import jax
-
-    device = device or jax.devices()[0]
-    jitted = jax.jit(fn)
-    host_frames = [np.asarray(a) for a in example]
-
-    # warm compile + resident inputs
-    resident = [jax.device_put(a, device) for a in host_frames]
-    out = jitted(*resident)
-    jax.block_until_ready(out)
-
-    # rtt: single sync round trip of a tiny array
-    ts = []
-    for _ in range(5):
-        t0 = time.perf_counter()
-        np.asarray(jax.device_put(np.zeros(4, np.float32), device))
-        ts.append(time.perf_counter() - t0)
-    rtt = float(np.median(ts))
-
-    h2d = _pipelined(
-        lambda i: [jax.device_put(a, device) for a in host_frames],
-        k, lambda outs: jax.block_until_ready(outs))
-
-    compute = _pipelined(
-        lambda i: jitted(*resident),
-        k, lambda outs: jax.block_until_ready(outs))
-
-    def read_back(outs):
-        flat = []
-        for o in outs:
-            flat.extend(o if isinstance(o, (tuple, list)) else [o])
-        for o in flat:
-            try:
-                o.copy_to_host_async()
-            except (AttributeError, RuntimeError):
-                pass
-        for o in flat:
-            np.asarray(o)
-
-    d2h = _pipelined(lambda i: jitted(*resident), k, read_back) - compute
-    return {
-        "rtt_us": round(rtt * 1e6, 1),
-        "h2d_us": round(h2d * 1e6, 1),
-        "compute_us": round(compute * 1e6, 1),
-        "d2h_us": round(max(d2h, 0.0) * 1e6, 1),
-    }

@@ -2,7 +2,7 @@
 ``LMEngine`` costs with P prompt rows under its decode rows, against a
 step with none, at a model's production shape on the attached chip.
 
-    chiprun -- python scripts/lane_microbench.py [--rows 0,32,64,128]
+    chiprun -- python scripts/lane_microbenchmark.py [--rows 0,32,64,128]
 
 ``serving/lm_engine.LANE_ROWS`` was chosen from this table (PERF.md, PR
 29). The program takes its lane's width from the plan's shape, so every

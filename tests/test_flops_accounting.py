@@ -1,11 +1,12 @@
 """FLOPs accounting: analytic closed forms vs XLA cost_analysis.
 
-Pins the empirical premise behind `models/causal_lm.prefill_flops` /
-`decode_flops` (and every transformer MFU row in bench.py): XLA's
-compiled ``cost_analysis()`` counts a ``lax.scan`` body ONCE regardless
-of trip count, so layer-scanned models undercount by ~L. If a jax
-upgrade changes that accounting, the L-invariance test here fails and
-the analytic forms should be re-validated against the new meaning.
+Pins the empirical premise behind `benchmark/flops.py`'s
+`prefill_flops` / `decode_flops` (the closed forms under `decode_mfu`
+and `decode_hbm_roofline`): XLA's compiled ``cost_analysis()`` counts a
+``lax.scan`` body ONCE regardless of trip count, so layer-scanned models
+undercount by ~L. If a jax upgrade changes that accounting, the
+L-invariance test here fails and the analytic forms should be
+re-validated against the new meaning.
 """
 
 import numpy as np
@@ -13,6 +14,7 @@ import pytest
 
 import jax
 
+from benchmark import flops
 from nnstreamer_tpu.models import causal_lm
 from nnstreamer_tpu.utils import probes
 
@@ -50,18 +52,18 @@ def test_analytic_matches_cost_analysis_at_one_layer(cost_by_layers):
     """With no repeated scan body (L=1) the two accountings must agree;
     the analytic form omits LN/softmax/gathers so it sits slightly
     below the XLA count."""
-    analytic = causal_lm.prefill_flops(B, T, D, 1, V)
+    analytic = flops.prefill_flops(B, T, D, 1, V)
     measured = cost_by_layers[1]
     assert 0.6 * measured < analytic <= 1.1 * measured, \
         f"analytic {analytic:.3e} vs cost_analysis {measured:.3e}"
 
 
 def test_analytic_scales_linearly_in_layers_and_batch():
-    one = causal_lm.prefill_flops(B, T, D, 1, V)
+    one = flops.prefill_flops(B, T, D, 1, V)
     unembed = B * 2 * D * V
-    assert causal_lm.prefill_flops(B, T, D, 8, V) == \
+    assert flops.prefill_flops(B, T, D, 8, V) == \
         pytest.approx(8 * (one - unembed) + unembed)
-    assert causal_lm.prefill_flops(4 * B, T, D, 1, V) == \
+    assert flops.prefill_flops(4 * B, T, D, 1, V) == \
         pytest.approx(4 * one)
 
 
@@ -80,7 +82,7 @@ def test_decode_flops_matches_single_step_cost_analysis():
     measured = probes.model_flops(fn, tok, kc, vc)
     if measured is None:
         pytest.skip("backend exposes no cost_analysis flops")
-    analytic = causal_lm.decode_flops(B, pos0, 1, D, 1, V)
+    analytic = flops.decode_flops(B, pos0, 1, D, 1, V)
     assert 0.5 * measured < analytic <= 1.2 * measured, \
         f"analytic {analytic:.3e} vs cost_analysis {measured:.3e}"
 
@@ -88,7 +90,7 @@ def test_decode_flops_matches_single_step_cost_analysis():
 def test_decode_flops_attention_term_sums_positions():
     """n_steps from pos0 must equal the sum of single steps (the
     attention term grows with position)."""
-    total = causal_lm.decode_flops(B, 10, 5, D, 3, V)
-    stepwise = sum(causal_lm.decode_flops(B, 10 + i, 1, D, 3, V)
+    total = flops.decode_flops(B, 10, 5, D, 3, V)
+    stepwise = sum(flops.decode_flops(B, 10 + i, 1, D, 3, V)
                    for i in range(5))
     assert total == pytest.approx(stepwise)

@@ -225,7 +225,7 @@ def test_lane_is_what_the_engine_is(lane_params):
     prompts, and give the same tokens."""
     prompt = lane_prompt(LANE_ROWS + 5, seed=9)
     want = lane_isolated(lane_params, prompt, 6)
-    kinds = {"lane": {}, "gang": {"gang": True}, "spec": {"spec_draft": 2},
+    kinds = {"lane": {}, "spec": {"spec_draft": 2},
              "paged": {"kv_page_size": 16}}
     for name, kw in kinds.items():
         eng = LMEngine(lane_params, H, LANE_MAXLEN, n_slots=2, chunk=4, **kw)

@@ -200,8 +200,9 @@ def test_lane_sampled_tokens_match_whole_prompt_path(lane_params, t, mode):
     ``fold_in(seed key, prompt length)``, the rest by the same schedule."""
     prompt = np.random.default_rng(t).integers(0, V, t).astype(np.int32)
     got = {}
-    for kind, kw in (("lane", {}), ("whole", {"gang": True})):
-        eng = LMEngine(lane_params, H, LANE_MAXLEN, n_slots=2, chunk=4, **kw)
+    # a store that whole windows do not tile makes a whole-prompt engine
+    for kind, cap in (("lane", LANE_MAXLEN), ("whole", LANE_MAXLEN - 8)):
+        eng = LMEngine(lane_params, H, cap, n_slots=2, chunk=4)
         assert eng._lane == (kind == "lane")
         rid = eng.submit(prompt, 8, **LANE_MODES[mode])
         got[kind] = eng.run()[rid]
@@ -221,8 +222,8 @@ def test_lane_sampled_streams_join_a_sampled_batch(lane_params):
     new = [20, 6, 9, 7]
 
     def lone(p, m, mode):
-        eng = LMEngine(lane_params, H, LANE_MAXLEN, n_slots=1, chunk=1,
-                       gang=True)
+        eng = LMEngine(lane_params, H, LANE_MAXLEN - 8, n_slots=1, chunk=1)
+        assert not eng._lane
         rid = eng.submit(p, m, **mode)
         return eng.run()[rid]
 

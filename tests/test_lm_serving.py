@@ -239,23 +239,6 @@ def test_host_pos_mirror_tracks_device(params):
         np.asarray(eng._pos)[:, 0], np.asarray(eng._pos_host))
 
 
-def test_gang_mode_static_batching_exact(params):
-    # gang=True (the static-batch baseline) admits only into an all-free
-    # engine; results stay exact, but later requests wait for the whole
-    # first gang, so more decode steps run than in continuous mode
-    prompts = prompts_rng(5, seed=11)
-    lens = [4, 16, 4, 16, 4]
-    cont = LMEngine(params, H, MAXLEN, n_slots=2, chunk=2)
-    gang = LMEngine(params, H, MAXLEN, n_slots=2, chunk=2, gang=True)
-    rc = [cont.submit(p, max_new=n) for p, n in zip(prompts, lens)]
-    rg = [gang.submit(p, max_new=n) for p, n in zip(prompts, lens)]
-    res_c, res_g = cont.run(), gang.run()
-    for rid_c, rid_g, p, n in zip(rc, rg, prompts, lens):
-        ref = isolated_generate(params, p, n)
-        assert res_c[rid_c] == ref and res_g[rid_g] == ref
-    assert gang.stats["decode_steps"] >= cont.stats["decode_steps"]
-
-
 def test_paged_kv_same_tokens_as_contiguous(params):
     # the paged cache's engine-level exactness suite is
     # tests/test_kv_paging.py; this pins the serving contract from THIS

@@ -279,8 +279,9 @@ def test_serving_engine_lane_runs_quantized(lane_qparams, windows, more):
     t = windows * LANE_ROWS + more    # under, one, and over one window
     prompt = np.random.default_rng(t).integers(0, V, t).astype(np.int32)
     got = {}
-    for kind, kw in (("lane", {}), ("whole", {"gang": True})):
-        eng = LMEngine(lane_qparams, H, max_len, n_slots=2, chunk=4, **kw)
+    # a store that whole windows do not tile makes a whole-prompt engine
+    for kind, cap in (("lane", max_len), ("whole", max_len - 8)):
+        eng = LMEngine(lane_qparams, H, cap, n_slots=2, chunk=4)
         assert eng._lane == (kind == "lane")
         rid = eng.submit(prompt, max_new=6)
         got[kind] = eng.run()[rid]

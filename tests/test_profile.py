@@ -434,22 +434,6 @@ class TestProbesRoofline:
         list (the test CPU) raises instead of borrowing v5e's numbers."""
         with pytest.raises(probes.UnknownDeviceError, match="no peak"):
             probes.chip_peak_flops(jax.devices()[0])
-        with pytest.raises(probes.UnknownDeviceError):
-            probes.mfu(1e6, 30.0, jax.devices()[0])
-
-    def test_pipeline_util_is_honest_alias_and_bounded(self, prof):
-        """Satellite: the renamed bench lane's backing helper. The old
-        adaptive_batch16_mfu=0.000965 reading was this quantity —
-        end-to-end utilization, tiny because the chip idles between
-        frames — not device MFU."""
-        assert probes.pipeline_util(1e6, 30.0, _V5E) == pytest.approx(
-            probes.mfu(1e6, 30.0, _V5E))
-        # a pipeline can never use more than the chip: bounded by 1
-        # for any rate up to peak/flops_per_frame
-        peak = probes.chip_peak_flops(_V5E)
-        assert 0.0 < probes.pipeline_util(1e6, 30.0, _V5E) <= 1.0
-        assert probes.pipeline_util(1e6, peak / 1e6, _V5E) \
-            == pytest.approx(1.0)
 
 
 class TestCliProfileArgv:
