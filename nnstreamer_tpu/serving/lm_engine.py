@@ -33,6 +33,13 @@ XLA-shaped design decisions:
   vmap construction). ``chunk=1`` gives lowest admission latency;
   larger chunks amortize dispatch (through a high-RTT link they are the
   difference between RTT-bound and compute-bound serving).
+- **One chunk ahead.** The contiguous engine with the lane dispatches
+  chunk k+1 before it reads chunk k (``_decode``): the plan of a chunk
+  needs positions and lengths the host can count, not the last tokens,
+  so the device has its next program queued when one ends. An end the
+  tokens decide (an EOS) is found a chunk late and that chunk's columns
+  for the request are dropped; ``_drain`` reads what is in flight for
+  whoever looks from outside the loop.
 - **Paged KV cache (opt-in: ``kv_page_size > 0``).** The per-slot
   contiguous stores are replaced by one shared page pool
   (serving/kv_cache.py): admission is gated on page availability
@@ -440,6 +447,13 @@ class _Request:
     seed: int = 0
     out: List[int] = field(default_factory=list)
     done: bool = False
+    #: tokens a chunk that is dispatched and not read yet gives the
+    #: request if none of them ends it: what the plan of the chunk behind
+    #: it counts with
+    due: int = 0
+    #: the first token and the tokens of the same chunk behind it, read
+    #: and not handed out: they go into ``out`` with the next chunk's
+    held: List[int] = field(default_factory=list)
     t_submit: float = 0.0       # monotonic stamp for the TTFT histogram
     #: resilience.policy.Deadline (or None): checked at submit and again
     #: at admission — expired work is shed, not prefilled
@@ -461,6 +475,31 @@ class _Request:
     wait_span: Any = None       # serving.admission_wait — submit → admit
     prefill_span: Any = None    # serving.prefill, while the lane holds it
     decode_span: Any = None     # serving.decode — admit → retire
+
+
+@dataclass
+class _Chunk:
+    """One decode chunk from its dispatch to its read (``_dispatch_chunk``,
+    ``_read_chunk``): what the host planned for it, so that the chunk
+    behind it can be planned and dispatched before its tokens are read."""
+    n: int                      # steps
+    outs: Any                   # (S, n) tokens, on the device until read
+    lane: bool                  # whether its steps carried prompt windows
+    ahead: bool                 # dispatched while another was unread
+    #: when the device could begin it: its dispatch, or the end of the
+    #: wait for the chunk it was queued behind
+    start_ns: int
+    active: int                 # slots that decoded from the first step
+    slot_steps: int
+    kv_rows: int
+    #: slot -> (request, the column its decode tokens start at, how many
+    #: of them it keeps if none ends it); a slot whose prompt ended in
+    #: step j of this chunk starts at j + 1, and ``firsts`` has its j,
+    #: the column of its first token
+    cols: Dict[int, Tuple[_Request, int, int]]
+    firsts: Dict[int, int] = field(default_factory=dict)
+    lane_tokens: int = 0
+    conf: Any = None            # the lane's confidence triples, (n, 3)
 
 
 class LMEngine:
@@ -623,9 +662,13 @@ class LMEngine:
         #: slots whose request is still being prefilled, in the order
         #: they were admitted
         self._lane_at: Dict[int, int] = {}
-        #: the lane's rows of the chunk last run (`_plan_lane`), and the
-        #: confidence triples its program returned
+        #: the lane's rows of the chunk last dispatched (`_plan_lane`), and
+        #: the confidence triples its program returns
         self._lane_plan = self._lane_conf = None
+        #: the chunk that is dispatched and not read, where the engine
+        #: runs a chunk ahead (`_runs_ahead`); None between iterations on
+        #: every other engine, and on an engine that holds no request
+        self._flight: Optional[_Chunk] = None
         # per-slot sampling controls (traced values — greedy and sampled
         # streams share one executable; see serving/sampling.py). A lane
         # engine keeps them on the host and hands them to every chunk;
@@ -679,9 +722,13 @@ class LMEngine:
                       "tokens_out": 0,
                       "spec_iterations": 0, "spec_drafted": 0,
                       "spec_accepted": 0,
-                      # iterations run and decode dispatches made (a
-                      # chunk or a verify window)
+                      # iterations run and decode dispatches read (a
+                      # chunk or a verify window); of the chunks, those
+                      # dispatched while the one before was unread, and
+                      # the slot-steps of those that were dropped because
+                      # the tokens of the chunk before ended the request
                       "iterations": 0, "chunks": 0,
+                      "chunks_ahead": 0, "ahead_dropped_slot_steps": 0,
                       # wall of the dispatch phases that used an
                       # executable for the first time (compile, or its
                       # load from the cache)
@@ -1001,13 +1048,18 @@ class LMEngine:
                 self._slow_steps, key=lambda r: -r["wall_s"])]
 
     def step_iteration(self) -> bool:
-        """One scheduler iteration: admit into free slots, then one
-        decode chunk (with prompts in the lane: a chunk that carries their
-        windows, and behind the last one's first token the decode chunk).
-        Returns True while work remains. When enrolled as
-        a sched.DeviceEngine tenant, the iteration runs under the
-        engine's deficit-round-robin fair share so serving steps and
-        pipeline batches interleave on one chip."""
+        """One scheduler iteration: admit into the slots that are free,
+        then one decode chunk read (with prompts in the lane: a chunk that
+        carries their windows). The contiguous engine with the lane runs a
+        chunk ahead: it dispatches the next chunk before it reads the
+        last, so the device has a program queued when one ends, and a
+        prompt's first token is handed out with the tokens of the chunk
+        behind it (``_decode``). Returns True while work remains; nothing
+        is in flight once it returns False. When enrolled as a
+        sched.DeviceEngine tenant, the iteration runs under the engine's
+        deficit-round-robin fair share so serving steps and pipeline
+        batches interleave on one chip, and dispatches nothing that
+        outlasts the tenant's call."""
         tenant = self._sched_tenant
         if tenant is not None:
             ret = tenant.call(self._step_direct,
@@ -1017,7 +1069,9 @@ class LMEngine:
             return True if not isinstance(ret, bool) else ret
         return self._step_direct()
 
-    def _step_direct(self) -> bool:
+    def _step_direct(self, read_only: bool = False) -> bool:
+        """An iteration; ``read_only`` is ``_drain``'s, which admits and
+        dispatches nothing."""
         self._hc.beat()  # watchdog liveness: the scheduler is turning
         st = self.stats
         st["iterations"] += 1
@@ -1030,10 +1084,14 @@ class LMEngine:
         cpu0 = time.thread_time_ns()
         with _tracing.phase(st, "serving.step") as step:
             step.set_attribute("iteration", rec["iteration"])
-            if self._kv_imports:  # truthiness: free when nothing arrived
-                self.drain_kv_imports()
-            self._admit(step, rec)
-            self._decode(step, rec)
+            if read_only:
+                chunk, self._flight = self._flight, None
+                self._read_chunk(chunk, step, rec)
+            else:
+                if self._kv_imports:  # truthiness: free when none arrived
+                    self.drain_kv_imports()
+                self._admit(step, rec)
+                self._decode(step, rec)
         rec["cpu_s"] = (time.thread_time_ns() - cpu0) / 1e9
         rec["start_ns"] = step.start_ns
         rec["wall_s"] = step.seconds
@@ -1066,6 +1124,14 @@ class LMEngine:
                 f"took {rec['wall_s']:.3f} s", severity="warning",
                 engine=self._engine_label, step=_copy_step(rec))
 
+    def _drain(self) -> None:
+        """Read the chunk that is in flight, if one is, and retire what it
+        ends, as an iteration of its own: called first by everything that
+        looks at the device's state or at a request's from outside the
+        loop, and by what changes how the loop runs."""
+        if self._flight is not None:
+            self._step_direct(read_only=True)
+
     # -- sched.DeviceEngine tenancy ---------------------------------------- #
     def enroll(self, scheduler: Any, *, name: Optional[str] = None,
                weight: float = 1.0, priority: int = 0) -> None:
@@ -1083,6 +1149,7 @@ class LMEngine:
     def unenroll(self) -> None:
         """Detach from the scheduler (no-op when not enrolled);
         step_iteration goes back to direct execution."""
+        self._drain()
         tenant, eng = self._sched_tenant, self._sched_engine
         self._sched_tenant = None
         self._sched_engine = None
@@ -1104,6 +1171,7 @@ class LMEngine:
     def kv_stats(self) -> Optional[Dict[str, int]]:
         """Paged-KV-cache counters (hit/prompt tokens, COW copies,
         evictions, pages_peak, ...) or None when running contiguous."""
+        self._drain()
         return None if self._kv is None else dict(self._kv.stats)
 
     @property
@@ -1139,6 +1207,7 @@ class LMEngine:
         if self._kv is None:
             raise RuntimeError(
                 "prefill_and_export requires the paged KV cache")
+        self._drain()
         p = np.asarray(prompt, np.int32).reshape(-1)
         rid = self.submit(
             p, 1, eos, temperature=temperature, top_k=top_k, top_p=top_p,
@@ -1157,6 +1226,7 @@ class LMEngine:
         recorded token path to export. In-flight requests already in a
         slot run to completion — freezing gates ADMISSION, not decode,
         so nothing in progress is torn."""
+        self._drain()
         s = str(session)
         self._frozen_sessions.add(s)
         path = self._session_paths.get(s)
@@ -1214,6 +1284,7 @@ class LMEngine:
         NOT freeze — the session keeps serving; ``export_pages`` walks
         the radix tree read-only, so the daemon only ever sees a
         self-consistent (possibly one-turn-stale) path."""
+        self._drain()
         path = self._session_paths.get(str(session))
         if path is None or self._kv is None:
             return None
@@ -1260,6 +1331,7 @@ class LMEngine:
         request over that prefix just prefills locally."""
         if self._kv is None:
             return 0
+        self._drain()
         spliced = 0
         while True:
             with self._kv_imports_lock:
@@ -1580,60 +1652,98 @@ class LMEngine:
                 pid = kv.lease_alloc(lease)
                 self._table_host[s, len(lease.pages) - 1] = pid
 
+    def _runs_ahead(self) -> bool:
+        """Whether an iteration dispatches the next chunk before it reads
+        the last. The plan of a chunk needs lengths the host can count
+        ahead and no token of the chunk before, so the order follows from
+        what the engine is and whose unit of account the iteration is:
+        the engines that prefill whole prompts block in every admission
+        (which empties the device's queue anyway), and a chunk left
+        running past a scheduler tenant's call would be charged to the
+        next tenant. A speculative window is drafted from the last
+        tokens and never runs ahead (``_decode``)."""
+        return self._lane and self._sched_tenant is None
+
     def _decode(self, step: "_tracing.phase", rec: Dict[str, Any]) -> None:
-        holding = [s for s, r in enumerate(self._slot_req) if r is not None]
-        if not holding:
-            return
-        # a slot whose prompt is still in the lane holds a request and
-        # does not decode yet
-        active = [s for s in holding if s not in self._lane_at]
-        rec["active"] = max(rec["active"], len(active))
+        """Dispatch, read, retire, in the order this iteration takes.
+
+        As it was on every engine, and still is where ``_runs_ahead`` says
+        no: dispatch chunk k, read it, retire; behind the first token of
+        the lane's last waiting prompt the next chunk runs in the same
+        iteration. Running ahead: dispatch chunk k + 1, then read chunk k
+        (dispatched an iteration earlier, or just now by an engine that
+        was idle), then retire: the device has k + 1 queued when k ends,
+        and the readback's tail and the host's work between two chunks
+        leave the streams' time. What the host cannot count ahead is an
+        end the tokens decide (an EOS): the chunk ahead decodes such a
+        request all the same, and its columns are dropped when read
+        (``_read_chunk``). Whatever the order, nothing stays in flight
+        once no request is left."""
+        chunk = self._flight
+        if chunk is None:
+            if self.spec_draft > 0 and self._decode_speculative(step, rec):
+                return
+            chunk = self._dispatch_chunk(step, rec)
+            if chunk is None:
+                return
+        ahead = self._runs_ahead()
+        self._flight = self._dispatch_chunk(step, rec, ahead=True) \
+            if ahead else None
+        self._read_chunk(chunk, step, rec)
+        if self._flight is not None and not self.pending():
+            # the tokens just read ended the last request: nothing of the
+            # chunk ahead is kept, and a quiet engine has nothing in flight
+            chunk, self._flight = self._flight, None
+            self._read_chunk(chunk, step, rec)
+        elif not ahead and chunk.firsts and not self._lane_at:
+            # the lane's last waiting prompt ended: the chunk behind its
+            # first token runs in this iteration too, as the chunk behind
+            # a whole-prompt prefill does
+            chunk = self._dispatch_chunk(step, rec)
+            if chunk is not None:
+                self._read_chunk(chunk, step, rec)
+
+    def _decoding(self) -> List[int]:
+        """The slots that decode from the first step of the chunk about to
+        be dispatched: those that hold a request whose prompt has left the
+        lane and that the chunk in flight does not end by length."""
+        return [s for s, r in enumerate(self._slot_req)
+                if r is not None and s not in self._lane_at
+                and self._left(r) > 0]
+
+    def _dispatch_chunk(self, step: "_tracing.phase", rec: Dict[str, Any],
+                        ahead: bool = False) -> Optional[_Chunk]:
+        """Plan the next chunk from what the host can count (positions,
+        lengths, the lane's windows) and enqueue it; None where nobody
+        decodes and no prompt waits. The host's mirrors (``_pos_host``,
+        ``_lane_at``, a request's ``due``) advance here, so that the chunk
+        behind this one can be planned before this one is read; the
+        counters advance when it is read."""
+        active = self._decoding()
+        lane = bool(self._lane_at)
+        if not active and not lane:
+            return None
         # capacity headroom is PER-REQUEST capacity: max_len contiguous,
-        # the kv_slot_pages * page_size view bound under paging. The old
-        # max_len comparison would either let speculation NaN-poison a
-        # bounded view (m_slot < max_len) or was simply the same number;
-        # page-pool headroom is NOT a gate — admission reserved every
+        # the kv_slot_pages * page_size view bound under paging;
+        # page-pool headroom is NOT a gate: admission reserved every
         # active request's full page budget, so _ensure_pages below
-        # always succeeds
-        headroom = self._m_slot - max(
-            (self._pos_host[s] for s in active), default=0)
-        if self.spec_draft > 0 and headroom >= self.spec_draft + 1 \
-                and all(self._slot_req[s].temperature <= 0.0
-                        for s in active) \
-                and any(self._slot_req[s].max_new - len(self._slot_req[s].out)
-                        > 1 for s in active):
-            # the last gate: a verify window costs (spec_draft+1)x a
-            # decode step's matmul rows — pointless when every active
-            # stream needs at most one more token (the chunk path caps
-            # its step count by `remaining` instead)
-            # verify writes spec_draft+1 cache slots per iteration; near
-            # capacity fall through to plain chunks (which self-cap).
-            # Speculation is gated to ALL-greedy active sets: a sampled
-            # stream can only accept one token per dispatch (its draw is
-            # sequential by definition), so any batch containing one is
-            # served strictly better by chunked decode
-            if self._kv is not None:
-                self._ensure_pages(active, self.spec_draft + 1)
-            self._decode_speculative(active, step, rec)
-            return
+        # always succeeds.
         # cap the chunk so no ACTIVE slot decodes past cache capacity
         # (an overflowing row NaN-poisons itself by contract); submit()'s
         # `prompt + max_new - 1 <= max_len` guard keeps cap >= 1 for
         # every active slot, so this never clamps to a forced overflow
-        cap = headroom
-        lane = bool(self._lane_at)
+        cap = self._m_slot - max(
+            (self._pos_host[s] for s in active), default=0)
         if lane:
             # the lane sets the length: a lane chunk has a window in
-            # every step and ends where the prompts that wait end (the
-            # decode chunk that follows is a dispatch of its own, below).
-            # Its step count is a traced value of ONE executable,
-            # whatever the prompts' lengths and however many wait
+            # every step and ends where the prompts that wait end. Its
+            # step count is a traced value of ONE executable, whatever
+            # the prompts' lengths and however many wait
             remaining = sum(-(-(int(self._slot_req[s].prompt.size) - at)
                               // LANE_ROWS)
                             for s, at in self._lane_at.items())
         else:
-            remaining = max(r.max_new - len(r.out) for r in self._slot_req
-                            if r is not None)
+            remaining = max(self._left(self._slot_req[s]) for s in active)
         n = max(1, min(self.chunk, cap, remaining))
         if n < self.chunk and not lane:
             # floor TAILS to a power of two: chunk length is a static
@@ -1645,10 +1755,6 @@ class LMEngine:
         if self._kv is not None:
             self._ensure_pages(active, n)
         st = self.stats
-        rec["chunk"] += n
-        # the call returns once the chunk is enqueued (after trace and
-        # compile on a first use); the readback blocks until the device
-        # has run it, then copies (S, n) tokens to the host
         key = ("lane", self.chunk) if lane else ("chunk", n)
         cspan = _tracing.NOOP_SPAN
         if lane and key not in self._seen_programs:
@@ -1660,62 +1766,161 @@ class LMEngine:
                 cspan = _tracing.start_span(
                     "serving.compile", parent=head.span.context,
                     attrs={"bucket": LANE_ROWS, "kernel": "lane"})
+        # the call returns once the chunk is enqueued (after trace and
+        # compile on a first use), not when the device has run it
         with _tracing.phase(st, "serving.decode_dispatch",
                             parent=step) as dd:
             outs = self._run_chunk(n)
         cspan.end()
+        self._note_first_use(key, dd, rec)
+        rows = self._kv_rows_asked(active, n)
+        for s in range(self.n_slots):
+            self._pos_host[s] += n  # device pos advances for EVERY slot
+        cols = {}
+        for s in active:
+            req = self._slot_req[s]
+            keeps = min(n, self._left(req))
+            req.due += keeps
+            cols[s] = (req, 0, keeps)
+        chunk = _Chunk(n, outs, lane, ahead, dd.start_ns, len(active),
+                       slot_steps=n * len(active), kv_rows=rows, cols=cols)
+        if lane:
+            self._join_lane(chunk)
+        return chunk
+
+    def _note_first_use(self, key: Any, dispatch: "_tracing.phase",
+                        rec: Dict[str, Any]) -> None:
+        """The dispatch's wall as first use where this engine had not
+        used that executable (``key``) before."""
+        if key not in self._seen_programs:
+            self._seen_programs.add(key)
+            self.stats["first_use_s"] += dispatch.seconds
+            rec["first_use"] = True
+
+    @staticmethod
+    def _left(req: _Request) -> int:
+        """Tokens ``req`` has still to be given a step for."""
+        return req.max_new - len(req.out) - len(req.held) - req.due
+
+    def _join_lane(self, chunk: _Chunk) -> None:
+        """Behind the dispatch of a chunk with a lane: note what the lane
+        carried, and for each prompt that ends in it (its last window in
+        step j) that its first token is ``outs[slot, j]`` and that it
+        decodes the ``n - 1 - j`` steps behind, from its prompt's end. The
+        slot leaves ``_lane_at``: in the next chunk it decodes."""
+        plan, n = self._lane_plan, chunk.n
+        chunk.lane_tokens = int(plan[:, 2].sum())
+        chunk.conf = self._lane_conf
+        for j in map(int, np.flatnonzero(plan[:, 3])):
+            slot = int(plan[j, 0])
+            req = self._slot_req[slot]
+            del self._lane_at[slot]
+            after = n - 1 - j
+            self._pos_host[slot] = int(req.prompt.size)
+            chunk.slot_steps += after
+            chunk.kv_rows += self._kv_rows_asked([slot], after)
+            self._pos_host[slot] += after
+            keeps = min(after, req.max_new - 1)
+            req.due += 1 + keeps
+            chunk.firsts[slot] = j
+            chunk.cols[slot] = (req, j + 1, keeps)
+
+    def _read_chunk(self, chunk: _Chunk, step: "_tracing.phase",
+                    rec: Dict[str, Any]) -> None:
+        """Wait for ``chunk``'s tokens, advance the counters and the step
+        record by it, hand the tokens out and retire what they end.
+
+        A request that the chunk before this one ended (by a token: what
+        ends by length was never planned into this one) has its columns
+        dropped, and they count as waste: slots x steps = kept + wasted
+        holds. The rows such a slot wrote lie past the request's own,
+        where a later prompt's lane overwrites them in the device's
+        order. A first token is handed out (``out``, the TTFT, the end of
+        ``prefill_span``) with the tokens of the chunk behind its own, so
+        a stream's first token comes with its first chunk of tokens; a
+        request that ends in the chunk of its first token gets all of
+        them at once."""
+        st = self.stats
+        n = chunk.n
+        # blocks until the device has run the chunk, then copies (S, n)
+        # tokens to the host
         with _tracing.phase(st, "serving.decode_wait", parent=step) as dw:
-            outs = np.asarray(outs)
-        self._note_dispatch(key, dd, rec)
-        self._m_tok_lat.observe((dw.end_ns - dd.start_ns) / 1e9 / n)
+            outs = np.asarray(chunk.outs)
+        if self._flight is not None:
+            # the device begins the chunk behind this one now
+            self._flight.start_ns = dw.end_ns
+        wall = (dw.end_ns - chunk.start_ns) / 1e9
+        self._m_tok_lat.observe(wall / n)
         if _profile.ENGINE_HOOK is not None:
             # np.asarray blocked on the chunk: wall ≈ device time; the
             # occupancy sample drives the Perfetto serving counter lane
             _profile.ENGINE_HOOK.record_engine(
-                self, "decode", dd.start_ns, dw.end_ns,
-                tokens=n * len(active), steps=n, active=len(active),
+                self, "decode", chunk.start_ns, dw.end_ns,
+                tokens=n * chunk.active, steps=n, active=chunk.active,
                 queued=len(self._queue), slots=self.n_slots,
                 # a prefill through the lane has no interval of its own
-                lane_steps=n if lane else 0)
+                lane_steps=n if chunk.lane else 0)
         shook = _slo.ENGINE_SLO_HOOK
         if shook is not None:
-            shook.record_engine_phase(
-                self._slo_tenant(), "decode",
-                (dw.end_ns - dd.start_ns) / 1e9)
-        # host bookkeeping with nothing on the device
+            shook.record_engine_phase(self._slo_tenant(), "decode", wall)
+        # host bookkeeping with nothing on the device it waits for
         with _tracing.phase(st, "serving.retire", parent=step):
-            st["kv_rows_attended"] += self._kv_rows_asked(active, n)
-            for s in range(self.n_slots):
-                self._pos_host[s] += n  # device pos advances for EVERY slot
+            rec["chunk"] += n
+            rec["active"] = max(rec["active"], chunk.active)
+            st["chunks"] += 1
+            st["chunks_ahead"] += chunk.ahead
             st["decode_steps"] += n
-            st["slot_steps"] += n * len(active)
-            # the step from which each slot decodes: the chunk's first,
-            # or the one after its prompt's last window
-            starts = dict.fromkeys(active, 0)
-            if lane:
-                starts.update(self._join_lane(n, outs, dw.end_ns, rec))
+            st["slot_steps"] += chunk.slot_steps
+            st["kv_rows_attended"] += chunk.kv_rows
+            if chunk.lane:
+                for key, value in (("lane_steps", n),
+                                   ("lane_rows", n * LANE_ROWS),
+                                   ("lane_tokens", chunk.lane_tokens)):
+                    st[key] += value
+                    rec[key] += value
             kept = 0
-            for slot, start in starts.items():
-                req = self._slot_req[slot]
-                for i in range(start, n):
-                    if req.done or len(req.out) >= req.max_new:
-                        break  # the tail of the chunk counts as waste
-                    tok = int(outs[slot, i])
-                    req.out.append(tok)
+            for slot, (req, start, keeps) in chunk.cols.items():
+                j = chunk.firsts.get(slot)
+                new = [] if j is None else [int(outs[slot, j])]
+                req.due -= len(new) + keeps
+                if req.done:
+                    st["ahead_dropped_slot_steps"] += n - start
+                    continue
+                if j is not None and _quality.QUALITY_HOOK is not None:
+                    req.conf = chunk.conf[j]
+                # the columns past `keeps` count as waste, and so do those
+                # behind a token that ends the request
+                for tok in map(int, outs[slot, start:start + keeps]):
+                    if new and new[-1] == req.eos:
+                        break
+                    new.append(tok)
                     kept += 1
-                    if req.eos is not None and tok == req.eos:
-                        req.done = True
+                ends = new[-1] == req.eos or \
+                    len(req.out) + len(req.held) + len(new) >= req.max_new
+                if j is not None and not ends:
+                    req.held = new
+                    continue
+                if req.prefill_span is not None:
+                    self._first_token_out(slot, req, dw.end_ns)
+                req.out += req.held + new
+                req.held = []
                 self._retire_if_done(slot, req)
             # invariant: slots x steps = kept tokens + wasted: wasted
-            # are the steps of empty and still-prefilling slots and
-            # those past a request's end
+            # are the steps of empty and still-prefilling slots, those
+            # past a request's end and those dropped
             st["wasted_slot_steps"] += n * self.n_slots - kept
-        if lane and len(starts) > len(active) and not self._lane_at:
-            # the lane's last waiting prompt ended and its first token is
-            # out: the decode chunk behind it runs in this iteration too,
-            # as the chunk behind a whole-prompt prefill does, so that a
-            # stream's first token comes with its first chunk of tokens
-            self._decode(step, rec)
+
+    def _first_token_out(self, slot: int, req: _Request,
+                         end_ns: int) -> None:
+        """A prompt prefilled through the lane gets its first token: the
+        TTFT, the end of its prefill span, the start of its decode span."""
+        self._m_ttft.observe(end_ns / 1e9 - req.t_submit)
+        req.prefill_span.end()
+        req.prefill_span = None
+        if req.span is not None:
+            req.decode_span = _tracing.start_span(
+                "serving.decode", parent=req.span.context,
+                attrs={"slot": slot})
 
     def _plan_lane(self, n: int) -> np.ndarray:
         """The lane's rows for the ``n`` steps of the chunk about to run,
@@ -1723,8 +1928,8 @@ class LMEngine:
         past ``n`` are not run): the prompts that wait in ``_lane_at``
         follow each other in the order they were admitted, a window of
         LANE_ROWS tokens a step, aligned to that width from the prompt's
-        start. Advances ``_lane_at``; ``_decode`` sizes ``n`` so that
-        every step has a window."""
+        start. Advances ``_lane_at``; ``_dispatch_chunk`` sizes ``n`` so
+        that every step has a window."""
         plan = np.zeros((self.chunk, 4 + LANE_ROWS), np.int32)
         step = 0
         for slot, at in self._lane_at.items():
@@ -1741,54 +1946,6 @@ class LMEngine:
                 break
         return plan
 
-    def _join_lane(self, n: int, outs: np.ndarray, end_ns: int,
-                   rec: Dict[str, Any]) -> Dict[int, int]:
-        """After a chunk of ``n`` steps with a lane: count what the lane
-        carried, and hand each prompt that ended in it its first token
-        (``outs[slot, step]``). Returns {slot: the step from which it
-        decoded}, the slots that joined the active set inside the chunk."""
-        st = self.stats
-        plan = self._lane_plan
-        for key, value in (("lane_steps", n), ("lane_rows", n * LANE_ROWS),
-                           ("lane_tokens", int(plan[:, 2].sum()))):
-            st[key] += value
-            rec[key] = value
-        starts = {}
-        for j in map(int, np.flatnonzero(plan[:, 3])):
-            slot = int(plan[j, 0])
-            req = self._slot_req[slot]
-            del self._lane_at[slot]
-            req.out.append(int(outs[slot, j]))
-            self._m_ttft.observe(end_ns / 1e9 - req.t_submit)
-            if req.eos is not None and req.out[0] == req.eos:
-                req.done = True
-            req.prefill_span.end()
-            req.prefill_span = None
-            if _quality.QUALITY_HOOK is not None:
-                req.conf = self._lane_conf[j]
-            if req.span is not None:
-                req.decode_span = _tracing.start_span(
-                    "serving.decode", parent=req.span.context,
-                    attrs={"slot": slot})
-            # it decoded the n - 1 - j steps after, from its prompt's end
-            after = n - 1 - j
-            self._pos_host[slot] = int(req.prompt.size)
-            st["slot_steps"] += after
-            st["kv_rows_attended"] += self._kv_rows_asked([slot], after)
-            self._pos_host[slot] += after
-            starts[slot] = j + 1
-        return starts
-
-    def _note_dispatch(self, key: Any, dispatch: "_tracing.phase",
-                       rec: Dict[str, Any]) -> None:
-        """Count one decode dispatch, and its wall as first use where
-        this engine had not used that executable (``key``) before."""
-        self.stats["chunks"] += 1
-        if key not in self._seen_programs:
-            self._seen_programs.add(key)
-            self.stats["first_use_s"] += dispatch.seconds
-            rec["first_use"] = True
-
     def _run_chunk(self, n: int):
         """Run ``n`` decode steps over all slots, updating the carried
         device state; returns the (S, n) generated tokens ((S, chunk), of
@@ -1797,9 +1954,8 @@ class LMEngine:
         device-layout hook a mesh-sharded engine overrides (the paged
         branch never reaches a TP engine — it pins kv_page_size=0)."""
         # the slots that decode: the others are not attended
-        active = np.fromiter(
-            (r is not None and s not in self._lane_at
-             for s, r in enumerate(self._slot_req)), bool, self.n_slots)
+        active = np.zeros(self.n_slots, bool)
+        active[self._decoding()] = True
         if self._kv is not None:
             kv = self._kv
             (self._tokens, kv.kpool, kv.vpool, self._pos, outs) = \
@@ -1814,10 +1970,15 @@ class LMEngine:
         if self._lane_at:
             self._lane_plan = self._plan_lane(n)
             lane = (self._lane_plan, np.int32(n))
+        # the lane engine's controls are host arrays that admission and
+        # retirement write in place while this chunk may still wait for
+        # the one before it: it gets copies
+        controls = [np.array(a) if self._lane else a for a in
+                    (self._skeys, self._temp, self._topk, self._topp)]
         (self._tokens, self._kc, self._vc, self._pos, outs,
          self._lane_conf) = _decode_chunk(
             self.params, self._tokens, self._kc, self._vc, self._pos,
-            active, self._skeys, self._temp, self._topk, self._topp, lane,
+            active, *controls, lane,
             n_heads=self.n_heads, n_steps=self.chunk if lane else n)
         return outs
 
@@ -1844,14 +2005,36 @@ class LMEngine:
         return _verify_chunk(self.params, tokens_in, self._kc, self._vc,
                              self._pos, n_heads=self.n_heads)
 
-    def _decode_speculative(self, active: List[int],
-                            step: "_tracing.phase",
-                            rec: Dict[str, Any]) -> None:
-        """One speculative iteration: host-drafted prompt-lookup tokens
-        verified in one dispatch; per-slot acceptance rolls pos back
-        past rejected drafts (lm_verify_window's overwrite-before-
-        visible invariant makes that roll-back free)."""
+    def _decode_speculative(self, step: "_tracing.phase",
+                            rec: Dict[str, Any]) -> bool:
+        """One speculative iteration, where it pays: host-drafted
+        prompt-lookup tokens verified in one dispatch; per-slot acceptance
+        rolls pos back past rejected drafts (lm_verify_window's
+        overwrite-before-visible invariant makes that roll-back free).
+        False where the iteration is a plain chunk's: near capacity, with
+        a sampled stream, or with one token left to make. The drafts are
+        made from the last tokens, so a window is dispatched and read in
+        one iteration, whatever the order of the plain chunks."""
+        active = self._decoding()
         g = self.spec_draft
+        # verify writes g + 1 cache slots per iteration; near capacity
+        # (PER-REQUEST capacity: a bounded paged view would NaN-poison)
+        # fall through to plain chunks, which self-cap. Speculation is
+        # gated to ALL-greedy active sets: a sampled stream can only
+        # accept one token per dispatch (its draw is sequential by
+        # definition), so any batch containing one is served strictly
+        # better by chunked decode. The last gate: a verify window costs
+        # (g + 1)x a decode step's matmul rows — pointless when every
+        # active stream needs at most one more token (the chunk path caps
+        # its step count by `remaining` instead)
+        if not active or self._m_slot - max(
+                self._pos_host[s] for s in active) < g + 1 \
+                or any(self._slot_req[s].temperature > 0.0 for s in active) \
+                or all(self._left(self._slot_req[s]) <= 1 for s in active):
+            return False
+        if self._kv is not None:
+            self._ensure_pages(active, g + 1)
+        rec["active"] = max(rec["active"], len(active))
         drafts = np.zeros((self.n_slots, g), np.int32)
         for s in active:
             drafts[s] = self._draft_tokens(self._slot_req[s], g)
@@ -1867,7 +2050,8 @@ class LMEngine:
         with _tracing.phase(st, "serving.decode_wait", parent=step) as dw:
             outs = np.asarray(outs)
             m = np.asarray(m)
-        self._note_dispatch(("verify", g + 1), dd, rec)
+        st["chunks"] += 1
+        self._note_first_use(("verify", g + 1), dd, rec)
         wall = (dw.end_ns - dd.start_ns) / 1e9
         # per-token latency of the verify dispatch: wall over the mean
         # ACCEPTED tokens across active slots (that is what a consumer
@@ -1907,6 +2091,7 @@ class LMEngine:
                 self._retire_if_done(slot, req)
         if _tune.TUNE_HOOK is not None:
             self._retune_spec_draft()
+        return True
 
     #: re-derive the draft length every this many verify iterations —
     #: often enough to track workload shifts, rare enough to cost nothing

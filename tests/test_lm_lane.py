@@ -93,9 +93,11 @@ def test_lane_tokens_match_forward(lane_params, t):
     eng.step_iteration()
     windows = -(-t // LANE_ROWS)
     # the first chunk carries min(2, windows) windows and no decode row;
-    # where the prompt ended in it, the decode chunk behind its first
-    # token ran in the same iteration
+    # the chunk behind it is in flight when it is read, and where the
+    # prompt ended in it, its first token waits for that chunk's tokens
     assert eng.recent_steps()[0]["lane_steps"] == min(2, windows)
+    assert eng.progress(rid) == [] and eng._flight is not None
+    eng.step_iteration()
     assert len(eng.progress(rid)) == (1 + 2 if windows <= 2 else 0)
     got = eng.run()[rid]
     assert got == lane_isolated(lane_params, prompt, 3)
@@ -120,7 +122,8 @@ def test_lane_two_prompts_queued_while_streams_decode(lane_params):
         lane_prompt(3 * LANE_ROWS + 8, seed=3)    # two windows and four
     eng = LMEngine(lane_params, H, LANE_MAXLEN, n_slots=4, chunk=4)
     r_old = eng.submit(old, max_new=30)
-    eng.step_iteration()            # its one lane step, and a chunk of 4
+    eng.step_iteration()            # its one lane step read, a chunk ahead
+    eng.step_iteration()            # the chunk of 4 read, the next ahead
     assert len(eng.progress(r_old)) == 1 + 4
     ra, rb = eng.submit(a, max_new=12), eng.submit(b, max_new=6)
 
@@ -131,21 +134,26 @@ def test_lane_two_prompts_queued_while_streams_decode(lane_params):
                 rec["active"]), [len(eng.progress(r))
                                  for r in (r_old, ra, rb)]
 
-    # six windows wait: a chunk of four steps. a's two windows, then it
-    # decodes in steps 2 and 3 of the same chunk; b's first two windows
-    assert step() == ((4, 4, a.size + 2 * LANE_ROWS, 1), [9, 1 + 2, 0])
+    # the two are admitted and their six windows planned into the chunk
+    # behind the one in flight, which is read: the old stream's four
+    assert step() == ((4, 0, 0, 1), [9, 0, 0])
     assert eng.recent_steps()[-1]["admitted"] == [[ra, 1], [rb, 2]]
     assert eng.slot_of(rb) == 2
+    # in flight: a chunk of four lane steps. a's two windows, then it
+    # decodes in steps 2 and 3 of the same chunk; b's first two windows
     kc = np.asarray(eng._kc)
     kb, _ = prefill_rows(lane_params, b)
     assert_rows_close(kc[2, :, :2 * LANE_ROWS], kb[:, :2 * LANE_ROWS])
     # the decode steps of that chunk left b's slot alone
     assert not kc[2, :, 2 * LANE_ROWS:].any() and not kc[3].any()
-    # b's last two windows: a lane chunk of two steps (not floored to a
-    # power of two: its length is data); the lane is empty then, so the
-    # decode chunk behind b's first token runs in the same iteration
-    assert step() == ((2 + 4, 2, b.size - 2 * LANE_ROWS, 3), [15, 9, 5])
-    assert step() == ((4, 0, 0, 3), [19, 12, 6])
+    # it is read (a's first token and the two behind it wait for the
+    # next chunk's), and b's last two windows follow: a lane chunk of two
+    # steps (not floored to a power of two: its length is data)
+    assert step() == ((4, 4, a.size + 2 * LANE_ROWS, 1), [13, 0, 0])
+    assert step() == ((2, 2, b.size - 2 * LANE_ROWS, 2), [15, 1 + 2 + 2, 0])
+    # b's first token comes with the chunk behind it
+    assert step() == ((4, 0, 0, 3), [19, 9, 1 + 4])
+    assert step() == ((4, 0, 0, 3), [23, 12, 6])
     res = eng.run()
     for rid, p, m in ((r_old, old, 30), (ra, a, 12), (rb, b, 6)):
         assert res[rid] == lane_isolated(lane_params, p, m)

@@ -222,10 +222,10 @@ def test_nonpow2_chunk_kept_at_steady_state(params):
     rid = eng.submit(prompt, max_new=14)  # 1 prefill + 13 decode
     got = eng.run()[rid]
     assert got == isolated_generate(params, prompt, 14)
-    # the prompt's one lane step and the chunk behind it in the first
-    # iteration, then of 13 remaining a chunk of 6 and the tail 1 (pow2)
+    # the prompt's one lane step, then of 13 remaining two chunks of 6
+    # and the tail 1 (pow2), each read an iteration after its dispatch
     assert eng.stats["decode_steps"] == 1 + 13
-    assert [r["chunk"] for r in eng.recent_steps()] == [1 + 6, 6, 1]
+    assert [r["chunk"] for r in eng.recent_steps()] == [1, 6, 6, 1]
     assert eng._seen_programs == {("lane", 6), ("chunk", 6), ("chunk", 1)}
 
 

@@ -112,15 +112,15 @@ def test_counts_match_what_was_driven(params):
     st = eng.stats
     assert st["iterations"] == n
     assert st["prefills"] == 3
-    # every iteration of this run had a stream to decode, and the two
-    # that took prompts into the lane (two at once, then the third) ran
-    # the lane's chunk and the decode chunk behind it
-    assert st["chunks"] == n + 2
+    # every iteration of this run read one chunk, and every chunk but
+    # the idle engine's first was dispatched while the one before it was
+    # unread (the lane's chunk of a prompt is a chunk like any other)
+    assert st["chunks"] == n and st["chunks_ahead"] == n - 1
     assert st["decode_steps"] == sum(r["chunk"] for r in eng.recent_steps())
     # an idle iteration counts, and dispatches nothing
     eng.step_iteration()
     assert eng.stats["iterations"] == n + 1
-    assert eng.stats["chunks"] == n + 2
+    assert eng.stats["chunks"] == n and eng._flight is None
     assert "wall_s" not in st
 
 
@@ -284,18 +284,21 @@ def test_one_iteration_is_one_trace_with_the_phases_as_children(
                     for roots in trees}
     assert sorted(by_iteration) == list(range(1, n + 1))
     first = by_iteration[1]
-    chunk = ["serving.decode_dispatch", "serving.decode_wait",
-             "serving.retire"]
-    # through the lane: the chunk that carries the prompt's window, then
-    # the decode chunk behind its first token
+    read = ["serving.decode_wait", "serving.retire"]
+    # through the lane the engine runs a chunk ahead: an idle engine
+    # dispatches the chunk that carries the prompt's window and the
+    # decode chunk behind it, then reads the first
     assert [c["name"] for c in first["children"]] == ["serving.admit"] \
-        + chunk * (2 if kind == "lane" else 1)
+        + ["serving.decode_dispatch"] * (2 if kind == "lane" else 1) + read
     admit = first["children"][0]
     assert [c["name"] for c in admit["children"]] == [
         f"serving.{p}" for p in _admit_children(kind)]
     # a later iteration admits nothing: no admit span at all
     assert [c["name"] for c in by_iteration[2]["children"]] == [
-        "serving.decode_dispatch", "serving.decode_wait", "serving.retire"]
+        "serving.decode_dispatch"] + read
+    # the last chunk has none behind it: the iteration only reads
+    assert [c["name"] for c in by_iteration[n]["children"]] == (
+        read if kind == "lane" else ["serving.decode_dispatch"] + read)
     # the request's own tree is a trace apart, as before
     assert sum(t["root"] == "serving.request"
                for t in tracing_on.summaries()) == 1
@@ -325,8 +328,8 @@ def test_profiler_session_holds_the_spans_nested(params, tmp_path, kind):
     assert names == {f"serving.{p}" for p in _phases(kind)}
     steps = [s for s in spans if s[0] == "serving.step"]
     waits = [s for s in spans if s[0] == "serving.decode_wait"]
-    # a wait a step, and one more where the lane's chunk came first
-    assert steps and len(waits) == len(steps) + (kind == "lane")
+    # a wait a step, whatever the order of dispatch and read
+    assert steps and len(waits) == len(steps)
     for _, a, b in waits:
         assert sum(1 for _, s0, s1 in steps if s0 <= a and b <= s1) == 1
 
@@ -349,19 +352,23 @@ def test_records_say_what_each_iteration_did(params, kind):
     # in the lane, one step each) from the second iteration on
     assert recs[0]["queued"] == 1
     if kind == "lane":
-        # two lane steps, and the chunk of four behind the first tokens
-        assert [recs[0][k] for k in ("active", "chunk", "lane_steps",
-                                     "lane_rows", "lane_tokens")] \
-            == [2, 2 + 4, 2, 2 * LANE_ROWS, 5 + 9]
-        # the third request takes the first slot that opens, and its
-        # prompt the lane, while the second request decodes
-        assert recs[1]["active"] == 2 and recs[1]["lane_steps"] == 1
+        # a record says what the chunk READ in its iteration did: two
+        # lane steps, then (dispatched before those were read) the chunk
+        # of four behind the first tokens
+        keys = ("active", "chunk", "lane_steps", "lane_rows", "lane_tokens")
+        assert [recs[0][k] for k in keys] == [0, 2, 2, 2 * LANE_ROWS, 5 + 9]
+        assert [recs[1][k] for k in keys] == [2, 4, 0, 0, 0]
+        # the third request takes the first slot that opens (iteration
+        # 3); its prompt's lane step is queued behind the chunk that was
+        # ahead and read an iteration later, while the second decodes
+        assert recs[2]["admitted"] == [[r2, 0]] and recs[2]["lane_steps"] == 0
+        assert recs[3]["active"] == 1 and recs[3]["lane_steps"] == 1
     else:
         assert recs[0]["active"] == 2 and recs[0]["lane_steps"] == 0
     later = [r["admitted"] for r in recs[1:] if r["admitted"]]
     assert later == [[[r2, 0]]]
     for r in recs:
-        assert r["chunk"] - r["lane_steps"] in (1, 2, 4)
+        assert r["chunk"] - r["lane_steps"] in (0, 1, 2, 4)
         assert r["wall_s"] >= 0.0 and r["cpu_s"] >= 0.0
         assert len(r["gc"]) == 3 and all(g >= 0 for g in r["gc"])
         assert set(r["phases"]) == set(STEP_PHASES)
@@ -479,20 +486,23 @@ def test_hooks_take_the_phases_stamps(params, monkeypatch, kind):
     eng.run()
     recs = eng.recent_steps()
     decodes = [g for g in got if g[0] == "decode"]
-    assert len(decodes) == eng.stats["chunks"] \
-        == len(recs) + (kind == "lane")
-    if kind == "lane":      # the first iteration's two dispatches
-        recs = [recs[0]] + recs
+    assert len(decodes) == eng.stats["chunks"] == len(recs)
     for (_, t0, t1), rec in zip(decodes, recs):
-        assert rec["start_ns"] <= t0 <= t1 \
+        assert recs[0]["start_ns"] <= t0 <= t1 \
             <= rec["start_ns"] + int(rec["wall_s"] * 1e9) + 1
-    for (_, t0, t1), rec in list(zip(decodes, recs))[2:]:
-        ph = rec["phases"]
-        assert (t1 - t0) / 1e9 >= ph["decode_dispatch"] + ph["decode_wait"] \
-            - 1e-9
     if kind == "lane":
+        # run ahead: a chunk's interval begins where the wait for the
+        # one before it ended, and ends with its own wait
+        assert [d[1] for d in decodes[1:]] == [d[2] for d in decodes[:-1]]
+        for (_, t0, t1), rec in list(zip(decodes, recs))[1:]:
+            assert (t1 - t0) / 1e9 >= rec["phases"]["decode_wait"] - 1e-9
         assert {g[0] for g in got} == {"decode"}
         return
+    for (_, t0, t1), rec in zip(decodes, recs):
+        ph = rec["phases"]
+        assert rec["start_ns"] <= t0
+        assert (t1 - t0) / 1e9 >= ph["decode_dispatch"] + ph["decode_wait"] \
+            - 1e-9
     (prefill,) = [g for g in got if g[0] == "prefill"]
     ph = recs[0]["phases"]
     assert (prefill[2] - prefill[1]) / 1e9 >= ph["prefill_dispatch"] \
@@ -513,6 +523,11 @@ def test_progress_and_slot_of(params, kind):
     assert eng.progress(99) is None and eng.slot_of(99) is None
     eng.step_iteration()
     assert eng.slot_of(a) == 0 and eng.slot_of(b) is None
+    if kind == "lane":
+        # the lane's chunk is read and the chunk behind it is in flight:
+        # the first token waits for that chunk's tokens
+        assert eng.progress(a) == []
+        eng.step_iteration()
     so_far = eng.progress(a)
     # the first token, and with it the chunk behind it
     first = 1 + 4
