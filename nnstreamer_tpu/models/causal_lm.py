@@ -41,6 +41,8 @@ from ..ops.int8 import quantize_weight, stack_shape
 from ..ops.pallas.decode_attention import (decode_attention,
                                            lane_window_attention,
                                            window_attention_reference)
+from . import glm_moe_lite
+from .glm_moe_lite import GlmBlock
 from .zoo import ModelBundle, register_model
 
 
@@ -78,6 +80,11 @@ def quantize_lm_params(params: Dict[str, Any]) -> Dict[str, Any]:
     Composes with the TP mesh: `parallel/tp_decode.tp_shard_params`
     relayouts a quantized tree preserving the single-device grids, so
     distributed int8 decode matches this path token-for-token."""
+    if "layers" in params:
+        raise ValueError(
+            "quantize_lm_params: the w8a8 form exists for the GPT-2 tree's "
+            "four GEMM stacks only; a latent-attention / expert tree "
+            "(models/glm_moe_lite.py) is served in float32")
     qp = dict(params)
     for k in ("wqkv", "wo", "w1", "w2"):
         qp[k] = quantize_weight(params[k])
@@ -521,6 +528,44 @@ def lm_decode_step_slots(params: Dict[str, jax.Array], tokens: jax.Array,
     return lm_verify_window_slots(
         params, tokens[:, :, 0], kcaches, vcaches, poss, n_heads, active,
         lane)
+
+
+def is_latent(n_heads) -> bool:
+    """Whether what stands for ``n_heads`` describes the latent-attention /
+    expert block (``glm_moe_lite.GlmBlock``) and not the GPT-2 block's head
+    count."""
+    return isinstance(n_heads, GlmBlock)
+
+
+def slot_store_shapes(params: Dict[str, Any], n_heads, n_slots: int,
+                      max_len: int):
+    """The shapes of the two per-slot stores an engine keeps for this tree,
+    a slot's rows at ``[slot, :, :rows]`` of each. What stands for
+    ``n_heads`` says which family the tree is: an int for the GPT-2 block
+    (K and V by head, ``(S, L*H, max_len, hd)`` each), a
+    :class:`~.glm_moe_lite.GlmBlock` for the latent-attention block (the
+    latent row in planes and the rotary key, ``glm_moe_lite.store_shapes``).
+    """
+    if is_latent(n_heads):
+        return glm_moe_lite.store_shapes(params, n_slots, max_len)
+    n_layers = stack_shape(params["wqkv"])[0]
+    hd = params["embed"].shape[1] // n_heads
+    shape = (n_slots, n_layers * n_heads, max_len, hd)
+    return shape, shape
+
+
+def lm_step_slots(params: Dict[str, Any], tokens: jax.Array,
+                  kcaches: jax.Array, vcaches: jax.Array, poss: jax.Array,
+                  n_heads, active: "jax.Array | None" = None, lane=None):
+    """:func:`lm_decode_step_slots` for whichever family the tree is (see
+    :func:`slot_store_shapes`), with a fifth result: the step's routing
+    counts, ``(experts hit, picks)`` int32 summed over the expert layers,
+    or None for a tree without experts."""
+    if is_latent(n_heads):
+        return glm_moe_lite.decode_step_slots(
+            params, tokens, kcaches, vcaches, poss, n_heads, active, lane)
+    return (*lm_decode_step_slots(params, tokens, kcaches, vcaches, poss,
+                                  n_heads, active, lane), None)
 
 
 # --------------------------------------------------------------------------- #

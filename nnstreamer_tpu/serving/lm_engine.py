@@ -236,18 +236,20 @@ def _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
     the whole-prompt prefill draws it), takes the slot's place in that
     step's ``outs``, ``pos`` becomes ``true_len`` and the slot decodes from
     the next step of the same loop: ``active`` rides the carry.
-    Returns (tokens, kc, vc, pos, outs (S, n_steps), conf (n_steps, 3)):
-    ``conf`` is the confidence triple of a ``last`` step's first-token
-    logits, None without a lane; the columns of ``outs`` and rows of
-    ``conf`` past ``count`` are zero."""
+    Returns (tokens, kc, vc, pos, outs (S, n_steps), conf (n_steps, 3),
+    counts): ``conf`` is the confidence triple of a ``last`` step's
+    first-token logits, None without a lane; the columns of ``outs`` and
+    rows of ``conf`` past ``count`` are zero. ``counts`` is the steps'
+    routing, ``causal_lm.lm_step_slots``'s fifth result summed over the
+    steps that ran, None for a tree without experts."""
     def one(carry, step):
         tokens, kc, vc, pos, active = carry
         if step is None:
-            logits, kc, vc, pos = causal_lm.lm_decode_step_slots(
+            logits, kc, vc, pos, hits = causal_lm.lm_step_slots(
                 params, tokens, kc, vc, pos, n_heads, active)
         else:
             slot, pos0, count, last = step[0], step[1], step[2], step[3] > 0
-            logits, kc, vc, pos = causal_lm.lm_decode_step_slots(
+            logits, kc, vc, pos, hits = causal_lm.lm_step_slots(
                 params, tokens, kc, vc, pos, n_heads, active,
                 lane=(step[4:], slot, pos0, count))
             lane_row, logits = logits[-1, 0], logits[:-1]
@@ -266,7 +268,7 @@ def _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
         # full-vocab top_k/softmax/cumsum in the decode hot loop
         nxt = jax.lax.cond(jnp.all(temp <= 0.0), greedy, sampled, logits)
         if step is None:
-            return (nxt[:, None, None], kc, vc, pos, active), (nxt, None)
+            return (nxt[:, None, None], kc, vc, pos, active), (nxt, hits)
         true_len = pos0 + count
         first = jax.lax.cond(
             last & (temp[slot] > 0.0),
@@ -280,26 +282,33 @@ def _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
         join = last & (jnp.arange(nxt.shape[0]) == slot)
         nxt = jnp.where(join, first, nxt)
         pos = jnp.where(join[:, None], true_len, pos)
-        return (nxt[:, None, None], kc, vc, pos, active | join), (nxt, conf)
+        return ((nxt[:, None, None], kc, vc, pos, active | join),
+                (nxt, conf, hits))
 
     if lane is None:
-        (tokens, kc, vc, pos, _), (outs, _) = jax.lax.scan(
+        (tokens, kc, vc, pos, _), (outs, hits) = jax.lax.scan(
             one, (tokens, kc, vc, pos, active), None, length=n_steps)
-        return tokens, kc, vc, pos, outs.T, None  # outs (S, n_steps)
+        if hits is not None:
+            hits = hits.sum(axis=0)
+        return tokens, kc, vc, pos, outs.T, None, hits  # outs (S, n_steps)
 
     plan, count = lane
+    counted = causal_lm.is_latent(n_heads)
 
     def lane_step(i, carry):
-        state, outs, confs = carry
-        state, (nxt, conf) = one(state, plan[i])
-        return state, outs.at[i].set(nxt), confs.at[i].set(conf)
+        state, outs, confs, total = carry
+        state, (nxt, conf, hits) = one(state, plan[i])
+        if counted:
+            total = total + hits
+        return state, outs.at[i].set(nxt), confs.at[i].set(conf), total
 
-    (tokens, kc, vc, pos, _), outs, confs = jax.lax.fori_loop(
+    (tokens, kc, vc, pos, _), outs, confs, hits = jax.lax.fori_loop(
         0, count, lane_step,
         ((tokens, kc, vc, pos, active),
          jnp.zeros((n_steps, tokens.shape[0]), jnp.int32),
-         jnp.zeros((n_steps, 3), jnp.float32)))
-    return tokens, kc, vc, pos, outs.T, confs
+         jnp.zeros((n_steps, 3), jnp.float32),
+         jnp.zeros((2,), jnp.int32) if counted else None))
+    return tokens, kc, vc, pos, outs.T, confs, hits
 
 
 @partial(jax.jit, static_argnames=("n_heads", "n_steps"),
@@ -308,9 +317,11 @@ def _decode_chunk(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
                   top_p, lane=None, *, n_heads, n_steps):
     """``_chunk_scan`` over the contiguous stores: an executable a step
     count without a prompt lane (``lane`` None), and one with a lane for
-    every count up to ``n_steps``."""
-    return _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp,
-                       top_k, top_p, n_heads, n_steps, lane)
+    every count up to ``n_steps``. A tree without experts has no routing
+    counts, and its programs return the six results they always did."""
+    *out, counts = _chunk_scan(params, tokens, kc, vc, pos, active, skeys,
+                               temp, top_k, top_p, n_heads, n_steps, lane)
+    return tuple(out) if counts is None else (*out, counts)
 
 
 @partial(jax.jit, static_argnames=("n_heads", "n_steps"),
@@ -325,7 +336,7 @@ def _decode_chunk_paged(params, tokens, kpool, vpool, tables, pos, active,
     kviews = causal_lm.paged_view_slots(kpool, tables)
     vviews = causal_lm.paged_view_slots(vpool, tables)
     p0s = pos[:, 0]
-    tokens, kviews, vviews, pos, outs, _ = _chunk_scan(
+    tokens, kviews, vviews, pos, outs, _, _ = _chunk_scan(
         params, tokens, kviews, vviews, pos, active, skeys, temp, top_k,
         top_p, n_heads, n_steps)
     nt = causal_lm.paged_touch_span(
@@ -492,6 +503,9 @@ class _Chunk:
     active: int                 # slots that decoded from the first step
     slot_steps: int
     kv_rows: int
+    #: the steps' routing counts, on the device until read (None for a
+    #: tree without experts)
+    counts: Any
     #: slot -> (request, the column its decode tokens start at, how many
     #: of them it keeps if none ends it); a slot whose prompt ended in
     #: step j of this chunk starts at j + 1, and ``firsts`` has its j,
@@ -536,6 +550,10 @@ class LMEngine:
         # prefill/decode chunk: explicit wins; unset consults the
         # autotuner (store/model only — no sweep closure: constructing
         # an engine must never dispatch), else the hand-set 8
+        #: whether the tree is the latent-attention / expert family
+        #: (models/glm_moe_lite.py): what stands for ``n_heads`` says so
+        self._latent = causal_lm.is_latent(n_heads)
+        heads = n_heads.n_heads if self._latent else n_heads
         if chunk is None:
             chunk = 8
             tn = _tune.TUNE_HOOK
@@ -544,7 +562,7 @@ class LMEngine:
                     "lm_chunk", _tune.device_kind(), "serving.lm",
                     _tune.shape_sig(("slots", n_slots),
                                     ("len", max_len),
-                                    ("heads", n_heads)),
+                                    ("heads", heads)),
                     candidates=(4, 8, 16, 32), default=8))
         if n_slots < 1 or chunk < 1:
             raise ValueError("n_slots and chunk must be >= 1")
@@ -574,8 +592,6 @@ class LMEngine:
         self.spec_draft = spec_draft
         self._bucket = bucket or (
             lambda n: min(next_pow2_bucket(n), max_len))
-        L = stack_shape(params["wqkv"])[0]
-        hd = params["embed"].shape[1] // n_heads
         # paged-KV config: explicit kwargs win; unset ones fall back to
         # the NNS_LM_KV_* environment (the nns-launch flag transport)
         ps = kv_page_size if kv_page_size is not None \
@@ -593,10 +609,12 @@ class LMEngine:
                 ps = int(_tune.TUNE_HOOK.pick(
                     "lm_kv_page_size", _tune.device_kind(), "serving.lm",
                     _tune.shape_sig(("len", max_len),
-                                    ("heads", n_heads)),
+                                    ("heads", heads)),
                     candidates=cands, default=dflt))
         if ps < 0:
             raise ValueError("kv_page_size must be >= 0 (0 = contiguous)")
+        if self._latent:
+            self._refuse_latent(params, ps, spec_draft, max_len)
         self._kv: Optional[PagedKVCache] = None
         self._m_slot = max_len  # one request's token capacity
         if ps:
@@ -620,8 +638,9 @@ class LMEngine:
             offload = kv_host_offload if kv_host_offload is not None \
                 else os.environ.get("NNS_LM_KV_OFFLOAD", "") == "1"
             self._kv = PagedKVCache(
-                L, n_heads, ps, n_pages, hd, host_offload=bool(offload),
-                label=self._engine_label)
+                stack_shape(params["wqkv"])[0], n_heads, ps, n_pages,
+                params["embed"].shape[1] // n_heads,
+                host_offload=bool(offload), label=self._engine_label)
             self._kv_slot_pages = slot_pages
             #: per-slot page tables, mirrored on host (the scheduler is
             #: the only writer); row entries past a request's allocated
@@ -648,7 +667,7 @@ class LMEngine:
         self._tokens = jnp.zeros((n_slots, 1, 1), jnp.int32)
         self._kc = self._vc = None
         if self._kv is None:
-            self._kc, self._vc = self._alloc_slot_caches(L, hd)
+            self._kc, self._vc = self._alloc_slot_caches()
         self._pos = jnp.zeros((n_slots, 1), jnp.int32)
         #: whether prompts are prefilled inside the decode chunks, a
         #: window of LANE_ROWS rows a step (`_chunk_scan`), or whole and
@@ -664,7 +683,7 @@ class LMEngine:
         self._lane_at: Dict[int, int] = {}
         #: the lane's rows of the chunk last dispatched (`_plan_lane`), and
         #: the confidence triples its program returns
-        self._lane_plan = self._lane_conf = None
+        self._lane_plan = self._lane_conf = self._chunk_counts = None
         #: the chunk that is dispatched and not read, where the engine
         #: runs a chunk ahead (`_runs_ahead`); None between iterations on
         #: every other engine, and on an engine that holds no request
@@ -736,6 +755,14 @@ class LMEngine:
                       # submit -> slot granted, summed and the longest
                       "admission_wait_s": 0.0,
                       "admission_wait_max_s": 0.0}
+        if self._latent:
+            # the routing of the steps read (counted inside the program
+            # from the picks, lane rows included): distinct experts that
+            # got a row, a step and expert layer, and the picks made
+            # (rows x experts a token); and the latent rows the steps were
+            # asked to read, as kv_rows_attended counts K/V rows
+            self.stats.update(experts_hit=0, expert_rows=0,
+                              latent_rows_attended=0)
         # <phase>_s: seconds in each phase, added by obs/tracing.phase
         self.stats.update((key, 0.0) for key in _PHASE_KEYS)
         # one record an iteration: the last STEP_RING of them, and the
@@ -851,12 +878,45 @@ class LMEngine:
             lambda: (lambda e: None if e is None
                      else bool(e._seen_programs))(ref()))
 
-    def _alloc_slot_caches(self, n_layers: int, hd: int):
-        """Zero per-slot KV stores, (S, L·H, max_len, hd). Overridden by
-        the mesh-sharded engine to allocate sharded-from-birth."""
-        shape = (self.n_slots, n_layers * self.n_heads, self.max_len, hd)
-        return (jnp.zeros(shape, jnp.float32),
-                jnp.zeros(shape, jnp.float32))
+    def _alloc_slot_caches(self):
+        """Zero per-slot stores in the tree's layout
+        (``causal_lm.slot_store_shapes``: (S, L·H, max_len, hd) twice for
+        the GPT-2 block). Overridden by the mesh-sharded engine to
+        allocate sharded-from-birth."""
+        kshape, vshape = causal_lm.slot_store_shapes(
+            self.params, self.n_heads, self.n_slots, self.max_len)
+        return (jnp.zeros(kshape, jnp.float32),
+                jnp.zeros(vshape, jnp.float32))
+
+    def _refuse_latent(self, params, page_size: int, spec_draft: int,
+                       max_len: int) -> None:
+        """A latent-attention / expert tree is served by the contiguous
+        lane engine alone; whatever else was asked for is refused here,
+        with its name, before anything could miscompute."""
+        what = None
+        if not self._lane_capable:
+            what = ("an engine whose chunk program carries no prompt lane "
+                    "(the mesh-sharded engine): its decode body attends K "
+                    "and V by head")
+        elif page_size:
+            what = (f"the paged KV cache (kv_page_size={page_size}): pages "
+                    "hold K and V by head, not a latent row")
+        elif spec_draft:
+            what = (f"speculative decoding (spec_draft={spec_draft}): the "
+                    "verify window attends K and V by head")
+        elif max_len % LANE_ROWS:
+            what = (f"max_len={max_len}, no multiple of the prompt lane's "
+                    f"{LANE_ROWS} rows: this family has no whole-prompt "
+                    "prefill")
+        elif any(getattr(leaf, "dtype", None) != jnp.float32
+                 for leaf in jax.tree_util.tree_leaves(params)):
+            what = ("a quantized tree (or any leaf that is not float32): "
+                    "the w8a8 form exists for the GPT-2 block's GEMM "
+                    "stacks only")
+        if what is not None:
+            raise ValueError(
+                "LMEngine cannot serve a latent-attention / expert tree "
+                f"(models/glm_moe_lite.py) with {what}")
 
     # -- public API ------------------------------------------------------- #
 
@@ -1783,7 +1843,8 @@ class LMEngine:
             req.due += keeps
             cols[s] = (req, 0, keeps)
         chunk = _Chunk(n, outs, lane, ahead, dd.start_ns, len(active),
-                       slot_steps=n * len(active), kv_rows=rows, cols=cols)
+                       slot_steps=n * len(active), kv_rows=rows,
+                       counts=self._chunk_counts, cols=cols)
         if lane:
             self._join_lane(chunk)
         return chunk
@@ -1872,6 +1933,11 @@ class LMEngine:
             st["decode_steps"] += n
             st["slot_steps"] += chunk.slot_steps
             st["kv_rows_attended"] += chunk.kv_rows
+            if chunk.counts is not None:
+                hit, picks = map(int, np.asarray(chunk.counts))
+                st["experts_hit"] += hit
+                st["expert_rows"] += picks
+                st["latent_rows_attended"] += chunk.kv_rows
             if chunk.lane:
                 for key, value in (("lane_steps", n),
                                    ("lane_rows", n * LANE_ROWS),
@@ -1976,10 +2042,11 @@ class LMEngine:
         controls = [np.array(a) if self._lane else a for a in
                     (self._skeys, self._temp, self._topk, self._topp)]
         (self._tokens, self._kc, self._vc, self._pos, outs,
-         self._lane_conf) = _decode_chunk(
+         self._lane_conf, *counts) = _decode_chunk(
             self.params, self._tokens, self._kc, self._vc, self._pos,
             active, *controls, lane,
             n_heads=self.n_heads, n_steps=self.chunk if lane else n)
+        self._chunk_counts = counts[0] if counts else None
         return outs
 
     def _kv_rows_asked(self, active: List[int], n: int) -> int:

@@ -67,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..models import causal_lm
 from ..ops.int8 import stack_shape
 from ..parallel.ring import _shard_map
 from ..parallel.tp_decode import (strip_device_leaves, tp_param_specs,
@@ -217,6 +218,11 @@ class TPLMEngine(LMEngine):
     def __init__(self, params: Dict[str, Any], n_heads: int, max_len: int,
                  mesh: Mesh, axis: str = "model", **kw) -> None:
         n = mesh.shape[axis]
+        if causal_lm.is_latent(n_heads):
+            raise ValueError(
+                "TPLMEngine (the mesh-sharded engine) cannot serve a "
+                "latent-attention / expert tree (models/glm_moe_lite.py): "
+                "its decode body shards K and V by head")
         if n_heads % n:
             raise ValueError(f"n_heads={n_heads} not divisible by "
                              f"mesh axis {axis}={n}")
@@ -247,12 +253,13 @@ class TPLMEngine(LMEngine):
 
     # -- device-layout hooks ---------------------------------------------- #
 
-    def _alloc_slot_caches(self, n_layers: int, hd: int):
+    def _alloc_slot_caches(self):
         # sharded from birth: the unsharded (S, L*H, M, hd) zeros the
         # base class would allocate may not FIT one device in the
         # regime this engine exists for
-        hn = self.n_heads // self._n
-        shape = (self.n_slots, self._n, n_layers * hn, self.max_len, hd)
+        (_, lh, _, hd), _ = causal_lm.slot_store_shapes(
+            self.params, self.n_heads, self.n_slots, self.max_len)
+        shape = (self.n_slots, self._n, lh // self._n, self.max_len, hd)
         dev = NamedSharding(self.mesh, P(None, self.axis))
         zero = functools.partial(jnp.zeros, dtype=jnp.float32)
         return (jax.device_put(zero(shape), dev),
