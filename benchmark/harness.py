@@ -4,8 +4,9 @@ Driven by data. ``BENCHMARK.json`` names a cell's configuration and traffic
 mix; ``workloads/<cell>.json`` holds the cell's own parameters (rate, check
 sample, limits); the configuration file names its builder and reference;
 each metric is a module ``end_to_end/<name>.py`` or ``layers/<name>.py``
-with one function ``read(ctx)``. Adding a cell, a configuration or a metric
-adds files and entries and edits nothing here.
+with one function ``read(ctx)`` and, where it cannot read every
+configuration, ``requires(config)``, which raises at load. Adding a cell, a
+configuration or a metric adds files and entries and edits nothing here.
 """
 
 from __future__ import annotations
@@ -70,12 +71,27 @@ def load_cell(name: str, bench_path: Optional[str] = None) -> Cell:
            if "workloads" not in m or name in m["workloads"]]
     per = [m for m in bench["per_layer"]
            if "workloads" not in m or name in m["workloads"]]
+    config = _load_json(os.path.join(CHECKOUT, cfg_row["file"]))
+    for kind, rows in (("end_to_end", e2e), ("layers", per)):
+        for m in rows:
+            # a reader that cannot read this configuration says so now,
+            # not after the window
+            requires = getattr(reader_of(kind, m["name"]), "requires", None)
+            if requires is not None:
+                requires(config)
     return Cell(
         name=name, chips=int(w["chips"]), config_name=w["config"],
-        config=_load_json(os.path.join(CHECKOUT, cfg_row["file"])),
+        config=config,
         traffic_name=w["traffic"], mix=open_loop.load_mix(w["traffic"]),
         params=_load_json(os.path.join(HERE, "workloads", f"{name}.json")),
         end_to_end=e2e, per_layer=per)
+
+
+def reader_of(kind: str, name: str) -> Any:
+    """The module that reads the metric ``name``. ``a.b`` is read by ``a``:
+    one reader, split by the cells' end-to-end metric."""
+    stem = name.split(".", 1)[0].replace("-", "_")
+    return importlib.import_module(f"{__package__}.{kind}.{stem}")
 
 
 # --------------------------------------------------------------------------- #
@@ -311,10 +327,7 @@ def read_metrics(kind: str, rows: List[Dict[str, Any]], ctx: Context
     something to read; a reader that returns None is left out."""
     out: Dict[str, Dict[str, Any]] = {}
     for row in rows:
-        # ``a.b`` is read by ``a``: one reader, split by the cells' metric
-        stem = row["name"].split(".", 1)[0].replace("-", "_")
-        mod = importlib.import_module(f"{__package__}.{kind}.{stem}")
-        value = mod.read(ctx)
+        value = reader_of(kind, row["name"]).read(ctx)
         if value is None:
             continue
         value = float(value)
@@ -453,14 +466,18 @@ def is_correct(compared: Dict[str, Dict[str, float]]) -> bool:
 # one run
 # --------------------------------------------------------------------------- #
 
-def set_up(cell: Cell, seed: int, seconds: float):
+def set_up(cell: Cell, seed: int, seconds: float,
+           marks: Optional[Dict[str, float]] = None):
     """The served engine with its weights from the seed, the window's
-    schedule, and one warm call of every program that schedule uses."""
+    schedule, and one warm call of every program that schedule uses.
+    ``marks`` is given the time at which the engine stood."""
     from .traffic import open_loop
 
     builder = importlib.import_module(
         f"{__package__}.builders.{cell.config['builder']}")
     adapter = builder.build(cell.config, seed)
+    if marks is not None:
+        marks["built"] = time.time()
     vocab = int(cell.config["vocab_size"])
     sched = open_loop.schedule(cell.mix, float(cell.params["rate_rps"]),
                                seconds, seed, vocab)
@@ -481,7 +498,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         else device_info(cell.chips)
     place_compile_cache()
     clock = CompileClock()
-    adapter, sched = set_up(cell, seed, seconds)
+    marks = {"device": time.time()}
+    adapter, sched = set_up(cell, seed, seconds, marks)
     trace_dir = None
     if trace:
         trace_dir = os.path.join(CHECKOUT, ".bench_trace", cell.name)
@@ -525,6 +543,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     out["harness"] = {
         "workload": cell.name, "seed": seed, "seconds": seconds,
         "trace": int(trace), "setup_compile_s": setup["compile_s"],
+        # where set-up's seconds went: to the first answer of the device,
+        # the engine with its weights, the warm calls
+        "setup_parts_s": {"device": marks["device"] - t_process,
+                          "build": marks["built"] - marks["device"],
+                          "warm": t_process + setup_s - marks["built"]},
         "cache_hits": setup["cache_hits"],
         "cache_misses": setup["cache_misses"],
         "compiles_in_window": window.compiles_in_window,

@@ -8,7 +8,7 @@ lower the share. A trace without the kernel (a commit whose decode step
 attends through XLA's fusions) gives None."""
 
 from .. import flops, work
-from .decode_step_ms import MODULE
+from ..steps import MODULE
 
 #: a Mosaic custom call as the trace names it (trace_reduce.short_op)
 KERNEL = "[tpu_custom_call]"

@@ -1,21 +1,15 @@
 """Engine step: device time of the decode-chunk module's executions in the
-traced window over the decode steps they ran."""
+traced window over the decode steps they ran. The prompts' windows ride
+inside the steps, so this is a step as the streams meet it. The block's
+counter (``steps/``) is chosen by the configuration's keys."""
 
-from .. import work
-
-MODULE = "jit__decode_chunk"
+from .. import steps
 
 
-def device_seconds_and_steps(ctx):
-    if ctx.trace is None:
-        return None
-    secs = ctx.trace.module_seconds(MODULE)
-    steps = work.tally(ctx, work.traced_iterations(ctx)).decode_steps
-    if secs <= 0 or steps <= 0:
-        return None
-    return secs, steps
+#: a configuration no counter counts fails when its cell is loaded
+requires = steps.counter
 
 
 def read(ctx):
-    got = device_seconds_and_steps(ctx)
-    return None if got is None else got[0] * 1e3 / got[1]
+    got = steps.counter(ctx.cell.config).traced(ctx)
+    return None if got is None else got.secs * 1e3 / got.steps

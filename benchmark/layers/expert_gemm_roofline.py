@@ -5,7 +5,7 @@ decode-chunk module (one an expert layer a step). A trace without the
 kernel gives None."""
 
 from .. import flops_glm47 as fg
-from . import moe_step
+from ..steps import glm_experts as moe_step
 
 KERNEL = "moe_expert_gemm"
 

@@ -8,7 +8,7 @@ of the chunk) are not work, so they lower the share; the lane's windows
 attend through XLA's dense form and are in neither side. A trace without
 the kernel gives None."""
 
-from . import moe_step
+from ..steps import glm_experts as moe_step
 
 KERNEL = "mla_decode_attention"
 

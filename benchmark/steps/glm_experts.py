@@ -1,22 +1,30 @@
-"""Shared by the readers of the decode step with experts
-(``moe_decode_step_ms``, ``moe_decode_mfu``, ``moe_decode_hbm_roofline``,
-``expert_gemm_roofline``, ``latent_attn_roofline``): the traced window's
-device time and its work, counted from the harness's own records as
-``work.py`` counts the GPT-2 block's.
+"""The decode step of the ``glm4_moe_lite`` block (latent attention, routed
+experts; ``flops_glm47.py``'s closed forms), counted from the harness's own
+records as ``gpt2_block.py`` counts the GPT-2 block's: the traced window's
+device time, its decode steps, the kept decode rows and the prompt tokens
+prefilled through the lane, and the stored tokens both attended. Read by the
+whole step's three metrics through ``steps.counter`` and by the readers of
+its kernels (``expert_gemm_roofline``, ``latent_attn_roofline``).
 
 ``experts_hit`` is a counter of the whole window (``LMEngine.stats``,
 counted inside the jitted step from the real routing); the traced steps
 are given the window's mean a step, which a cell that runs with full slots
 from its first seconds to its close bears out. A program without the
-counter (the parent of the PR that brought it) gives None everywhere."""
+counter (the parent of the PR that brought it) gives None for the bytes."""
 
 from typing import NamedTuple
 
 from .. import flops_glm47 as fg
+from . import MODULE
 
-MODULE = "jit__decode_chunk"
 #: a Mosaic custom call as the trace names it (trace_reduce.short_op)
 CALL = "[tpu_custom_call]"
+#: what ``flops_glm47.sizes`` reads
+KEYS = ("hidden_size", "num_attention_heads", "qk_nope_head_dim",
+        "qk_rope_head_dim", "v_head_dim", "q_lora_rank", "kv_lora_rank",
+        "intermediate_size", "moe_intermediate_size", "n_routed_experts",
+        "num_experts_per_tok", "n_shared_experts", "first_k_dense_replace",
+        "num_hidden_layers", "vocab_size")
 
 
 class Traced(NamedTuple):
@@ -29,8 +37,9 @@ class Traced(NamedTuple):
 
 
 def traced(ctx):
-    """The traced iterations' device time and work, or None."""
-    if ctx.trace is None or "num_experts_per_tok" not in ctx.cell.config:
+    """The traced iterations' device time and work, or None (an untraced
+    run; another family's configuration, for the kernels' readers)."""
+    if ctx.trace is None or any(k not in ctx.cell.config for k in KEYS):
         return None
     secs = ctx.trace.module_seconds(MODULE)
     by_index = {r.index: r for r in ctx.window.requests}

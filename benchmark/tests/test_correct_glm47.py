@@ -44,7 +44,8 @@ def test_altered_token_is_not_correct(monkeypatch):
     sound = harness.run_cell(cell, 6, 2.0, False, time.time(),
                              need_tpu=False)
     assert sound["correct"] is True and sound["failed"] == 0
-    assert set(sound["metrics"]) == {"tpot_p90_ms", "setup_s"}
+    assert set(sound["metrics"]) == {"tpot_p90_ms", "out_tokens_per_s",
+                                     "setup_s"}
     assert sound["harness"]["compiles_in_window"] == 0
 
     run_chunk = LMEngine._run_chunk

@@ -1,7 +1,8 @@
-"""The readers of the decode step with experts on hand-made records and a
-hand-made reduction of a trace: their arithmetic, and None where the
-program has no such counter or kernel (the parent of the PR that brought
-them) or the cell's configuration is not of this family."""
+"""The whole step's readers on the family with experts, and the readers of
+its kernels and counter, on hand-made records and a hand-made reduction of
+a trace: their arithmetic, and None where the program has no such counter
+or kernel (the parent of the PR that brought them) or, for the kernels and
+the counter, the cell's configuration is not of this family."""
 
 import json
 import os
@@ -13,9 +14,10 @@ import pytest
 from benchmark import flops_glm47 as fg
 from benchmark import harness, trace_reduce
 from benchmark.harness import IterationRecord, RequestRecord
-from benchmark.layers import (expert_gemm_roofline, expert_hit_share,
-                              latent_attn_roofline, moe_decode_hbm_roofline,
-                              moe_decode_mfu, moe_decode_step_ms, moe_step)
+from benchmark.layers import (decode_hbm_roofline, decode_mfu,
+                              decode_step_ms, expert_gemm_roofline,
+                              expert_hit_share, latent_attn_roofline)
+from benchmark.steps import glm_experts as moe_step
 
 with open(os.path.join(harness.HERE, "configs",
                        "tiny-glm47-selftest.json")) as f:
@@ -71,7 +73,7 @@ def test_the_published_sizes_count_as_the_issue_reckons_them():
 def test_the_traced_work_is_counted_from_the_harness_records():
     got = moe_step.traced(ctx_of(ops=OPS))
     assert got == moe_step.Traced(0.02, 4, 5, 58 + 6, 5, 15)
-    assert moe_decode_step_ms.read(ctx_of(ops=OPS)) == pytest.approx(5.0)
+    assert decode_step_ms.read(ctx_of(ops=OPS)) == pytest.approx(5.0)
     assert moe_step.hit_per_step(ctx_of(ops=OPS)) == pytest.approx(12.0)
 
 
@@ -79,12 +81,12 @@ def test_shares_are_least_time_over_measured_time():
     ctx = ctx_of(ops=OPS)
     flops = 5 * fg.row_flops(TINY, True) + 5 * fg.row_flops(TINY, False) \
         + (64 + 15) * fg.attended_row_flops(TINY)
-    assert moe_decode_mfu.read(ctx) == pytest.approx(
+    assert decode_mfu.read(ctx) == pytest.approx(
         flops * 100.0 / (0.02 * 1e12))
     nbytes = 4 * (fg.fixed_weight_bytes_per_step(TINY)
                   + 12 * fg.expert_bytes(TINY)) \
         + (64 + 15) * 3 * fg.latent_bytes_per_row(TINY)
-    assert moe_decode_hbm_roofline.read(ctx) == pytest.approx(
+    assert decode_hbm_roofline.read(ctx) == pytest.approx(
         max(nbytes / 1e9, flops / 1e12) * 100.0 / 0.02)
     # the kernels: their own calls inside the module, and no other's
     assert expert_gemm_roofline.read(ctx) == pytest.approx(
@@ -95,7 +97,7 @@ def test_shares_are_least_time_over_measured_time():
     assert expert_hit_share.read(ctx) == pytest.approx(75.0)
 
 
-READERS = (moe_decode_step_ms, moe_decode_mfu, moe_decode_hbm_roofline,
+READERS = (decode_step_ms, decode_mfu, decode_hbm_roofline,
            expert_gemm_roofline, latent_attn_roofline, expert_hit_share)
 
 
@@ -104,9 +106,13 @@ def test_none_where_there_is_nothing_to_read(reader):
     gpt2 = {"n_embd": 64, "n_layer": 2, "n_head": 4, "n_inner": 256,
             "vocab_size": 97, "n_positions": 128}
     old = {"decode_steps": 80}
-    # another family's cell; a program without the counter
-    assert reader.read(ctx_of(config=gpt2, ops=OPS)) is None
-    if reader is not moe_decode_step_ms and reader is not moe_decode_mfu \
+    # another family's cell: the whole step's readers count it by that
+    # family's counter (test_whole_step.py), these have nothing to read
+    if reader in (expert_gemm_roofline, latent_attn_roofline,
+                  expert_hit_share):
+        assert reader.read(ctx_of(config=gpt2, ops=OPS)) is None
+    # a program without the counter
+    if reader is not decode_step_ms and reader is not decode_mfu \
             and reader is not latent_attn_roofline:
         assert reader.read(ctx_of(ops=OPS, start=old, end=old)) is None
     if reader is not expert_hit_share:

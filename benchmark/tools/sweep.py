@@ -26,7 +26,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 def main(argv=None) -> int:
     from benchmark import harness
     from benchmark.clock import CompileClock
-    from benchmark.end_to_end import tpot_p90_ms, ttft_p90_ms
+    from benchmark.end_to_end import (out_tokens_per_s, tpot_p90_ms,
+                                      ttft_p90_ms)
     from benchmark.layers import ttft_mean_ms
     from benchmark.stats import percentile
     from benchmark.traffic import open_loop
@@ -76,9 +77,7 @@ def main(argv=None) -> int:
             "ttft_mean_ms": ttft_mean_ms.read(ctx),
             "ttft_p90_ms": ttft_p90_ms.read(ctx),
             "tpot_p90_ms": tpot_p90_ms.read(ctx),
-            "out_tokens_per_s": sum(
-                1 for r in w.requests for t in r.token_s
-                if t <= w.seconds) / w.seconds,
+            "out_tokens_per_s": out_tokens_per_s.read(ctx),
             "pending_max": max((it.pending_after for it in in_win),
                                default=0),
             "pending_at_close": in_win[-1].pending_after if in_win else 0,
