@@ -41,8 +41,7 @@ from ..ops.int8 import quantize_weight, stack_shape
 from ..ops.pallas.decode_attention import (decode_attention,
                                            lane_window_attention,
                                            window_attention_reference)
-from . import glm_moe_lite
-from .glm_moe_lite import GlmBlock
+from . import glm_moe_lite, qwen3_next
 from .zoo import ModelBundle, register_model
 
 
@@ -530,24 +529,33 @@ def lm_decode_step_slots(params: Dict[str, jax.Array], tokens: jax.Array,
         lane)
 
 
-def is_latent(n_heads) -> bool:
-    """Whether what stands for ``n_heads`` describes the latent-attention /
-    expert block (``glm_moe_lite.GlmBlock``) and not the GPT-2 block's head
-    count."""
-    return isinstance(n_heads, GlmBlock)
+#: the families served beside the GPT-2 block, by the type of the static
+#: description that stands where that block takes ``n_heads``
+_FAMILIES = {glm_moe_lite.GlmBlock: glm_moe_lite,
+             qwen3_next.Qwen3NextBlock: qwen3_next}
+
+
+def family(n_heads):
+    """The module of the family whose static description stands for
+    ``n_heads`` (``glm_moe_lite`` for a ``GlmBlock``, ``qwen3_next`` for a
+    ``Qwen3NextBlock``), or None for the GPT-2 block's head count. A family
+    module has ``store_shapes``, ``decode_step_slots``, ``COUNTS`` (the
+    engine stats its step's counts add to), ``ATTENTION`` (the kernel
+    module whose ``read_grain`` its stored rows are read at) and
+    ``DESCRIBED``."""
+    return _FAMILIES.get(type(n_heads))
 
 
 def slot_store_shapes(params: Dict[str, Any], n_heads, n_slots: int,
                       max_len: int):
     """The shapes of the two per-slot stores an engine keeps for this tree,
-    a slot's rows at ``[slot, :, :rows]`` of each. What stands for
-    ``n_heads`` says which family the tree is: an int for the GPT-2 block
-    (K and V by head, ``(S, L*H, max_len, hd)`` each), a
-    :class:`~.glm_moe_lite.GlmBlock` for the latent-attention block (the
-    latent row in planes and the rotary key, ``glm_moe_lite.store_shapes``).
-    """
-    if is_latent(n_heads):
-        return glm_moe_lite.store_shapes(params, n_slots, max_len)
+    each a shape or a tuple of shapes, a slot at ``[slot]`` of each. What
+    stands for ``n_heads`` says which family the tree is (:func:`family`):
+    an int for the GPT-2 block (K and V by head, ``(S, L*H, max_len, hd)``
+    each), else its family's ``store_shapes``."""
+    fam = family(n_heads)
+    if fam is not None:
+        return fam.store_shapes(params, n_slots, max_len, n_heads)
     n_layers = stack_shape(params["wqkv"])[0]
     hd = params["embed"].shape[1] // n_heads
     shape = (n_slots, n_layers * n_heads, max_len, hd)
@@ -558,11 +566,11 @@ def lm_step_slots(params: Dict[str, Any], tokens: jax.Array,
                   kcaches: jax.Array, vcaches: jax.Array, poss: jax.Array,
                   n_heads, active: "jax.Array | None" = None, lane=None):
     """:func:`lm_decode_step_slots` for whichever family the tree is (see
-    :func:`slot_store_shapes`), with a fifth result: the step's routing
-    counts, ``(experts hit, picks)`` int32 summed over the expert layers,
-    or None for a tree without experts."""
-    if is_latent(n_heads):
-        return glm_moe_lite.decode_step_slots(
+    :func:`family`), with a fifth result: the step's counts, int32 in the
+    order of the family's ``COUNTS``, or None for the GPT-2 block."""
+    fam = family(n_heads)
+    if fam is not None:
+        return fam.decode_step_slots(
             params, tokens, kcaches, vcaches, poss, n_heads, active, lane)
     return (*lm_decode_step_slots(params, tokens, kcaches, vcaches, poss,
                                   n_heads, active, lane), None)

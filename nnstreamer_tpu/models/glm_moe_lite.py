@@ -46,11 +46,21 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas import latent_attention
 from ..ops.pallas.latent_attention import (LANES, latent_decode_attention,
                                            latent_lane_attention)
 from ..ops.pallas.moe_gemm import expert_gemm, pick_weights
 
 _PRECISION = "float32"
+
+#: what the engine names this family in a refusal
+DESCRIBED = "a latent-attention / expert tree (models/glm_moe_lite.py)"
+#: the engine's stats a step's counts add to, in their order; the latent
+#: rows the steps were asked to read add to ``ROWS_ATTENDED``
+COUNTS = ("experts_hit", "expert_rows")
+ROWS_ATTENDED = "latent_rows_attended"
+#: the kernel the latent rows are read by (its ``read_grain``)
+ATTENTION = latent_attention
 
 
 @dataclass(frozen=True)
@@ -79,12 +89,13 @@ def split_kv_b(kv_b, block: GlmBlock):
             w[:, :, block.qk_nope:].transpose(1, 0, 2))
 
 
-def store_shapes(params: Dict[str, Any], n_slots: int, max_len: int
+def store_shapes(params: Dict[str, Any], n_slots: int, max_len: int,
+                 block: "GlmBlock | None" = None
                  ) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
     """The engine's two stores for this tree: the latent as planes of 128
     lanes, ``(S, L * C/128, max_len, 128)``, and the rotary key padded to
     the lanes, ``(S, L, max_len, 128)``; a slot's rows at ``[slot, :,
-    :rows]``."""
+    :rows]``. The tree's shapes say it all; ``block`` is not read."""
     layers = params["layers"]
     c = layers[0]["kv_norm"].shape[0]
     if c % LANES:

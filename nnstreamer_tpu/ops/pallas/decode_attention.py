@@ -33,6 +33,13 @@ Arithmetic is float32 on the VPU: a one-row product is a multiply and a
 lane reduction, which keeps the precision the configuration states with
 no bf16 passes, and the MXU would run it at one row of 128.
 
+Grouped-query attention (fewer K/V heads than query heads, each K/V head
+serving ``group`` query heads in order) is read from the shapes: the
+queries have ``group`` times the heads of the new rows and the store. An
+item then fetches a K/V head's block once and folds it into the online
+softmax of each query head of its group. A group of one is the program
+the equal-head block has always run.
+
 Where the computation is not placed on a TPU the dense form runs
 (``ops/pallas.per_platform``); it is also the reference the tests hold the
 kernel to, and the form a window wider than one token takes.
@@ -99,14 +106,17 @@ def window_attention_reference(q, k_new, v_new, kc, vc, li, pos,
                                active=None, *, layer_axis: int):
     """Dense masked form: write the window's rows, attend ``max_len``.
 
-    q, k_new, v_new: (B, H, W, hd); kc, vc: the 5-D store, layers on
-    ``layer_axis`` (0: ``(L, B, H, max_len, hd)``, 1: ``(B, L, H, max_len,
-    hd)``); li: the layer; pos: () for streams in step or (B,) per stream;
-    active: None or (B,) bool. Row j of a stream attends columns
-    <= pos + j. A stream that is not active writes nothing and gets its
-    own value rows back; neither does a window past capacity write.
-    Returns (o (B, H, W, hd), kc, vc)."""
+    q: (B, H, W, hd); k_new, v_new: (B, H / group, W, hd), the K/V heads
+    (K/V head i serves query heads ``i * group .. i * group + group - 1``);
+    kc, vc: the 5-D store, layers on ``layer_axis`` (0: ``(L, B, H / group,
+    max_len, hd)``, 1: ``(B, L, H / group, max_len, hd)``); li: the layer;
+    pos: () for streams in step or (B,) per stream; active: None or (B,)
+    bool. Row j of a stream attends columns <= pos + j. A stream that is
+    not active writes nothing and gets its own value rows back (a K/V
+    head's to each query head of its group); neither does a window past
+    capacity write. Returns (o (B, H, W, hd), kc, vc)."""
     b, _, w, hd = q.shape
+    group = q.shape[1] // k_new.shape[1]
     max_len = kc.shape[-2]
     bax = 1 - layer_axis
     k_new, v_new = k_new.astype(kc.dtype), v_new.astype(vc.dtype)
@@ -131,6 +141,9 @@ def window_attention_reference(q, k_new, v_new, kc, vc, li, pos,
         kc2, vc2 = jnp.where(keep, kc2, kc), jnp.where(keep, vc2, vc)
     kc_l = jax.lax.dynamic_index_in_dim(kc2, li, layer_axis, keepdims=False)
     vc_l = jax.lax.dynamic_index_in_dim(vc2, li, layer_axis, keepdims=False)
+    if group > 1:
+        kc_l, vc_l, v_new = (jnp.repeat(z, group, axis=1)
+                             for z in (kc_l, vc_l, v_new))
     live = (jnp.arange(max_len)[None, None, :]
             <= (rows[:, None] + jnp.arange(w)[None, :])[:, :, None])
     s = jnp.einsum("bhqd,bhkd->bhqk", q, kc_l) / math.sqrt(hd)
@@ -159,12 +172,15 @@ def lane_window_attention(q, k_new, v_new, kc, vc, li, slot, pos0):
     columns ``<= pos0 + j``, read from the smallest prefix of
     :func:`lane_bounds` that holds ``pos0 + P`` rows, not ``max_len``.
 
-    q, k_new, v_new: (P, H, 1, hd), the rows as a batch of one-token
-    windows (the layout of the decode rows they ride with); kc, vc: the
-    store a slot, ``(S, L, H, max_len, hd)``; li, slot, pos0: scalars,
+    q: (P, H, 1, hd), the rows as a batch of one-token windows (the layout
+    of the decode rows they ride with); k_new, v_new: (P, H / group, 1, hd),
+    grouped as in :func:`window_attention_reference`; kc, vc: the store a
+    slot, ``(S, L, H / group, max_len, hd)``; li, slot, pos0: scalars,
     ``pos0 + P <= max_len`` (the caller keeps windows aligned to P).
     Returns (o (P, H, 1, hd), kc, vc)."""
-    p, h, _, hd = q.shape
+    p, _, _, hd = q.shape
+    h = k_new.shape[1]
+    group = q.shape[1] // h
     max_len = kc.shape[-2]
     pos0 = jnp.asarray(pos0, jnp.int32)
     rows = lambda z: z[:, :, 0].transpose(1, 0, 2)          # (H, P, hd)
@@ -180,11 +196,16 @@ def lane_window_attention(q, k_new, v_new, kc, vc, li, slot, pos0):
             size = (1, 1, h, bound, hd)
             kb = jax.lax.dynamic_slice(kc, (slot, li, 0, 0, 0), size)[0, 0]
             vb = jax.lax.dynamic_slice(vc, (slot, li, 0, 0, 0), size)[0, 0]
-            s = jnp.einsum("hqd,hkd->hqk", qh, kb) / math.sqrt(hd)
             live = jnp.arange(bound)[None, :] \
                 <= (pos0 + jnp.arange(p))[:, None]
+            if group > 1:
+                # a K/V head's query heads as one block of group x P rows
+                qh = qh.reshape(h, group * p, hd)
+                live = jnp.tile(live, (group, 1))
+            s = jnp.einsum("hqd,hkd->hqk", qh, kb) / math.sqrt(hd)
             s = jnp.where(live[None], s, _NEG_INF)
-            return jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, axis=-1), vb)
+            o = jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, axis=-1), vb)
+            return o.reshape(h * group, p, hd)
         return dense
 
     # the first prefix that holds the window's last row
@@ -196,10 +217,16 @@ def lane_window_attention(q, k_new, v_new, kc, vc, li, slot, pos0):
 def _decode_kernel(row0_ref, pos_ref, write_ref, n_items_ref, item_b_ref,
                    item_blk_ref, q_ref, kn_ref, vn_ref, kc_hbm, vc_hbm,
                    o_ref, kc_out, vc_out, kbuf, vbuf, sem, wsem, m_scr,
-                   l_scr, acc_scr, *, n_streams: int, n_heads: int,
-                   block: int, grain: int, max_len: int, sm_scale: float):
+                   l_scr, acc_scr, *tile, n_streams: int, n_heads: int,
+                   block: int, grain: int, max_len: int, sm_scale: float,
+                   group: int = 1):
     del kc_hbm, vc_hbm  # the same buffers as kc_out / vc_out (aliased)
     n_items = n_items_ref[0]
+    # Mosaic takes a one-row DMA into store rows one lane tile wide only;
+    # wider rows go in through their tile of 8 (``tile``): read at the
+    # start, the new row set in VMEM, written back at the end, when every
+    # item has read what it reads
+    row_dma = not tile
 
     def row_copies(b):
         at = (pl.ds(row0_ref[b], n_heads), pl.ds(pos_ref[b], 1))
@@ -207,6 +234,25 @@ def _decode_kernel(row0_ref, pos_ref, write_ref, n_items_ref, item_b_ref,
                                       wsem.at[0, b]),
                 pltpu.make_async_copy(vn_ref.at[b], vc_out.at[at],
                                       wsem.at[1, b]))
+
+    def tile_copies(b, back):
+        """The 8-row tile of stream b's store that holds row pos[b], into
+        ``tile`` or, ``back``, out of it."""
+        first = pl.multiple_of(pos_ref[b] // 8 * 8, 8)
+        at = (pl.ds(row0_ref[b], n_heads), pl.ds(first, 8))
+        out = []
+        for i, store in enumerate((kc_out, vc_out)):
+            ends = (store.at[at], tile[0].at[i, b])
+            src, dst = ends[::-1] if back else ends
+            out.append(pltpu.make_async_copy(src, dst, wsem.at[i, b]))
+        return out
+
+    def set_row(b):
+        at = jax.lax.broadcasted_iota(jnp.int32, tile[0].shape[2:], 1) \
+            == pos_ref[b] % 8                           # (H, 8, hd)
+        for i, new in enumerate((kn_ref, vn_ref)):
+            tile[0][i, b] = jnp.where(at, new[b].astype(tile[0].dtype),
+                                      tile[0][i, b])
 
     def live_rows(t):
         # rows of item t's block below its stream's position: block for
@@ -247,9 +293,14 @@ def _decode_kernel(row0_ref, pos_ref, write_ref, n_items_ref, item_b_ref,
                                  grain))
 
     # a stream without items (no request) gets its own value row back
-    o_ref[...] = vn_ref[...].astype(o_ref.dtype)
+    if group == 1:
+        o_ref[...] = vn_ref[...].astype(o_ref.dtype)
+    else:
+        for hq in range(n_heads * group):
+            o_ref[:, hq] = vn_ref[:, hq // group].astype(o_ref.dtype)
     for b in range(n_streams):
-        pl.when(write_ref[b] == 1)(functools.partial(start, row_copies(b)))
+        pl.when(write_ref[b] == 1)(functools.partial(
+            start, row_copies(b) if row_dma else tile_copies(b, False)))
     pl.when(n_items > 0)(lambda: item_copies(0, 0, start))
 
     def item(t, carry):
@@ -262,11 +313,24 @@ def _decode_kernel(row0_ref, pos_ref, write_ref, n_items_ref, item_b_ref,
 
         @pl.when(blk == 0)
         def _from_new_row():
-            m_scr[...] = jnp.sum(
-                q_ref[b].astype(jnp.float32) * kn_ref[b].astype(jnp.float32),
-                axis=-1, keepdims=True) * sm_scale      # (H, 1, 1)
+            if group == 1:
+                m_scr[...] = jnp.sum(
+                    q_ref[b].astype(jnp.float32)
+                    * kn_ref[b].astype(jnp.float32),
+                    axis=-1, keepdims=True) * sm_scale  # (H, 1, 1)
+                l_scr[...] = jnp.ones_like(l_scr)
+                acc_scr[...] = vn_ref[b].astype(jnp.float32)
+                return
             l_scr[...] = jnp.ones_like(l_scr)
-            acc_scr[...] = vn_ref[b].astype(jnp.float32)
+            for h in range(n_heads):
+                at = pl.ds(h * group, group)
+                m_scr[at] = jnp.sum(
+                    q_ref[b, at].astype(jnp.float32)
+                    * kn_ref[b, h][None].astype(jnp.float32),
+                    axis=-1, keepdims=True) * sm_scale  # (group, 1, 1)
+                acc_scr[at] = jnp.broadcast_to(
+                    vn_ref[b, h][None].astype(jnp.float32),
+                    (group,) + vn_ref.shape[2:])
 
         item_copies(t, par, wait)
         n_live = p - blk * block
@@ -287,27 +351,34 @@ def _decode_kernel(row0_ref, pos_ref, write_ref, n_items_ref, item_b_ref,
             jnp.int32, (block, 1), 0)
         live = rows < p                                 # (block, 1)
 
-        def head(h):
-            k = kbuf[par, h].astype(jnp.float32)        # (block, hd)
-            v = vbuf[par, h].astype(jnp.float32)
-            s = jnp.sum(k * q_ref[b, h].astype(jnp.float32), axis=-1,
+        def fold(k, v, h, hq):
+            """Query head ``hq``'s online softmax over K/V head h's block
+            (k, v: (block, hd))."""
+            s = jnp.sum(k * q_ref[b, hq].astype(jnp.float32), axis=-1,
                         keepdims=True) * sm_scale       # (block, 1)
             s = jnp.where(live, s, _NEG_INF)
-            m_prev = m_scr[h]                           # (1, 1)
+            m_prev = m_scr[hq]                          # (1, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
             pr = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            l_scr[h] = alpha * l_scr[h] + jnp.sum(pr, axis=0, keepdims=True)
-            acc_scr[h] = alpha * acc_scr[h] + jnp.sum(
+            l_scr[hq] = alpha * l_scr[hq] + jnp.sum(pr, axis=0,
+                                                    keepdims=True)
+            acc_scr[hq] = alpha * acc_scr[hq] + jnp.sum(
                 pr * v, axis=0, keepdims=True)          # (1, hd)
-            m_scr[h] = m_new
+            m_scr[hq] = m_new
+
+        def head(h):
+            k = kbuf[par, h].astype(jnp.float32)        # (block, hd)
+            v = vbuf[par, h].astype(jnp.float32)
+            for j in range(group):
+                fold(k, v, h, h * group + j)
 
         def heads(i, c):
             for j in range(unroll):
                 head(i * unroll + j)
             return c
 
-        unroll = math.gcd(n_heads, _HEAD_UNROLL)
+        unroll = math.gcd(n_heads, max(_HEAD_UNROLL // group, 1))
         jax.lax.fori_loop(0, n_heads // unroll, heads, 0)
 
         @pl.when((blk + 1) * block >= p)
@@ -318,12 +389,26 @@ def _decode_kernel(row0_ref, pos_ref, write_ref, n_items_ref, item_b_ref,
 
     jax.lax.fori_loop(0, n_items, item, 0)
     for b in range(n_streams):
-        pl.when(write_ref[b] == 1)(functools.partial(wait, row_copies(b)))
+        if row_dma:
+            pl.when(write_ref[b] == 1)(functools.partial(wait,
+                                                         row_copies(b)))
+            continue
+
+        @pl.when(write_ref[b] == 1)
+        def _row_in_tile(b=b):
+            wait(tile_copies(b, False))
+            set_row(b)
+            start(tile_copies(b, True))
+
+    for b in range(0 if row_dma else n_streams):
+        pl.when(write_ref[b] == 1)(functools.partial(wait,
+                                                     tile_copies(b, True)))
 
 
 def _decode_pallas(q, k_new, v_new, kc, vc, li, pos, active=None, *,
                    layer_axis: int, block: int, grain: int, interpret: bool):
-    b, h, _, hd = q.shape
+    b, hq, _, hd = q.shape
+    h = k_new.shape[1]                                  # K/V heads
     max_len = kc.shape[-2]
     n_layers = kc.shape[layer_axis]
     li = jnp.asarray(li, jnp.int32)
@@ -349,7 +434,9 @@ def _decode_pallas(q, k_new, v_new, kc, vc, li, pos, active=None, *,
     flat = (kc.shape[0] * kc.shape[1] * h, max_len, hd)
     kernel = functools.partial(
         _decode_kernel, n_streams=b, n_heads=h, block=block, grain=grain,
-        max_len=max_len, sm_scale=1.0 / math.sqrt(hd))
+        max_len=max_len, sm_scale=1.0 / math.sqrt(hd), group=hq // h)
+    # a new row wider than one lane tile goes in through its tile of 8
+    tile = [] if hd == 128 else [pltpu.VMEM((2, b, h, 8, hd), kc.dtype)]
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -365,14 +452,16 @@ def _decode_pallas(q, k_new, v_new, kc, vc, li, pos, active=None, *,
             pltpu.VMEM((2, h, block, hd), vc.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SemaphoreType.DMA((2, b)),
-            pltpu.VMEM((h, 1, 1), jnp.float32),
-            pltpu.VMEM((h, 1, 1), jnp.float32),
-            pltpu.VMEM((h, 1, hd), jnp.float32),
-        ],
+            pltpu.VMEM((hq, 1, 1), jnp.float32),
+            pltpu.VMEM((hq, 1, 1), jnp.float32),
+            pltpu.VMEM((hq, 1, hd), jnp.float32),
+        ] + tile,
         input_output_aliases={9: 1, 10: 2},
-        # two buffers each of K and V, and room for the item's temporaries
+        # two buffers each of K and V, the tiles, and room for the item's
+        # temporaries
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=4 * h * block * hd * 4 + (16 << 20)),
+            vmem_limit_bytes=4 * h * block * hd * 4
+            + (2 * b * h * 8 * hd * 4 if tile else 0) + (16 << 20)),
         name="decode_attention",
         interpret=interpret,
     )(row0, pos, write, ends[-1:].astype(jnp.int32), item_b, item_blk,
@@ -389,7 +478,7 @@ def decode_attention(q, k_new, v_new, kc, vc, li, pos, active=None, *,
     are attended, and the new row is written at ``pos[b]``.
 
     Arguments and results as :func:`window_attention_reference` with a
-    window of one. On a TPU the kernel runs; elsewhere, and where no
+    window of one; the queries' heads over the new rows' give the group. On a TPU the kernel runs; elsewhere, and where no
     block size divides ``max_len``, the head size is not a multiple of
     the 128 lanes (Mosaic refuses the one-row DMA out of a padded row) or
     the store is not float32, the dense form. ``interpret=True`` (tests) runs the kernel through the Pallas

@@ -91,7 +91,7 @@ from ..obs import quality as _quality
 from ..obs import slo as _slo
 from ..obs import tracing as _tracing
 from ..ops.int8 import stack_shape
-from ..ops.pallas import decode_attention, latent_attention
+from ..ops.pallas import decode_attention
 from ..resilience import policy as _rp
 from . import sampling
 from .kv_cache import PagedKVCache
@@ -293,7 +293,8 @@ def _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
         return tokens, kc, vc, pos, outs.T, None, hits  # outs (S, n_steps)
 
     plan, count = lane
-    counted = causal_lm.is_latent(n_heads)
+    fam = causal_lm.family(n_heads)
+    counted = fam is not None
 
     def lane_step(i, carry):
         state, outs, confs, total = carry
@@ -307,7 +308,7 @@ def _chunk_scan(params, tokens, kc, vc, pos, active, skeys, temp, top_k,
         ((tokens, kc, vc, pos, active),
          jnp.zeros((n_steps, tokens.shape[0]), jnp.int32),
          jnp.zeros((n_steps, 3), jnp.float32),
-         jnp.zeros((2,), jnp.int32) if counted else None))
+         jnp.zeros((len(fam.COUNTS),), jnp.int32) if counted else None))
     return tokens, kc, vc, pos, outs.T, confs, hits
 
 
@@ -550,10 +551,10 @@ class LMEngine:
         # prefill/decode chunk: explicit wins; unset consults the
         # autotuner (store/model only — no sweep closure: constructing
         # an engine must never dispatch), else the hand-set 8
-        #: whether the tree is the latent-attention / expert family
-        #: (models/glm_moe_lite.py): what stands for ``n_heads`` says so
-        self._latent = causal_lm.is_latent(n_heads)
-        heads = n_heads.n_heads if self._latent else n_heads
+        #: the module of the tree's family where it is not the GPT-2 block
+        #: (``causal_lm.family``): what stands for ``n_heads`` says which
+        self._family = causal_lm.family(n_heads)
+        heads = n_heads.n_heads if self._family else n_heads
         if chunk is None:
             chunk = 8
             tn = _tune.TUNE_HOOK
@@ -613,8 +614,8 @@ class LMEngine:
                     candidates=cands, default=dflt))
         if ps < 0:
             raise ValueError("kv_page_size must be >= 0 (0 = contiguous)")
-        if self._latent:
-            self._refuse_latent(params, ps, spec_draft, max_len)
+        if self._family:
+            self._refuse_family(params, ps, spec_draft, max_len)
         self._kv: Optional[PagedKVCache] = None
         self._m_slot = max_len  # one request's token capacity
         if ps:
@@ -648,7 +649,7 @@ class LMEngine:
             self._table_host = np.zeros((n_slots, slot_pages), np.int32)
         #: rows a slot's length rounds up to in its attention kernel's
         #: reads (``stats['kv_rows_attended']``)
-        self._kv_grain = (latent_attention if self._latent
+        self._kv_grain = (self._family.ATTENTION if self._family
                           else decode_attention).read_grain(self._m_slot)
         if self.role != "unified" and self._kv is None:
             # the page pool IS the transfer substrate: a prefill engine
@@ -759,14 +760,17 @@ class LMEngine:
                       # submit -> slot granted, summed and the longest
                       "admission_wait_s": 0.0,
                       "admission_wait_max_s": 0.0}
-        if self._latent:
-            # the routing of the steps read (counted inside the program
-            # from the picks, lane rows included): distinct experts that
-            # got a row, a step and expert layer, and the picks made
-            # (rows x experts a token); and the latent rows the steps were
-            # asked to read, as kv_rows_attended counts K/V rows
-            self.stats.update(experts_hit=0, expert_rows=0,
-                              latent_rows_attended=0)
+        if self._family:
+            # the counts of the steps read, counted inside the program
+            # (lane rows included): for a family with experts the distinct
+            # experts that got a row, a step and expert layer, and the
+            # picks made; for the latent family the latent rows the steps
+            # were asked to read, as kv_rows_attended counts K/V rows; for
+            # the Gated DeltaNet family the slot-layers whose recurrent
+            # state a step updated
+            rows = getattr(self._family, "ROWS_ATTENDED", None)
+            self.stats.update((key, 0) for key in
+                              self._family.COUNTS + ((rows,) if rows else ()))
         # <phase>_s: seconds in each phase, added by obs/tracing.phase
         self.stats.update((key, 0.0) for key in _PHASE_KEYS)
         # one record an iteration: the last STEP_RING of them, and the
@@ -887,16 +891,19 @@ class LMEngine:
         (``causal_lm.slot_store_shapes``: (S, L·H, max_len, hd) twice for
         the GPT-2 block). Overridden by the mesh-sharded engine to
         allocate sharded-from-birth."""
-        kshape, vshape = causal_lm.slot_store_shapes(
-            self.params, self.n_heads, self.n_slots, self.max_len)
-        return (jnp.zeros(kshape, jnp.float32),
-                jnp.zeros(vshape, jnp.float32))
+        def zeros(shape):
+            if isinstance(shape[0], tuple):
+                return tuple(zeros(part) for part in shape)
+            return jnp.zeros(shape, jnp.float32)
 
-    def _refuse_latent(self, params, page_size: int, spec_draft: int,
+        return tuple(map(zeros, causal_lm.slot_store_shapes(
+            self.params, self.n_heads, self.n_slots, self.max_len)))
+
+    def _refuse_family(self, params, page_size: int, spec_draft: int,
                        max_len: int) -> None:
-        """A latent-attention / expert tree is served by the contiguous
-        lane engine alone; whatever else was asked for is refused here,
-        with its name, before anything could miscompute."""
+        """A tree of a family other than the GPT-2 block is served by the
+        contiguous lane engine alone; whatever else was asked for is
+        refused here, with its name, before anything could miscompute."""
         what = None
         if not self._lane_capable:
             what = ("an engine whose chunk program carries no prompt lane "
@@ -904,7 +911,8 @@ class LMEngine:
                     "and V by head")
         elif page_size:
             what = (f"the paged KV cache (kv_page_size={page_size}): pages "
-                    "hold K and V by head, not a latent row")
+                    "hold K and V by head, and none of this family's "
+                    "stores")
         elif spec_draft:
             what = (f"speculative decoding (spec_draft={spec_draft}): the "
                     "verify window attends K and V by head")
@@ -919,8 +927,7 @@ class LMEngine:
                     "stacks only")
         if what is not None:
             raise ValueError(
-                "LMEngine cannot serve a latent-attention / expert tree "
-                f"(models/glm_moe_lite.py) with {what}")
+                f"LMEngine cannot serve {self._family.DESCRIBED} with {what}")
 
     # -- public API ------------------------------------------------------- #
 
@@ -1938,10 +1945,12 @@ class LMEngine:
             st["slot_steps"] += chunk.slot_steps
             st["kv_rows_attended"] += chunk.kv_rows
             if chunk.counts is not None:
-                hit, picks = map(int, np.asarray(chunk.counts))
-                st["experts_hit"] += hit
-                st["expert_rows"] += picks
-                st["latent_rows_attended"] += chunk.kv_rows
+                fam = self._family
+                for key, value in zip(fam.COUNTS, np.asarray(chunk.counts)):
+                    st[key] += int(value)
+                rows = getattr(fam, "ROWS_ATTENDED", None)
+                if rows:
+                    st[rows] += chunk.kv_rows
             if chunk.lane:
                 for key, value in (("lane_steps", n),
                                    ("lane_rows", n * LANE_ROWS),

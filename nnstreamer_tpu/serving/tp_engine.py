@@ -218,11 +218,11 @@ class TPLMEngine(LMEngine):
     def __init__(self, params: Dict[str, Any], n_heads: int, max_len: int,
                  mesh: Mesh, axis: str = "model", **kw) -> None:
         n = mesh.shape[axis]
-        if causal_lm.is_latent(n_heads):
+        fam = causal_lm.family(n_heads)
+        if fam is not None:
             raise ValueError(
-                "TPLMEngine (the mesh-sharded engine) cannot serve a "
-                "latent-attention / expert tree (models/glm_moe_lite.py): "
-                "its decode body shards K and V by head")
+                "TPLMEngine (the mesh-sharded engine) cannot serve "
+                f"{fam.DESCRIBED}: its decode body shards K and V by head")
         if n_heads % n:
             raise ValueError(f"n_heads={n_heads} not divisible by "
                              f"mesh axis {axis}={n}")

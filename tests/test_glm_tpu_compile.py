@@ -1,7 +1,8 @@
 """The two kernels of the latent-attention / expert family compiled for a
-described v5e chip at GLM-4.7-Flash's widths, and the GPT-2 block's decode
-attention at Cerebras-GPT-1.3B's, by the TPU compiler that is installed
-beside JAX: Mosaic's own passes run (they refused a one-row DMA
+described v5e chip at GLM-4.7-Flash's widths, the GPT-2 block's decode
+attention at Cerebras-GPT-1.3B's, and the Gated DeltaNet family's
+recurrent update and grouped decode attention at Qwen3-Next-80B-A3B's, by
+the TPU compiler that is installed beside JAX: Mosaic's own passes run (they refused a one-row DMA
 into a 512-wide latent row that every interpreter test had passed), nothing
 executes, no chip is needed. One file, one worker: the process that
 describes the topology holds libtpu until it exits (the topology is
@@ -106,4 +107,51 @@ def test_decode_attention_compiles_at_the_production_shape(one_chip,
     # the stores are updated in place
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= 2 * s * layers * h * m * hd * 4
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_gated_delta_compiles_at_the_published_widths(one_chip, no_cache):
+    """64 slots x 6 Gated DeltaNet layers x 32 value heads of 128 x 128
+    (16 key heads): the state is updated in place, a stream's 32 states a
+    DMA into a buffer of three."""
+    from nnstreamer_tpu.ops.pallas import gated_delta as gd
+
+    s, layers, h, hk, d = 64, 6, 32, 16, 128
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    fn = jax.jit(functools.partial(gd._step_pallas, interpret=False),
+                 donate_argnums=(5,))
+    compiled = fn.lower(
+        sd((s, hk, d)), sd((s, hk, d)), sd((s, h, d)), sd((s, h)),
+        sd((s, h)), sd((s, layers, h, d, d)), sd((), jnp.int32),
+        sd((s,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= s * layers * h * d * d * 4
+    assert mem.temp_size_in_bytes < (8 << 20)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_grouped_decode_attention_compiles_at_the_published_widths(
+        one_chip, no_cache):
+    """16 query heads over 2 K/V heads of 256, 64 slots x 2 full layers x
+    2048 positions: an item reads a K/V head's block once for its group of
+    8, and a new row 256 wide goes in through its tile of 8 rows (Mosaic
+    refuses the one-row DMA into it)."""
+    from nnstreamer_tpu.ops.pallas import decode_attention as da
+
+    s, layers, hq, hkv, m, hd = 64, 2, 16, 2, 2048, 256
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    fn = jax.jit(functools.partial(da._decode_pallas, layer_axis=1,
+                                   block=da.kv_block(m),
+                                   grain=da.read_grain(m), interpret=False),
+                 donate_argnums=(3, 4))
+    row = sd((s, hkv, 1, hd))
+    compiled = fn.lower(
+        sd((s, hq, 1, hd)), row, row, sd((s, layers, hkv, m, hd)),
+        sd((s, layers, hkv, m, hd)), sd((), jnp.int32), sd((s,), jnp.int32),
+        sd((s,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * s * layers * hkv * m * hd * 4
+    assert mem.temp_size_in_bytes < (8 << 20)
     assert "tpu_custom_call" in compiled.as_text()

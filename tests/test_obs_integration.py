@@ -76,6 +76,7 @@ def _run_query_roundtrip():
         Pipeline.link(src, qc, sink)
         cp.run(timeout=60)
         assert sink.num_buffers == 3
+        return qc.name
     finally:
         sp.stop()
 
@@ -140,9 +141,12 @@ def test_engine_slot_gauges_live_and_release(metrics_on):
 
 
 def test_query_inflight_gauge_registered(metrics_on):
-    _run_query_roundtrip()
+    name = _run_query_roundtrip()
     snap = obs_metrics.registry().snapshot()
-    depth = snap["nnstpu_query_inflight_depth"]["series"]
-    assert all(s["value"] == 0 for s in depth)  # all drained at EOS
+    # this client's series: clients of other tests in the same process
+    # may still be registered, with requests of their own in flight
+    depth = [s for s in snap["nnstpu_query_inflight_depth"]["series"]
+             if s["labels"]["element"] == name]
+    assert depth and all(s["value"] == 0 for s in depth)  # drained at EOS
     rec = snap["nnstpu_query_reconnects_total"]["series"]
     assert sum(s["value"] for s in rec) >= 1
