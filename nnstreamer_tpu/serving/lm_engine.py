@@ -39,7 +39,10 @@ XLA-shaped design decisions:
   so the device has its next program queued when one ends. An end the
   tokens decide (an EOS) is found a chunk late and that chunk's columns
   for the request are dropped; ``_drain`` reads what is in flight for
-  whoever looks from outside the loop.
+  whoever looks from outside the loop. An end the host can count (a
+  request's length) frees its slot when the chunk that ends it is
+  dispatched, so the next prompt's first window rides the chunk behind
+  that one (``_release``).
 - **Paged KV cache (opt-in: ``kv_page_size > 0``).** The per-slot
   contiguous stores are replaced by one shared page pool
   (serving/kv_cache.py): admission is gated on page availability
@@ -707,6 +710,10 @@ class LMEngine:
         # chunk — so the capacity cap never needs a blocking D2H read)
         self._pos_host: List[int] = [0] * n_slots
         self._slot_req: List[Optional[_Request]] = [None] * n_slots
+        #: rid -> (slot, request) for the requests that gave their slot up
+        #: when the chunk that ends them by length was dispatched, while
+        #: that chunk is unread (``_release``)
+        self._released: Dict[int, Tuple[int, _Request]] = {}
         self._queue: deque[_Request] = deque()
         self._finished: Dict[int, List[int]] = {}
         self._next_rid = 0
@@ -753,6 +760,9 @@ class LMEngine:
                       # the tokens of the chunk before ended the request
                       "iterations": 0, "chunks": 0,
                       "chunks_ahead": 0, "ahead_dropped_slot_steps": 0,
+                      # admissions into a slot whose request before still
+                      # had tokens in a chunk not read (``_release``)
+                      "admits_ahead": 0,
                       # wall of the dispatch phases that used an
                       # executable for the first time (compile, or its
                       # load from the cache)
@@ -1069,7 +1079,9 @@ class LMEngine:
                        reason=reason)
 
     def pending(self) -> int:
-        return len(self._queue) + sum(
+        """Requests not finished: queued, holding a slot, or past the
+        slot they gave up with tokens still in a chunk not read."""
+        return len(self._queue) + len(self._released) + sum(
             r is not None for r in self._slot_req)
 
     def progress(self, rid: int) -> Optional[List[int]]:
@@ -1079,19 +1091,26 @@ class LMEngine:
         done = self._finished.get(rid)
         if done is not None:
             return list(done)
-        slot = self.slot_of(rid)
-        if slot is not None:
-            return list(self._slot_req[slot].out)
+        running = self._running(rid)
+        if running is not None:
+            return list(running[1].out)
         return [] if any(r.rid == rid for r in self._queue) else None
 
     def slot_of(self, rid: int) -> Optional[int]:
         """The slot request ``rid`` occupies now, or None (queued,
-        finished or unknown). The slot a finished request took is in
-        the step record of the iteration that admitted it."""
+        finished or unknown). A request whose last tokens are in a chunk
+        not read yet has its slot until they are, even where the next
+        request was admitted to it. The slot a finished request took is
+        in the step record of the iteration that admitted it."""
+        running = self._running(rid)
+        return None if running is None else running[0]
+
+    def _running(self, rid: int) -> Optional[Tuple[int, _Request]]:
+        """(slot, request) of a request admitted and not finished."""
         for slot, req in enumerate(self._slot_req):
             if req is not None and req.rid == rid:
-                return slot
-        return None
+                return slot, req
+        return self._released.get(rid)
 
     def recent_steps(self) -> List[Dict[str, Any]]:
         """The records of the last ``STEP_RING`` iterations, oldest
@@ -1100,7 +1119,9 @@ class LMEngine:
         ``wall_s``, ``cpu_s`` (this thread's CPU time: wall far above
         it means the thread was blocked or descheduled, wall about equal
         means Python ran, a collection included), ``phases`` (seconds
-        by phase name), ``admitted`` ([rid, slot] pairs), ``chunk``
+        by phase name), ``admitted`` ([rid, slot, ahead] triples: ``ahead``
+        where the slot's request before still had tokens in a chunk not
+        read, ``stats["admits_ahead"]``), ``chunk``
         (decode steps dispatched, 0 for none), ``active`` and ``queued``
         (slots decoding, requests still waiting), ``lane_steps``,
         ``lane_rows`` and ``lane_tokens`` (those of the steps that carried
@@ -1423,7 +1444,11 @@ class LMEngine:
     def _admit(self, step: "_tracing.phase", rec: Dict[str, Any]) -> None:
         """Take requests from the head of the queue into the free slots,
         lowest slot first. A request holds its slot (``_slot_req``) from
-        here on.
+        here on, until it is retired or, on an engine that runs ahead,
+        until the chunk that ends it by length is dispatched
+        (``_release``): a slot that chunk frees is filled here, in the
+        iteration after, and the new prompt's first window rides the chunk
+        dispatched next, behind the one that ends the old request.
 
         On an engine with a prompt lane this is host work only: the
         slot's sampling controls are noted in the host's arrays, the slot
@@ -1565,7 +1590,7 @@ class LMEngine:
                     req.decode_span = _tracing.start_span(
                         "serving.decode", parent=req.span.context,
                         attrs={"slot": slot})
-                rec["admitted"].append([req.rid, slot])
+                self._note_admission(slot, req, rec)
                 self._pos_host[slot] = t
                 self._slot_req[slot] = req
                 self._retire_if_done(slot, req)
@@ -1608,8 +1633,17 @@ class LMEngine:
         lbl = self._engine_label
         self._m_prefills.labels(lbl, "lane").inc()
         self._m_streams.labels(lbl, "admitted").inc()
-        rec["admitted"].append([req.rid, slot])
+        self._note_admission(slot, req, rec)
         self._slot_req[slot] = req
+
+    def _note_admission(self, slot: int, req: "_Request",
+                        rec: Dict[str, Any]) -> None:
+        """The step record's entry for ``req`` taking ``slot``, and
+        whether it came ahead: the slot's request before still has tokens
+        in a chunk not read."""
+        ahead = any(s == slot for s, _ in self._released.values())
+        self.stats["admits_ahead"] += ahead
+        rec["admitted"].append([req.rid, slot, ahead])
 
     def _prefill_into(self, slot: int, padded, true_len: int, skey,
                       temp, tk, tp, want_conf: bool = False):
@@ -1680,7 +1714,11 @@ class LMEngine:
         the return to ``(first, conf)``."""
         kv = self._kv
         conf = None
-        table = jnp.asarray(self._table_host[slot])
+        # copies of the host's page table go to the device, here and in
+        # every dispatch: the host writes the table in place while a
+        # program may still wait to run, and an array made from host
+        # memory without a copy (``jnp.asarray`` on the CPU) reads it then
+        table = jnp.array(self._table_host[slot])
         if hit == 0:
             if want_conf:
                 first, kc, vc, pos, conf = _prefill_admit_conf(
@@ -1745,11 +1783,14 @@ class LMEngine:
         (dispatched an iteration earlier, or just now by an engine that
         was idle), then retire: the device has k + 1 queued when k ends,
         and the readback's tail and the host's work between two chunks
-        leave the streams' time. What the host cannot count ahead is an
-        end the tokens decide (an EOS): the chunk ahead decodes such a
-        request all the same, and its columns are dropped when read
-        (``_read_chunk``). Whatever the order, nothing stays in flight
-        once no request is left."""
+        leave the streams' time. An end by length the host counts at the
+        dispatch of the chunk that holds it, and the slot is free for the
+        next iteration's ``_admit`` from there (``_release``). What the
+        host cannot count ahead is an end the tokens decide (an EOS): the
+        chunk ahead decodes such a request all the same, its columns are
+        dropped when read (``_read_chunk``), and its slot is freed when
+        the EOS is read. Whatever the order, nothing stays in flight once
+        no request is left."""
         chunk = self._flight
         if chunk is None:
             if self.spec_draft > 0 and self._decode_speculative(step, rec):
@@ -1777,10 +1818,11 @@ class LMEngine:
     def _decoding(self) -> List[int]:
         """The slots that decode from the first step of the chunk about to
         be dispatched: those that hold a request whose prompt has left the
-        lane and that the chunk in flight does not end by length."""
+        lane. A request that a chunk in flight ends by length holds none
+        (``_release``); every other engine has read each chunk it
+        dispatched, and retired what it ended, before the next."""
         return [s for s, r in enumerate(self._slot_req)
-                if r is not None and s not in self._lane_at
-                and self._left(r) > 0]
+                if r is not None and s not in self._lane_at]
 
     def _dispatch_chunk(self, step: "_tracing.phase", rec: Dict[str, Any],
                         ahead: bool = False) -> Optional[_Chunk]:
@@ -1858,7 +1900,24 @@ class LMEngine:
                        counts=self._chunk_counts, cols=cols)
         if lane:
             self._join_lane(chunk)
+        if self._runs_ahead():
+            self._release(chunk)
         return chunk
+
+    def _release(self, chunk: _Chunk) -> None:
+        """Free the slot of each request that ``chunk``, just dispatched,
+        ends by length: the next iteration's ``_admit`` fills it, a chunk
+        before the one that reads ``chunk``. The request is retired when
+        ``chunk`` is read (``_read_chunk``, which finds it in ``cols``) and
+        counts in ``pending`` until then (``_released``). On the device
+        the new prompt's windows run after ``chunk``, since the stores are
+        threaded from one chunk to the next, and write the slot's rows
+        (and a recurrent state) from position 0 before anything reads
+        them; ``chunk`` holds its own copies of the slot's controls."""
+        for slot, (req, _, _) in chunk.cols.items():
+            if self._left(req) == 0:
+                self._released[req.rid] = (slot, req)
+                self._free_slot(slot, req)
 
     def _note_first_use(self, key: Any, dispatch: "_tracing.phase",
                         rec: Dict[str, Any]) -> None:
@@ -1907,11 +1966,14 @@ class LMEngine:
         dropped, and they count as waste: slots x steps = kept + wasted
         holds. The rows such a slot wrote lie past the request's own,
         where a later prompt's lane overwrites them in the device's
-        order. A first token is handed out (``out``, the TTFT, the end of
-        ``prefill_span``) with the tokens of the chunk behind its own, so
-        a stream's first token comes with its first chunk of tokens; a
-        request that ends in the chunk of its first token gets all of
-        them at once."""
+        order. A request this chunk ends by length gave its slot up when
+        the chunk was dispatched (``_release``), and the slot may hold the
+        next request by now: it is retired from ``cols`` all the same, and
+        leaves the slot to its new holder. A first token is handed out
+        (``out``, the TTFT, the end of ``prefill_span``) with the tokens
+        of the chunk behind its own, so a stream's first token comes with
+        its first chunk of tokens; a request that ends in the chunk of its
+        first token gets all of them at once."""
         st = self.stats
         n = chunk.n
         # blocks until the device has run the chunk, then copies (S, n)
@@ -2040,7 +2102,7 @@ class LMEngine:
             (self._tokens, kv.kpool, kv.vpool, self._pos, outs) = \
                 _decode_chunk_paged(
                     self.params, self._tokens, kv.kpool, kv.vpool,
-                    jnp.asarray(self._table_host), self._pos, active,
+                    jnp.array(self._table_host), self._pos, active,
                     self._skeys, self._temp, self._topk, self._topp,
                     n_heads=self.n_heads, n_steps=n)
             return outs
@@ -2079,7 +2141,7 @@ class LMEngine:
             carried, kv.kpool, kv.vpool, pos, outs, m = \
                 _verify_chunk_paged(
                     self.params, tokens_in, kv.kpool, kv.vpool,
-                    jnp.asarray(self._table_host), self._pos,
+                    jnp.array(self._table_host), self._pos,
                     n_heads=self.n_heads)
             return carried, self._kc, self._vc, pos, outs, m
         return _verify_chunk(self.params, tokens_in, self._kc, self._vc,
@@ -2280,7 +2342,8 @@ class LMEngine:
                     self._engine_label, self._slo_tenant(), req.session,
                     float(ent), float(top1), float(margin))
             self._finished[req.rid] = req.out
-            self._slot_req[slot] = None
+            if self._released.pop(req.rid, None) is None:
+                self._free_slot(slot, req)
             if self._kv is not None and req.kv_lease is not None:
                 # positions 0..consumed-1 hold valid K/V (the final
                 # output token was never written back); register those
@@ -2297,16 +2360,25 @@ class LMEngine:
                     self._session_paths.move_to_end(req.session)
                     while len(self._session_paths) > SESSION_PATHS_LIMIT:
                         self._session_paths.popitem(last=False)
-                self._table_host[slot] = 0
-            if req.temperature > 0.0 and self._lane:
-                # restore greedy defaults so a finished sampled stream
-                # doesn't keep the all-greedy fast path disabled for the
-                # slots that remain
-                self._temp[slot], self._topk[slot] = 0.0, 0
-                self._topp[slot] = 1.0
-            elif req.temperature > 0.0:
-                # the same on the device (and for the speculation gate)
-                sl = jnp.int32(slot)
-                self._temp = _slot_insert(self._temp, jnp.float32(0.0), sl)
-                self._topk = _slot_insert(self._topk, jnp.int32(0), sl)
-                self._topp = _slot_insert(self._topp, jnp.float32(1.0), sl)
+                if self._slot_req[slot] is None:
+                    # else the row is the next request's: a release
+                    # (``_release``) let it take the slot before this read
+                    self._table_host[slot] = 0
+
+    def _free_slot(self, slot: int, req: _Request) -> None:
+        """``req`` gives ``slot`` up: retired, or its last chunk
+        dispatched (``_release``), which holds its own copies of the
+        slot's controls."""
+        self._slot_req[slot] = None
+        if req.temperature > 0.0 and self._lane:
+            # restore greedy defaults so a finished sampled stream
+            # doesn't keep the all-greedy fast path disabled for the
+            # slots that remain
+            self._temp[slot], self._topk[slot] = 0.0, 0
+            self._topp[slot] = 1.0
+        elif req.temperature > 0.0:
+            # the same on the device (and for the speculation gate)
+            sl = jnp.int32(slot)
+            self._temp = _slot_insert(self._temp, jnp.float32(0.0), sl)
+            self._topk = _slot_insert(self._topk, jnp.int32(0), sl)
+            self._topp = _slot_insert(self._topp, jnp.float32(1.0), sl)

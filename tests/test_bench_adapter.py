@@ -31,7 +31,8 @@ def test_more_requests_than_slots_and_the_rule_held(driven):
     assert len(sched) > 2 * eng.n_slots
     assert all(r.finished for r in window.requests)
     assert adapter._rule_held
-    took = [slot for rec in eng.recent_steps() for _, slot in rec["admitted"]]
+    took = [slot for rec in eng.recent_steps()
+            for _, slot, _ in rec["admitted"]]
     assert len(set(took)) > 1       # more than one slot was taken again
 
 

@@ -137,7 +137,8 @@ def test_lane_two_prompts_queued_while_streams_decode(lane_params):
     # the two are admitted and their six windows planned into the chunk
     # behind the one in flight, which is read: the old stream's four
     assert step() == ((4, 0, 0, 1), [9, 0, 0])
-    assert eng.recent_steps()[-1]["admitted"] == [[ra, 1], [rb, 2]]
+    assert eng.recent_steps()[-1]["admitted"] == [[ra, 1, False],
+                                                  [rb, 2, False]]
     assert eng.slot_of(rb) == 2
     # in flight: a chunk of four lane steps. a's two windows, then it
     # decodes in steps 2 and 3 of the same chunk; b's first two windows

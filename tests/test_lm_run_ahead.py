@@ -6,9 +6,13 @@ keeps dispatch, read, retire.
 Contract: the tokens are those of the same engine in the old order, whatever
 ends a request and whenever it arrives; slots x steps = kept + wasted holds
 after every iteration, with the columns a run-ahead chunk decoded for a
-request that an EOS had already ended counted as waste; a first token is
-handed out with the chunk behind it; ``_drain()`` makes the state a caller
-outside the loop sees the old order's; a quiet engine has nothing in flight.
+request that an EOS had already ended counted as waste; a request that a
+dispatched chunk ends by length gives its slot to the queue's head for the
+chunk right behind that one, and is seen running (``pending``,
+``slot_of``, ``progress``) until that chunk is read, where an EOS frees a
+slot only once it is read; a first token is handed out with the chunk
+behind it; ``_drain()`` makes the state a caller outside the loop sees the
+old order's; a quiet engine has nothing in flight.
 The product has no switch for the order: the tests that need the old one
 override the private predicate ``_runs_ahead`` on their engine. A module of
 its own, so that its executables are dropped apart from the others'
@@ -60,6 +64,12 @@ def kept_tokens(reqs):
     return sum(max(len(r.out) + len(r.held) - 1, 0) for r in reqs)
 
 
+def admissions(eng):
+    """rid -> (slot, ahead) from the step records."""
+    return {rid: (slot, ahead) for rec in eng.recent_steps()
+            for rid, slot, ahead in rec["admitted"]}
+
+
 def lane_holds(eng, reqs):
     """What holds after every iteration of the engine with the lane, in
     either order."""
@@ -67,11 +77,19 @@ def lane_holds(eng, reqs):
     assert eng.n_slots * st["decode_steps"] \
         == kept_tokens(reqs) + st["wasted_slot_steps"]
     assert st["chunks_ahead"] <= st["chunks"]
+    assert st["admits_ahead"] == sum(a for _, a in admissions(eng).values())
+    assert eng.pending() == sum(not r.done for r in reqs)
+    queued = {r.rid for r in eng._queue}
     for r in reqs:
         # a first token alone is the whole of what was asked for, or
         # ended the request: otherwise it comes with the chunk behind
         assert len(r.out) != 1 or r.done
         assert r.due >= 0 and (not r.done or not r.held)
+        # running to whoever looks until its last chunk is read, even
+        # where its slot holds the next request by now
+        running = not r.done and r.rid not in queued
+        assert (eng.slot_of(r.rid) is not None) == running
+        assert eng.progress(r.rid) == (r.out if r.rid not in queued else [])
 
 
 def nothing_in_flight(eng, reqs):
@@ -166,7 +184,19 @@ CASES = {
     "idle_and_started_again": (
         [req(0, 9, 6), req(1, 40, 3), req(40, 70, 9), req(41, 5, 2),
          req(80, 12, 5)], False),
+    "queue_behind_full_slots": (
+        [req(0, 5 + 7 * i, 3 + 2 * i, i) for i in range(6)], False),
+    "queue_behind_full_slots_sampled": (
+        [req(0, 5 + 7 * i, 3 + 2 * i, i, **dict(SAMPLED, seed=i))
+         for i in range(6)], False),
+    "one_token_answers_in_a_queue": (
+        [req(0, 20, 14)] + [req(0, 9, 1, i) for i in range(5)], False),
 }
+
+#: the cases whose queue waits for slots that chunks end by length: some
+#: of their admissions come ahead of the read of such a chunk
+QUEUED = ("queue_behind_full_slots", "queue_behind_full_slots_sampled",
+          "one_token_answers_in_a_queue")
 
 
 @pytest.mark.parametrize("name", CASES)
@@ -186,6 +216,9 @@ def test_tokens_are_the_old_orders(params, name):
     assert (st["ahead_dropped_slot_steps"] > 0) == eos
     assert old.stats["chunks_ahead"] == 0
     assert old.stats["ahead_dropped_slot_steps"] == 0
+    assert old.stats["admits_ahead"] == 0
+    if name in QUEUED:
+        assert st["admits_ahead"] > 0
     assert st["tokens_out"] == old.stats["tokens_out"]
     assert st["lane_tokens"] == old.stats["lane_tokens"]
     # the host's mirror of the positions is the device's, in flight or not
@@ -223,6 +256,8 @@ def test_the_other_engines_keep_the_old_order(params, kind):
     st = eng.stats
     assert st["chunks"] > 0 and st["chunks_ahead"] == 0
     assert st["ahead_dropped_slot_steps"] == 0
+    assert st["admits_ahead"] == 0 and not eng._released
+    assert not any(a for _, a in admissions(eng).values())
 
 
 def test_enrolling_reads_what_is_in_flight(params):
@@ -353,3 +388,95 @@ def test_a_drain_comes_before_every_look_from_outside(params, call):
     assert_docs_equal(got_doc, want_doc)
     if call in ("export_session", "checkpoint_session"):
         assert got_doc is not None
+
+
+def dispatch_log(eng):
+    """Every chunk the engine dispatches, in order: (iteration, the rids it
+    gives tokens to, the rids whose prompt windows ride it)."""
+    log = []
+    dispatch = eng._dispatch_chunk
+
+    def logged(step, rec, ahead=False):
+        waiting = {s: (eng._slot_req[s].rid, at)
+                   for s, at in eng._lane_at.items()}
+        chunk = dispatch(step, rec, ahead)
+        if chunk is not None:
+            log.append((rec["iteration"],
+                        {req.rid for slot, (req, _, keeps) in
+                         chunk.cols.items()
+                         if keeps or slot in chunk.firsts},
+                        {rid for s, (rid, at) in waiting.items()
+                         if eng._lane_at.get(s) != at}))
+        return chunk
+
+    eng._dispatch_chunk = logged
+    return log
+
+
+@pytest.mark.parametrize("sampled", [False, True])
+def test_the_queue_head_takes_a_slot_when_its_last_chunk_is_dispatched(
+        params, sampled):
+    """R's last three tokens ride the chunk dispatched in iteration 3. That
+    dispatch frees its slot: N, at the queue's head, takes it in
+    iteration 4 while the chunk is unread, and N's first window rides the
+    next chunk dispatched, right behind R's last, as in the order that
+    reads each chunk before the next (where N is admitted after the read).
+    Until the read R is running to whoever looks, from the slot N holds."""
+    kw = dict(SAMPLED) if sampled else {}
+    arrivals = [req(0, 5, 13, 1, **kw), req(0, 9, 40, 2, **kw),
+                req(0, 6, 4, 3, **kw)]
+    ahead, old = engine(params), engine(params, ahead=False)
+    r, _, n = (ahead.submit(**a) for _, a in arrivals)
+    for _, a in arrivals:
+        old.submit(**a)
+    logs = dispatch_log(ahead), dispatch_log(old)
+    for _ in range(3):
+        ahead.step_iteration()
+    # released and unread: no slot holds R, yet R is running in slot 0
+    assert ahead._slot_req[0] is None and ahead.slot_of(r) == 0
+    assert ahead.pending() == 3 and len(ahead.progress(r)) == 10
+    seen = []
+    read = ahead._read_chunk
+
+    def looked(chunk, step, rec):
+        seen.append((ahead.slot_of(r), ahead.slot_of(n), ahead.pending(),
+                     len(ahead.progress(r))))
+        read(chunk, step, rec)
+
+    ahead._read_chunk = looked
+    ahead.step_iteration()
+    assert seen == [(0, 0, 3, 10)]
+    assert ahead.slot_of(r) is None and ahead.progress(r) == ahead.results[r]
+    assert admissions(ahead)[n] == (0, True)
+    assert ahead.stats["admits_ahead"] == 1
+    got, want = ahead.run(), old.run()
+    assert got == want
+    assert admissions(old)[n] == (0, False) and old.stats["admits_ahead"] == 0
+    for log in logs:
+        last_r = max(i for i, (_, gets, _) in enumerate(log) if r in gets)
+        first_n = min(i for i, (_, _, rides) in enumerate(log) if n in rides)
+        assert first_n == last_r + 1
+        assert log[first_n][0] == 4
+    # slots x steps = kept + wasted
+    st = ahead.stats
+    kept = sum(len(out) - 1 for out in got.values())
+    assert SLOTS * st["decode_steps"] == kept + st["wasted_slot_steps"]
+
+
+@pytest.mark.parametrize("idx", [0, 2, 4])
+def test_an_eos_frees_its_slot_when_it_is_read(params, idx):
+    """An EOS in the chunk that is read while the chunk behind it (which
+    would not end the request by length) is in flight: the host learns of
+    it only at the read, so the slot is not given up at a dispatch, and
+    the prompt waiting for it is admitted after that read, not ahead."""
+    arrivals = eos_case(idx)(params)
+
+    def each(eng, reqs):
+        lane_holds(eng, reqs)
+        assert reqs[0].rid not in eng._released
+
+    eng = engine(params)
+    reqs = drive(eng, arrivals, each=each)
+    assert reqs[0].out[-1] == reqs[0].eos and len(reqs[0].out) == idx + 1
+    assert admissions(eng)[reqs[2].rid] == (0, False)
+    assert eng.stats["ahead_dropped_slot_steps"] > 0

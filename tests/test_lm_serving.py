@@ -310,7 +310,8 @@ def test_mixed_lengths_empty_slot_and_midchunk_finish(params):
     eng = LMEngine(params, H, MAXLEN, n_slots=4, chunk=4)
     rids = [eng.submit(p, max_new=m) for p, m in zip(prompts, new)]
     eng.step_iteration()
-    took = dict(map(tuple, eng.recent_steps()[0]["admitted"]))
+    took = {rid: slot
+            for rid, slot, _ in eng.recent_steps()[0]["admitted"]}
     slots = [took[rid] for rid in rids]
     assert sorted(slots) == [0, 1, 2]
     res = eng.run()

@@ -199,7 +199,8 @@ def test_tp_engine_inherits_the_counters(params):
     assert st["prefills"] == 2
     assert all(st[f"{p}_s"] > 0.0 for p in STEP_PHASES)
     assert sum(st[f"{p}_s"] for p in STEP_CHILDREN) <= st["step_s"]
-    assert [r["admitted"] for r in eng.recent_steps()][0] == [[0, 0], [1, 1]]
+    assert [r["admitted"] for r in eng.recent_steps()][0] \
+        == [[0, 0, False], [1, 1, False]]
 
 
 # --------------------------------------------------------------------------- #
@@ -347,7 +348,7 @@ def test_records_say_what_each_iteration_did(params, kind):
     n = _drive(eng)
     recs = eng.recent_steps()
     assert [r["iteration"] for r in recs] == list(range(1, n + 1))
-    assert recs[0]["admitted"] == [[r0, 0], [r1, 1]]
+    assert recs[0]["admitted"] == [[r0, 0, False], [r1, 1, False]]
     # the two admitted decode from the first chunk on, or (their prompts
     # in the lane, one step each) from the second iteration on
     assert recs[0]["queued"] == 1
@@ -358,15 +359,18 @@ def test_records_say_what_each_iteration_did(params, kind):
         keys = ("active", "chunk", "lane_steps", "lane_rows", "lane_tokens")
         assert [recs[0][k] for k in keys] == [0, 2, 2, 2 * LANE_ROWS, 5 + 9]
         assert [recs[1][k] for k in keys] == [2, 4, 0, 0, 0]
-        # the third request takes the first slot that opens (iteration
-        # 3); its prompt's lane step is queued behind the chunk that was
-        # ahead and read an iteration later, while the second decodes
-        assert recs[2]["admitted"] == [[r2, 0]] and recs[2]["lane_steps"] == 0
-        assert recs[3]["active"] == 1 and recs[3]["lane_steps"] == 1
+        # the chunk of four ends the first request, so its slot is free
+        # once that chunk is dispatched (iteration 1): the third request
+        # takes it in iteration 2, ahead of the chunk's read, and its
+        # prompt's lane step rides the chunk dispatched there, read in
+        # iteration 3 while the second decodes
+        assert recs[1]["admitted"] == [[r2, 0, True]]
+        assert recs[2]["active"] == 1 and recs[2]["lane_steps"] == 1
     else:
         assert recs[0]["active"] == 2 and recs[0]["lane_steps"] == 0
     later = [r["admitted"] for r in recs[1:] if r["admitted"]]
-    assert later == [[[r2, 0]]]
+    assert later == [[[r2, 0, kind == "lane"]]]
+    assert eng.stats["admits_ahead"] == (kind == "lane")
     for r in recs:
         assert r["chunk"] - r["lane_steps"] in (0, 1, 2, 4)
         assert r["wall_s"] >= 0.0 and r["cpu_s"] >= 0.0
@@ -378,7 +382,8 @@ def test_records_say_what_each_iteration_did(params, kind):
     # copies: a caller cannot edit the engine's records
     recs[0]["admitted"].append("x")
     recs[0]["phases"]["step"] = -1.0
-    assert eng.recent_steps()[0]["admitted"] == [[r0, 0], [r1, 1]]
+    assert eng.recent_steps()[0]["admitted"] == [[r0, 0, False],
+                                                 [r1, 1, False]]
     assert eng.recent_steps()[0]["phases"]["step"] >= 0.0
 
 
@@ -539,5 +544,8 @@ def test_progress_and_slot_of(params, kind):
     assert eng.progress(a) == eng.results[a] and len(eng.progress(a)) == 9
     assert eng.progress(b) == eng.results[b]
     assert eng.slot_of(a) is None and eng.slot_of(b) is None
-    took = {rid: slot for r in eng.recent_steps() for rid, slot in r["admitted"]}
-    assert took == {a: 0, b: 0}
+    took = {rid: (slot, ahead) for r in eng.recent_steps()
+            for rid, slot, ahead in r["admitted"]}
+    # on the lane engine b takes the slot while a's last tokens are in a
+    # chunk not read
+    assert took == {a: (0, False), b: (0, kind == "lane")}
