@@ -39,19 +39,21 @@ jax can be imported (looked up on first use, never at import).
 from __future__ import annotations
 
 import contextvars
+import logging
 import os
 import threading
 import time
 from collections import OrderedDict
 from collections import deque as _deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 __all__ = [
     "Span", "SpanContext", "SpanStore", "CTX_META_KEY", "ROOT_META_KEY",
     "TRACE_META_KEY", "ctx_from_wire", "current_context", "disable",
     "enable", "enabled", "element_stats", "element_stats_report",
     "live_pipelines", "phase", "pipeline_topology", "register_pipeline",
-    "stamp_buffer", "start_span", "store",
+    "stamp_buffer", "start_span", "store", "Tick", "WAIT_CAUSES",
+    "classify_wait", "wait_cause", "wait_record", "witness_stats",
 ]
 
 #: Buffer.meta key carrying the in-process parent SpanContext
@@ -659,6 +661,241 @@ def _annotation() -> Any:
     return _ANNOTATION
 
 
+# -- the wait witness -------------------------------------------------------- #
+
+#: the witness's sleep between two looks at an armed wait
+WITNESS_TICK_NS = 5_000_000
+#: a look that woke more than this past the wake it asked for: the host
+#: stood still over the look's interval, which is then held time
+WITNESS_LATE_NS = 10_000_000
+#: looks kept a wait (a minute of them); a wait that outlasts them is
+#: judged from its last kept look to its end as that look saw it
+WITNESS_MAX_TICKS = 12_000
+#: the four disjoint parts of a wait's time (``classify_wait``); the
+#: engine's ``stats`` counts each as ``wait_<cause>_s``
+WAIT_CAUSES = ("unready", "unreturned", "held", "delivered_late")
+
+
+class Tick(NamedTuple):
+    """One look at an armed wait. Its interval runs from the look before
+    it, or from the wait's start, to ``end_ns``."""
+    end_ns: int     # when the look was made (``monotonic_ns``)
+    late_ns: int    # how far past the wake it asked for the witness woke
+    cpu_ns: int     # the process's CPU time over the witness's sleep
+    ready: bool     # the awaited arrays were all ready
+    behind: bool    # the arrays queued behind them were all ready
+
+
+def classify_wait(start_ns: int, end_ns: int,
+                  ticks: Sequence[Tick]) -> Dict[str, Any]:
+    """Split the wait ``[start_ns, end_ns]`` by what ``ticks`` saw (those
+    that end past ``end_ns`` are left out) into four disjoint parts, in
+    seconds, that sum to the wait:
+
+    * ``held_s``: the intervals of looks that woke more than
+      ``WITNESS_LATE_NS`` late. Nothing is judged there; ``held_cpu_s`` is the process's CPU
+      time over them: none where the process was descheduled, about their
+      wall where another thread held the interpreter;
+    * ``unreturned_s``: from the first look that saw the awaited arrays
+      ready to the end, less held time. The arrays turned ready up to a
+      look before, so it is a lower bound;
+    * ``delivered_late_s``: the time before that look, less held time,
+      where the arrays queued behind were first seen ready in the same
+      look, within two looks after it or before it, with no held look
+      in the wait up to the later sighting: a chunk behind takes a
+      chunk's device time after the one it waits for, unless the first
+      one's completion came late; and a completion that comes late after
+      the host stood still is the hold's (the runtime's threads stood
+      still with it);
+    * ``unready_s``: the rest: the device running, or a completion not
+      delivered, with nothing to tell which.
+
+    ``lag_s`` is from the awaited arrays' first sighting to the arrays
+    behind's, None where either was not seen."""
+    ticks = [t for t in ticks if t.end_ns <= end_ns]
+    held = held_cpu = held_after = 0
+    first = behind = None
+    prev = start_ns
+    for i, t in enumerate(ticks):
+        if t.late_ns > WITNESS_LATE_NS:
+            held += t.end_ns - prev
+            held_cpu += t.cpu_ns
+            if first is not None:
+                held_after += t.end_ns - prev
+        prev = t.end_ns
+        if first is None and t.ready:
+            first = i
+        if behind is None and t.behind:
+            behind = i
+    unreturned = 0 if first is None \
+        else end_ns - ticks[first].end_ns - held_after
+    unready = end_ns - start_ns - held - unreturned
+    late = False
+    if behind is not None and (first is None or behind <= first + 2):
+        hi = behind if first is None else max(first, behind)
+        late = not any(t.late_ns > WITNESS_LATE_NS for t in ticks[:hi + 1])
+    return {"unready_s": 0.0 if late else unready / 1e9,
+            "unreturned_s": unreturned / 1e9, "held_s": held / 1e9,
+            "delivered_late_s": unready / 1e9 if late else 0.0,
+            "held_cpu_s": held_cpu / 1e9,
+            "lag_s": None if first is None or behind is None
+            else (ticks[behind].end_ns - ticks[first].end_ns) / 1e9}
+
+
+def wait_record() -> Dict[str, Any]:
+    """The ``wait`` entry of a step record before its first wait: the
+    parts of ``classify_wait`` summed over the iteration's waits, and the
+    longest wait with its ``lag_s``."""
+    return {"unready_s": 0.0, "unreturned_s": 0.0, "held_s": 0.0,
+            "delivered_late_s": 0.0, "held_cpu_s": 0.0, "longest_s": 0.0,
+            "longest_lag_s": None}
+
+
+def wait_cause(record: Dict[str, Any]) -> Optional[str]:
+    """The largest part of a ``wait_record``, held time named by whether
+    the process ran through it; None where nothing was waited for."""
+    cause = max(WAIT_CAUSES, key=lambda c: record[f"{c}_s"])
+    if record[f"{cause}_s"] <= 0.0:
+        return None
+    if cause == "held":
+        return "held, process busy" \
+            if record["held_cpu_s"] >= record["held_s"] / 2 \
+            else "held, descheduled"
+    return cause.replace("_", " ")
+
+
+def _ready(arrays: Sequence[Any]) -> bool:
+    """Whether every array is ready; a host array or a None (no
+    ``is_ready``) is."""
+    try:
+        return all(a.is_ready() for a in arrays if hasattr(a, "is_ready"))
+    except RuntimeError:        # an array deleted under the look
+        return False
+
+
+class _Watch:
+    """One armed wait: the arrays awaited and those behind, and the looks
+    the witness made at them."""
+
+    __slots__ = ("start_ns", "awaited", "behind", "ticks", "open")
+
+    def __init__(self, start_ns: int, awaited: Sequence[Any],
+                 behind: Optional[Sequence[Any]]) -> None:
+        self.start_ns = start_ns
+        self.awaited = awaited
+        self.behind = behind
+        self.ticks: List[Tick] = []
+        self.open = True
+
+
+class _Witness:
+    """The process's one witness thread, started by the first armed wait.
+    While a wait is armed it sleeps ``WITNESS_TICK_NS`` at a time and adds
+    a ``Tick`` to every armed wait; with none armed it blocks on an event.
+    It calls nothing but ``is_ready()``, the monotonic clock and the
+    process CPU clock, never blocks on an array, and holds no lock across
+    a look: arming and disarming take none while it is awake. A thread
+    that died (an exception a look did not expect, logged once) is started
+    again by the next armed wait."""
+
+    def __init__(self) -> None:
+        self._open: List[_Watch] = []
+        self._armed = threading.Event()
+        self._start_lock = threading.Lock()
+        self._thread: Optional[threading.Thread] = None
+        self._stopping = False
+        self._failed = False    # a thread of it died, and it was logged
+        self.ticks = 0          # looks made, over the process's life
+        self.busy_ns = 0        # wall spent looking, wake to next sleep
+
+    def arm(self, start_ns: int, awaited: Sequence[Any],
+            behind: Optional[Sequence[Any]]) -> _Watch:
+        w = _Watch(start_ns, awaited, behind)
+        self._open.append(w)
+        if self._thread is None or not self._thread.is_alive():
+            with self._start_lock:
+                if self._thread is None or not self._thread.is_alive():
+                    self._thread = threading.Thread(
+                        target=self._run, name="nns-wait-witness",
+                        daemon=True)
+                    self._thread.start()
+        # the witness clears the event only before it looks at _open again
+        if not self._armed.is_set():
+            self._armed.set()
+        return w
+
+    def disarm(self, w: _Watch) -> List[Tick]:
+        """Close ``w``; the looks it got."""
+        w.open = False
+        try:
+            self._open.remove(w)
+        except ValueError:
+            pass
+        return list(w.ticks)
+
+    def stop(self, timeout: float = 1.0) -> None:
+        """End the witness thread; the next armed wait starts another."""
+        t = self._thread
+        if t is None:
+            return
+        self._stopping = True
+        self._armed.set()
+        t.join(timeout)
+        self._thread = None
+        self._stopping = False
+
+    def _run(self) -> None:
+        try:
+            self._look()
+        except Exception:
+            if not self._failed:
+                self._failed = True
+                _log.exception("wait witness died; the next armed wait "
+                               "starts it again")
+
+    def _look(self) -> None:
+        tick = WITNESS_TICK_NS
+        sched = cpu = None
+        while not self._stopping:
+            watches = tuple(self._open)
+            if not watches:
+                self._armed.clear()
+                if not self._open and not self._stopping:
+                    self._armed.wait()
+                sched = None
+                continue
+            if sched is None:
+                sched = min(w.start_ns for w in watches) + tick
+                cpu = time.process_time_ns()
+            now = time.monotonic_ns()
+            if sched > now:
+                time.sleep((sched - now) / 1e9)
+            woke = time.monotonic_ns()
+            cpu0, cpu = cpu, time.process_time_ns()
+            for w in tuple(self._open):
+                if w.open and len(w.ticks) < WITNESS_MAX_TICKS:
+                    w.ticks.append(Tick(
+                        woke, woke - sched, cpu - cpu0, _ready(w.awaited),
+                        w.behind is not None and _ready(w.behind)))
+            done = time.monotonic_ns()
+            self.ticks += 1
+            self.busy_ns += done - woke
+            sched = done + tick
+
+
+_WITNESS = _Witness()
+_log = logging.getLogger("nns_tpu.obs")
+
+
+def witness_stats() -> Dict[str, Any]:
+    """The witness's own cost, for an operator: its looks over the
+    process's life (``ticks``), the wall they took, wake to next sleep
+    (``busy_s``), and whether its thread runs (``alive``)."""
+    t = _WITNESS._thread
+    return {"ticks": _WITNESS.ticks, "busy_s": _WITNESS.busy_ns / 1e9,
+            "alive": t is not None and t.is_alive()}
+
+
 class phase:
     """One host phase of an engine iteration, timed once and written
     three ways. ``with phase(stats, "serving.decode_wait", parent=step)``
@@ -678,13 +915,26 @@ class phase:
       ``parent`` roots a trace of its own (thinned by ``sample_every``
       like any buffer-rate root), one with a parent is its child, and
       records only if the parent did. Tracing off makes no ``Span``.
+
+    A phase given ``awaited`` (the arrays a wait blocks on) arms the
+    process's witness for its length, with ``behind`` (the arrays of the
+    program queued behind them, or None): the witness looks at them every
+    5 ms. On exit the waiting thread adds its own last look (the awaited
+    arrays ready, and whether those behind are), ``classify_wait`` splits
+    the phase's time, and each part is added to
+    ``stats["wait_<cause>_s"]`` and, where ``record`` is a
+    ``wait_record``, to it.
     """
 
     __slots__ = ("name", "start_ns", "end_ns", "_stats", "_key", "_parent",
-                 "_note", "_span")
+                 "_note", "_span", "_awaited", "_behind", "_record",
+                 "_watch")
 
     def __init__(self, stats: Dict[str, Any], name: str,
-                 parent: Optional["phase"] = None) -> None:
+                 parent: Optional["phase"] = None,
+                 awaited: Optional[Sequence[Any]] = None,
+                 behind: Optional[Sequence[Any]] = None,
+                 record: Optional[Dict[str, Any]] = None) -> None:
         self.name = name
         self._stats = stats
         self._key = name.partition(".")[2] + "_s"
@@ -692,6 +942,10 @@ class phase:
         self._note = None
         self._span: Optional[Span] = None
         self.start_ns = self.end_ns = 0
+        self._awaited = awaited
+        self._behind = behind
+        self._record = record
+        self._watch: Optional[_Watch] = None
 
     def set_attribute(self, key: str, value: Any) -> None:
         """On the ``Span``, where one is being recorded."""
@@ -712,6 +966,9 @@ class phase:
         if note:
             self._note = note(self.name)
             self._note.__enter__()
+        if self._awaited is not None:
+            self._watch = _WITNESS.arm(self.start_ns, self._awaited,
+                                       self._behind)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -719,14 +976,38 @@ class phase:
             self._note.__exit__(exc_type, exc, tb)
         self.end_ns = time.monotonic_ns()
         self._stats[self._key] += (self.end_ns - self.start_ns) / 1e9
+        if self._watch is not None:
+            self._judge(exc_type is None)
         if self._span is not None:
             if exc_type is not None:
                 self._span.attrs["error"] = True
             self._span.end()
 
+    def _judge(self, returned: bool) -> None:
+        w = self._watch
+        ticks = _WITNESS.disarm(w)
+        if returned:
+            ticks.append(Tick(self.end_ns, 0, 0, True, w.behind is not None
+                              and _ready(w.behind)))
+        wait = classify_wait(self.start_ns, self.end_ns, ticks)
+        st = self._stats
+        for key, part in _WAIT_KEYS:
+            st[key] += wait[part]
+        rec = self._record
+        if rec is not None:
+            for _, part in _WAIT_KEYS:
+                rec[part] += wait[part]
+            rec["held_cpu_s"] += wait["held_cpu_s"]
+            if self.seconds > rec["longest_s"]:
+                rec["longest_s"] = self.seconds
+                rec["longest_lag_s"] = wait["lag_s"]
+
     @property
     def seconds(self) -> float:
         return (self.end_ns - self.start_ns) / 1e9
+
+
+_WAIT_KEYS = tuple((f"wait_{c}_s", f"{c}_s") for c in WAIT_CAUSES)
 
 
 def stamp_buffer(buf: Any, span_store: SpanStore, source: str):

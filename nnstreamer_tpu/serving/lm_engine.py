@@ -128,10 +128,12 @@ ROLES = ("unified", "prefill", "decode")
 SESSION_PATHS_LIMIT = 256
 
 #: the phases of one iteration (``serving.<name>`` spans, ``<name>_s``
-#: counters; docs/observability.md has the table of what each covers)
+#: counters; docs/observability.md has the table of what each covers);
+#: ``device_wait`` and ``readback`` lie inside ``first_token_wait`` and
+#: ``decode_wait``
 STEP_PHASES = ("step", "admit", "admit_host", "prefill_dispatch",
                "slot_insert", "first_token_wait", "decode_dispatch",
-               "decode_wait", "retire")
+               "decode_wait", "device_wait", "readback", "retire")
 _PHASE_KEYS = tuple(f"{name}_s" for name in STEP_PHASES)
 
 #: weak registry of every constructed engine — `nns-launch` walks it at
@@ -141,7 +143,7 @@ _LIVE_ENGINES: "weakref.WeakSet[LMEngine]" = weakref.WeakSet()
 
 
 def _copy_step(rec: Dict[str, Any]) -> Dict[str, Any]:
-    return {**rec, "phases": dict(rec["phases"]),
+    return {**rec, "phases": dict(rec["phases"]), "wait": dict(rec["wait"]),
             "admitted": list(rec["admitted"]), "gc": list(rec["gc"])}
 
 
@@ -781,8 +783,12 @@ class LMEngine:
             rows = getattr(self._family, "ROWS_ATTENDED", None)
             self.stats.update((key, 0) for key in
                               self._family.COUNTS + ((rows,) if rows else ()))
-        # <phase>_s: seconds in each phase, added by obs/tracing.phase
+        # <phase>_s: seconds in each phase, added by obs/tracing.phase;
+        # wait_<cause>_s: the parts of device_wait the witness told apart
+        # (obs/tracing.classify_wait)
         self.stats.update((key, 0.0) for key in _PHASE_KEYS)
+        self.stats.update((f"wait_{cause}_s", 0.0)
+                          for cause in _tracing.WAIT_CAUSES)
         # one record an iteration: the last STEP_RING of them, and the
         # STEP_KEEP_SLOWEST slowest of the engine's life that used no
         # executable for the first time (a compile is slow by design)
@@ -1126,9 +1132,10 @@ class LMEngine:
         (slots decoding, requests still waiting), ``lane_steps``,
         ``lane_rows`` and ``lane_tokens`` (those of the steps that carried
         prompt rows, the rows offered and the prompt tokens among them),
-        ``gc`` (collections per generation that fell in it) and
+        ``gc`` (collections per generation that fell in it),
         ``first_use`` (whether an executable was used for the first
-        time)."""
+        time) and ``wait`` (its ``serving.device_wait`` phases split by
+        cause: ``obs.tracing.wait_record``)."""
         with self._steps_lock:
             return [_copy_step(r) for r in self._steps]
 
@@ -1170,7 +1177,8 @@ class LMEngine:
         rec: Dict[str, Any] = {
             "iteration": st["iterations"], "admitted": [], "chunk": 0,
             "active": 0, "first_use": False,
-            "lane_steps": 0, "lane_rows": 0, "lane_tokens": 0}
+            "lane_steps": 0, "lane_rows": 0, "lane_tokens": 0,
+            "wait": _tracing.wait_record()}
         before = [st[key] for key in _PHASE_KEYS]
         gc0 = [g["collections"] for g in gc.get_stats()]
         cpu0 = time.thread_time_ns()
@@ -1205,16 +1213,19 @@ class LMEngine:
                     slow.remove(min(slow, key=lambda r: r["wall_s"]))
         if rec["wall_s"] > self.STEP_STALL_S and not rec["first_use"]:
             # the engine names its own stalled phase: which one stood
-            # still, whether the thread ran (cpu_s), and whether a
-            # collection fell in it (gc, by generation)
-            log.warning("%s: iteration %d stood still for %.3f s: %s",
-                        self._engine_label, rec["iteration"],
-                        rec["wall_s"], rec)
+            # still, whether the thread ran (cpu_s), whether a collection
+            # fell in it (gc, by generation), and what most of its waits
+            # on the device were (wait)
+            cause = _tracing.wait_cause(rec["wait"])
+            log.warning("%s: iteration %d stood still for %.3f s "
+                        "(device wait mostly %s): %s", self._engine_label,
+                        rec["iteration"], rec["wall_s"], cause, rec)
             _events.record(
                 "serving.step_stall",
                 f"{self._engine_label}: iteration {rec['iteration']} "
                 f"took {rec['wall_s']:.3f} s", severity="warning",
-                engine=self._engine_label, step=_copy_step(rec))
+                engine=self._engine_label, cause=cause,
+                step=_copy_step(rec))
 
     def _drain(self) -> None:
         """Read the chunk that is in flight, if one is, and retire what it
@@ -1567,7 +1578,12 @@ class LMEngine:
                 # finishing, then one D2H
                 with _tracing.phase(st, "serving.first_token_wait",
                                     parent=admit) as fw:
-                    req.out.append(int(first))
+                    with _tracing.phase(st, "serving.device_wait",
+                                        parent=fw, awaited=(first,),
+                                        record=rec["wait"]):
+                        jax.block_until_ready(first)
+                    with _tracing.phase(st, "serving.readback", parent=fw):
+                        req.out.append(int(first))
                 # TTFT after the int() materialization: the prefill
                 # dispatch is async, so the first token only exists for
                 # the caller once that D2H read completes
@@ -1976,17 +1992,39 @@ class LMEngine:
         first token gets all of them at once."""
         st = self.stats
         n = chunk.n
-        # blocks until the device has run the chunk, then copies (S, n)
-        # tokens to the host
+        # what the host reads of the chunk: its tokens, the routing counts
+        # of a family that counts, the lane's confidences for a quality
+        # hook (None where there is nothing to read)
+        conf = chunk.conf if _quality.QUALITY_HOOK is not None else None
+        need = (chunk.outs, chunk.counts, conf)
+        behind = self._flight
+        # the chunk behind tells a completion delivered late only where it
+        # runs a full chunk's steps: a shorter one (a lane chunk whose
+        # prompts end in a step or two, a tail) can be ready within the
+        # witness's two looks by its own device time
+        watched = None if behind is None or behind.n < self.chunk \
+            else (behind.outs,)
         with _tracing.phase(st, "serving.decode_wait", parent=step) as dw:
-            outs = np.asarray(chunk.outs)
-        if self._flight is not None:
+            # the device running what is left of the chunk, its completion
+            # delivered and this thread woken; the witness watches the
+            # chunk and, on an engine that runs ahead, a full chunk behind
+            with _tracing.phase(
+                    st, "serving.device_wait", parent=dw, awaited=need,
+                    behind=watched, record=rec["wait"]):
+                jax.block_until_ready(need)
+            # the copy of the ready arrays to the host: one round trip
+            with _tracing.phase(st, "serving.readback", parent=dw):
+                outs, counts, conf = jax.device_get(need)
+        if behind is not None:
             # the device begins the chunk behind this one now
-            self._flight.start_ns = dw.end_ns
+            behind.start_ns = dw.end_ns
         wall = (dw.end_ns - chunk.start_ns) / 1e9
         self._m_tok_lat.observe(wall / n)
         if _profile.ENGINE_HOOK is not None:
-            # np.asarray blocked on the chunk: wall ≈ device time; the
+            # from when the device could begin the chunk (its dispatch, or
+            # the end of the wait for the chunk it was queued behind) to
+            # the end of its readback: its device time, the delivery of
+            # its completion and the readback (device_wait, readback); the
             # occupancy sample drives the Perfetto serving counter lane
             _profile.ENGINE_HOOK.record_engine(
                 self, "decode", chunk.start_ns, dw.end_ns,
@@ -2006,9 +2044,9 @@ class LMEngine:
             st["decode_steps"] += n
             st["slot_steps"] += chunk.slot_steps
             st["kv_rows_attended"] += chunk.kv_rows
-            if chunk.counts is not None:
+            if counts is not None:
                 fam = self._family
-                for key, value in zip(fam.COUNTS, np.asarray(chunk.counts)):
+                for key, value in zip(fam.COUNTS, counts):
                     st[key] += int(value)
                 rows = getattr(fam, "ROWS_ATTENDED", None)
                 if rows:
@@ -2027,8 +2065,8 @@ class LMEngine:
                 if req.done:
                     st["ahead_dropped_slot_steps"] += n - start
                     continue
-                if j is not None and _quality.QUALITY_HOOK is not None:
-                    req.conf = chunk.conf[j]
+                if j is not None and conf is not None:
+                    req.conf = conf[j]
                 # the columns past `keeps` count as waste, and so do those
                 # behind a token that ends the request
                 for tok in map(int, outs[slot, start:start + keeps]):
@@ -2190,8 +2228,11 @@ class LMEngine:
             (self._tokens, self._kc, self._vc, self._pos, outs, m) = \
                 self._run_verify(tokens_in)
         with _tracing.phase(st, "serving.decode_wait", parent=step) as dw:
-            outs = np.asarray(outs)
-            m = np.asarray(m)
+            with _tracing.phase(st, "serving.device_wait", parent=dw,
+                                awaited=(outs, m), record=rec["wait"]):
+                jax.block_until_ready((outs, m))
+            with _tracing.phase(st, "serving.readback", parent=dw):
+                outs, m = jax.device_get((outs, m))
         st["chunks"] += 1
         self._note_first_use(("verify", g + 1), dd, rec)
         wall = (dw.end_ns - dd.start_ns) / 1e9

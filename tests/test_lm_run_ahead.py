@@ -25,6 +25,7 @@ import pytest
 import jax
 
 from nnstreamer_tpu.models import causal_lm
+from nnstreamer_tpu.obs import tracing
 from nnstreamer_tpu.sched import DeviceEngine
 from nnstreamer_tpu.serving import LMEngine
 from nnstreamer_tpu.serving.lm_engine import LANE_ROWS
@@ -327,6 +328,42 @@ def test_an_idle_engine_dispatches_two_and_reads_one(params):
     # 1 lane step, then 9 tokens behind the first: chunks of 4, 4, 1
     assert per_iteration == [(2, 1), (1, 4), (1, 4), (0, 1)]
     assert eng.stats["chunks"] == 4 and eng.stats["chunks_ahead"] == 3
+
+
+def test_the_wait_watches_the_chunk_behind(params, monkeypatch):
+    """A chunk read while a full chunk behind it is in flight arms the
+    witness with the outputs of that one; a chunk with a shorter one
+    behind it (here the tail of one step), or with none (the last), arms
+    it alone; every wait is split into the record's ``wait``."""
+    armed = []
+    arm = tracing._WITNESS.arm
+    monkeypatch.setattr(tracing._WITNESS, "arm",
+                        lambda t, awaited, behind: armed.append(
+                            (awaited, behind)) or arm(t, awaited, behind))
+    eng = engine(params)
+    eng.submit(prompt(5, 1), 10)
+    flights = []
+    while True:
+        before = len(armed)
+        more = eng.step_iteration()
+        flights.append(eng._flight)
+        # one wait an iteration, on the chunk's tokens
+        assert len(armed) == before + 1
+        if not more:
+            break
+    behinds = [b for _, b in armed]
+    assert [f and f.n for f in flights] == [CHUNK, CHUNK, 1, None]
+    assert [b is None for b in behinds] \
+        == [f is None or f.n < CHUNK for f in flights]
+    assert all(b[0] is f.outs for b, f in zip(behinds, flights)
+               if b is not None)
+    for rec in eng.recent_steps():
+        w = rec["wait"]
+        parts = sum(w[f"{c}_s"] for c in tracing.WAIT_CAUSES)
+        assert parts == pytest.approx(rec["phases"]["device_wait"],
+                                      abs=1e-6)
+        assert w["longest_s"] == pytest.approx(
+            rec["phases"]["device_wait"], abs=1e-6)
 
 
 def paged(params, ahead):

@@ -11,6 +11,7 @@ import logging
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -148,10 +149,69 @@ def test_children_sum_to_no_more_than_their_parent(params):
     st = eng.stats
     assert sum(st[f"{p}_s"] for p in STEP_CHILDREN) <= st["step_s"]
     assert sum(st[f"{p}_s"] for p in ADMIT_CHILDREN) <= st["admit_s"]
+    # the wait's two phases lie inside it
+    assert st["device_wait_s"] + st["readback_s"] \
+        <= st["decode_wait_s"] + st["first_token_wait_s"]
     for rec in eng.recent_steps():
         ph = rec["phases"]
         assert sum(ph[p] for p in STEP_CHILDREN) <= ph["step"] + 1e-9
         assert ph["step"] == pytest.approx(rec["wall_s"])
+        assert ph["device_wait"] + ph["readback"] \
+            <= ph["decode_wait"] + ph["first_token_wait"] + 1e-9
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_device_wait_is_split_by_the_witness(params, kind):
+    """The four parts of ``device_wait`` that the witness tells apart sum
+    to it, in ``stats`` and in every record's ``wait``."""
+    eng = _engine(params, kind)
+    for i in range(3):
+        eng.submit(_prompt(4 + 3 * i), max_new=6)
+    eng.run()
+    st = eng.stats
+    parts = {c: st[f"wait_{c}_s"] for c in tracing.WAIT_CAUSES}
+    assert all(v >= 0.0 for v in parts.values())
+    assert sum(parts.values()) <= st["device_wait_s"] + 1e-6
+    assert sum(parts.values()) == pytest.approx(st["device_wait_s"],
+                                                abs=1e-6)
+    assert st["device_wait_s"] + st["readback_s"] \
+        <= st["decode_wait_s"] + st["first_token_wait_s"]
+    recs = eng.recent_steps()
+    assert all(set(r["wait"]) == set(tracing.wait_record()) for r in recs)
+    for c in tracing.WAIT_CAUSES:
+        assert sum(r["wait"][f"{c}_s"] for r in recs) \
+            == pytest.approx(parts[c], abs=1e-6)
+    for r in recs:
+        assert r["wait"]["longest_s"] <= r["phases"]["device_wait"] + 1e-9
+        assert r["wait"]["held_cpu_s"] >= 0.0
+    # a copy, as the rest of the record
+    recs[0]["wait"]["unready_s"] = -1.0
+    assert eng.recent_steps()[0]["wait"]["unready_s"] >= 0.0
+
+
+def test_one_witness_however_many_engines_and_idle_when_unarmed(params):
+    engines = [_engine(params) for _ in range(3)]
+    for i, eng in enumerate(engines):
+        eng.submit(_prompt(5 + i), max_new=5)
+    for eng in engines:
+        eng.run()
+    witnesses = [t for t in threading.enumerate()
+                 if t.name == "nns-wait-witness"]
+    assert len(witnesses) == 1 and witnesses[0].daemon
+    assert tracing.witness_stats()["alive"]
+    # nothing armed: after the look it may still have been making, it
+    # blocks and looks no more
+    time.sleep(0.05)
+    ticks = tracing.witness_stats()["ticks"]
+    time.sleep(0.2)
+    assert tracing.witness_stats()["ticks"] == ticks
+    # stopped, the next armed wait starts one again, and only one
+    tracing._WITNESS.stop()
+    assert not tracing.witness_stats()["alive"]
+    engines[0].submit(_prompt(6), max_new=3)
+    engines[0].run()
+    assert [t.name for t in threading.enumerate()].count(
+        "nns-wait-witness") == 1
 
 
 def test_first_use_and_admission_wait(params):
@@ -449,6 +509,42 @@ def test_a_stalled_dispatch_is_named_and_warned_once(
     assert len(stalls) == 1
     assert stalls[0]["attrs"]["step"]["iteration"] == worst["iteration"]
     assert stalls[0]["severity"] == "warning"
+
+
+def test_a_stalled_wait_names_its_cause(params, monkeypatch, caplog,
+                                       events_on):
+    """A chunk that is ready while the call that waits for it does not
+    return: the witness sees it ready, and the stall's warning and event
+    name the wait unreturned."""
+    eng = _engine(params)
+    eng.submit(_prompt(5), max_new=6)
+    eng.run()                       # every program this test uses, warm
+    block = jax.block_until_ready
+    calls = []
+
+    def returns_late(x):
+        got = block(x)
+        calls.append(1)
+        if len(calls) == 2:
+            time.sleep(LMEngine.STEP_STALL_S + 0.1)
+        return got
+
+    monkeypatch.setattr(jax, "block_until_ready", returns_late)
+    eng.submit(_prompt(6), max_new=13)
+    with caplog.at_level(logging.WARNING, logger="nns_tpu.serving"):
+        eng.run()
+    worst = eng.slowest_steps()[0]
+    w = worst["wait"]
+    assert w["unreturned_s"] + w["held_s"] > LMEngine.STEP_STALL_S
+    assert w["unreturned_s"] > w["unready_s"] + w["delivered_late_s"]
+    assert w["longest_s"] > LMEngine.STEP_STALL_S
+    (warned,) = [r for r in caplog.records
+                 if "stood still" in r.getMessage()]
+    (stall,) = [e for e in events_on.snapshot()
+                if e["type"] == "serving.step_stall"]
+    assert stall["attrs"]["cause"] == tracing.wait_cause(w)
+    assert f"device wait mostly {stall['attrs']['cause']}" \
+        in warned.getMessage()
 
 
 def test_a_slow_first_use_iteration_is_no_stall(params, monkeypatch, caplog):

@@ -7,6 +7,7 @@ meta), serving-engine spans, the ``/debug/*`` exposition endpoints,
 and the PipelineTracer report ordering satellite."""
 
 import json
+import logging
 import socket
 import threading
 import time
@@ -45,6 +46,137 @@ def free_port():
     port = s.getsockname()[1]
     s.close()
     return port
+
+
+# --------------------------------------------------------------------------- #
+# the wait witness: how a wait's time is split, from a sequence of looks
+# --------------------------------------------------------------------------- #
+
+MS = 1_000_000
+
+
+def looks(n, ready_at=None, behind_at=None, late=(), cpu=False):
+    """``n`` looks 5 ms apart from 0; the awaited arrays ready from look
+    ``ready_at`` on, those behind from ``behind_at`` on; the looks in
+    ``late`` woke 200 ms late, with the process running through them
+    where ``cpu``."""
+    out, t = [], 0
+    for i in range(n):
+        lag = 200 * MS if i in late else 0
+        t += 5 * MS + lag
+        out.append(tracing.Tick(
+            t, lag, lag if cpu else 0,
+            ready_at is not None and i >= ready_at,
+            behind_at is not None and i >= behind_at))
+    return out
+
+
+#: (looks, the parts in ms: unready, unreturned, held, delivered late; the
+#: cause named; the lag from the awaited arrays' sighting to those behind)
+WAITS = {
+    # 20 looks that saw nothing ready, and the call returned 1 ms after
+    "normal": (looks(20), (101, 0, 0, 0), "unready", None),
+    # ready from look 10, and the call stood in it 40 looks more
+    "unreturned": (looks(51, ready_at=10), (55, 200, 0, 0), "unreturned",
+                   None),
+    # three looks 200 ms late: the process descheduled, or running
+    "held_descheduled": (looks(20, late={5, 6, 7}), (85, 0, 615, 0),
+                         "held, descheduled", None),
+    "held_busy": (looks(20, late={5, 6, 7}, cpu=True), (85, 0, 615, 0),
+                  "held, process busy", None),
+    # both chunks seen ready in one look after a long wait: the device had
+    # run the one behind too, so the awaited one's completion came late
+    "delivered_late": (looks(40, ready_at=30, behind_at=30),
+                       (0, 45, 0, 155), "delivered late", 0.0),
+    # both seen ready at once, but after the host stood still: the hold's
+    "held_then_both": (looks(40, ready_at=30, behind_at=30, late={20}),
+                       (150, 45, 205, 0), "held, descheduled", 0.0),
+    # the chunk behind 20 looks after: a chunk's device time, not a
+    # completion come late
+    "behind_later": (looks(60, ready_at=30, behind_at=50), (155, 145, 0, 0),
+                     "unready", 0.1),
+}
+
+
+@pytest.mark.parametrize("case", list(WAITS))
+def test_wait_is_split_by_what_the_witness_saw(case):
+    ticks, want, cause, lag = WAITS[case]
+    end = ticks[-1].end_ns + (MS if case == "normal" else 0)
+    got = tracing.classify_wait(0, end, ticks + [
+        tracing.Tick(end + 5 * MS, 0, 0, True, True)])  # after the end
+    parts = [got[f"{c}_s"] for c in tracing.WAIT_CAUSES]
+    assert parts == pytest.approx([w / 1e3 for w in want])
+    assert sum(parts) == pytest.approx(end / 1e9)
+    assert got["held_cpu_s"] == pytest.approx(
+        0.6 if case == "held_busy" else 0.0)
+    assert got["lag_s"] == (None if lag is None else pytest.approx(lag))
+    rec = tracing.wait_record()
+    for c in tracing.WAIT_CAUSES:
+        rec[f"{c}_s"] = got[f"{c}_s"]
+    rec["held_cpu_s"] = got["held_cpu_s"]
+    assert tracing.wait_cause(rec) == cause
+    assert tracing.wait_cause(tracing.wait_record()) is None
+
+
+class Comes:
+    """A fake array that turns ready at a given monotonic instant."""
+
+    def __init__(self, at_ns):
+        self.at_ns = at_ns
+
+    def is_ready(self):
+        return time.monotonic_ns() >= self.at_ns
+
+
+def test_witness_sees_a_result_the_call_did_not_return():
+    """A result ready 100 ms into the wait (beside a host array, which is
+    always ready), a call that returns 300 ms after that, and a chunk
+    behind that never comes: most of the wait is unreturned, and nothing
+    is put down to a late completion. Margins of 50 ms: a look held up by
+    a loaded machine moves time to ``held``."""
+    stats = {f"{p}_s": 0.0 for p in ("device_wait",)}
+    stats.update((f"wait_{c}_s", 0.0) for c in tracing.WAIT_CAUSES)
+    rec = tracing.wait_record()
+    t0 = time.monotonic_ns()
+    with tracing.phase(stats, "serving.device_wait",
+                       awaited=[Comes(t0 + 100 * MS), np.zeros(2)],
+                       behind=[Comes(t0 + 10 ** 12)], record=rec) as ph:
+        time.sleep(0.4)
+    assert ph.seconds >= 0.4
+    assert 0.25 <= rec["unreturned_s"] + rec["held_s"]
+    assert rec["unreturned_s"] <= 0.35
+    assert rec["unready_s"] <= 0.15
+    assert rec["delivered_late_s"] == 0.0 and rec["longest_lag_s"] is None
+    assert sum(stats[f"wait_{c}_s"] for c in tracing.WAIT_CAUSES) \
+        == pytest.approx(stats["device_wait_s"], abs=1e-6)
+    assert all(stats[f"wait_{c}_s"] == rec[f"{c}_s"]
+               for c in tracing.WAIT_CAUSES)
+    assert rec["longest_s"] == ph.seconds
+
+
+def test_a_witness_that_died_is_started_again(monkeypatch, caplog):
+    """A look that raises ends the witness's thread, logged once however
+    often it dies; the next armed wait starts a thread again."""
+    wit = tracing._Witness()
+
+    def broken(arrays):
+        raise ValueError("a look that fails")
+
+    monkeypatch.setattr(tracing, "_ready", broken)
+    with caplog.at_level(logging.ERROR, logger="nns_tpu.obs"):
+        for _ in range(2):
+            w = wit.arm(time.monotonic_ns(), [Comes(0)], None)
+            wit._thread.join(5)
+            assert not wit._thread.is_alive()
+            assert wit.disarm(w) == []
+    assert [r.getMessage() for r in caplog.records].count(
+        "wait witness died; the next armed wait starts it again") == 1
+    monkeypatch.undo()
+    w = wit.arm(time.monotonic_ns(), [Comes(0)], None)
+    time.sleep(0.1)
+    ticks = wit.disarm(w)
+    wit.stop()
+    assert ticks and all(t.ready for t in ticks)
 
 
 # --------------------------------------------------------------------------- #
