@@ -520,6 +520,8 @@ class _Chunk:
     firsts: Dict[int, int] = field(default_factory=dict)
     lane_tokens: int = 0
     conf: Any = None            # the lane's confidence triples, (n, 3)
+    #: whether its step count was cut at the earliest end by length
+    cut: bool = False
 
 
 class LMEngine:
@@ -765,6 +767,9 @@ class LMEngine:
                       # admissions into a slot whose request before still
                       # had tokens in a chunk not read (``_release``)
                       "admits_ahead": 0,
+                      # chunks cut short to end where a request ends by
+                      # length while a queue waited (``_dispatch_chunk``)
+                      "chunks_cut": 0,
                       # wall of the dispatch phases that used an
                       # executable for the first time (compile, or its
                       # load from the cache)
@@ -1801,7 +1806,9 @@ class LMEngine:
         and the readback's tail and the host's work between two chunks
         leave the streams' time. An end by length the host counts at the
         dispatch of the chunk that holds it, and the slot is free for the
-        next iteration's ``_admit`` from there (``_release``). What the
+        next iteration's ``_admit`` from there (``_release``); while a
+        queue waits, that chunk ends at the request's last token
+        (``_dispatch_chunk`` states the rule). What the
         host cannot count ahead is an end the tokens decide (an EOS): the
         chunk ahead decodes such a request all the same, its columns are
         dropped when read (``_read_chunk``), and its slot is freed when
@@ -1847,7 +1854,21 @@ class LMEngine:
         decodes and no prompt waits. The host's mirrors (``_pos_host``,
         ``_lane_at``, a request's ``due``) advance here, so that the chunk
         behind this one can be planned before this one is read; the
-        counters advance when it is read."""
+        counters advance when it is read.
+
+        A chunk runs ``chunk`` steps, fewer where a slot nears capacity,
+        where what is left to decode is shorter, or, with a lane, where
+        the prompts that wait have fewer windows. On an engine that runs
+        ahead, while a request waits in the queue, a chunk also ends no
+        later than the step in which the first decoding request ends by
+        length: ``_release`` frees that slot at this dispatch, and the
+        queue's head takes its first window in the chunk right behind,
+        with no idle tail of this one in between (``chunks_cut`` counts
+        the chunks this made shorter). Without a queue nobody waits for
+        the slot, and an end by EOS is not known at the dispatch: neither
+        cuts. A plain chunk shorter than ``chunk`` is floored to a power
+        of two, and a lane chunk's count is a traced value, so the cut
+        uses no executable the tails did not."""
         active = self._decoding()
         lane = bool(self._lane_at)
         if not active and not lane:
@@ -1874,13 +1895,11 @@ class LMEngine:
         else:
             remaining = max(self._left(self._slot_req[s]) for s in active)
         n = max(1, min(self.chunk, cap, remaining))
-        if n < self.chunk and not lane:
-            # floor TAILS to a power of two: chunk length is a static
-            # shape, so every distinct n is its own executable — pow2
-            # tails bound the cache at log2(chunk) entries instead of
-            # one per tail length (full-size chunks keep the user's
-            # exact value, whatever it is)
-            n = 1 << (n.bit_length() - 1)
+        uncut = self._floor_tail(n, lane)
+        if active and self._queue and self._runs_ahead():
+            n = max(1, min(n, min(self._left(self._slot_req[s])
+                                  for s in active)))
+        n = self._floor_tail(n, lane)
         if self._kv is not None:
             self._ensure_pages(active, n)
         st = self.stats
@@ -1913,12 +1932,23 @@ class LMEngine:
             cols[s] = (req, 0, keeps)
         chunk = _Chunk(n, outs, lane, ahead, dd.start_ns, len(active),
                        slot_steps=n * len(active), kv_rows=rows,
-                       counts=self._chunk_counts, cols=cols)
+                       counts=self._chunk_counts, cols=cols,
+                       cut=n < uncut)
         if lane:
             self._join_lane(chunk)
         if self._runs_ahead():
             self._release(chunk)
         return chunk
+
+    def _floor_tail(self, n: int, lane: bool) -> int:
+        """A plain chunk's ``n`` steps, a TAIL floored to a power of two:
+        chunk length is a static shape, so every distinct n is its own
+        executable — pow2 tails bound the cache at log2(chunk) entries
+        instead of one per tail length (full-size chunks keep the user's
+        exact value, whatever it is). A lane chunk's count is traced."""
+        if n < self.chunk and not lane:
+            return 1 << (n.bit_length() - 1)
+        return n
 
     def _release(self, chunk: _Chunk) -> None:
         """Free the slot of each request that ``chunk``, just dispatched,
@@ -2041,6 +2071,7 @@ class LMEngine:
             rec["active"] = max(rec["active"], chunk.active)
             st["chunks"] += 1
             st["chunks_ahead"] += chunk.ahead
+            st["chunks_cut"] += chunk.cut
             st["decode_steps"] += n
             st["slot_steps"] += chunk.slot_steps
             st["kv_rows_attended"] += chunk.kv_rows

@@ -12,12 +12,19 @@ chunk right behind that one, and is seen running (``pending``,
 ``slot_of``, ``progress``) until that chunk is read, where an EOS frees a
 slot only once it is read; a first token is handed out with the chunk
 behind it; ``_drain()`` makes the state a caller outside the loop sees the
-old order's; a quiet engine has nothing in flight.
+old order's; a quiet engine has nothing in flight. While a request waits
+in the queue, a chunk that the engine running ahead dispatches ends no
+later than the step in which a decoding request ends by length, with no
+executable the tails did not use and no token changed; with an empty
+queue, or an end by EOS, chunk lengths are those of the rule before.
 The product has no switch for the order: the tests that need the old one
-override the private predicate ``_runs_ahead`` on their engine. A module of
-its own, so that its executables are dropped apart from the others'
-(conftest).
+override the private predicate ``_runs_ahead`` on their engine, and those
+that need the lengths before the cut hide the queue from the planning of
+each chunk (``uncut``). A module of its own, so that its executables are
+dropped apart from the others' (conftest).
 """
+
+from collections import deque
 
 import numpy as np
 import pytest
@@ -78,6 +85,7 @@ def lane_holds(eng, reqs):
     assert eng.n_slots * st["decode_steps"] \
         == kept_tokens(reqs) + st["wasted_slot_steps"]
     assert st["chunks_ahead"] <= st["chunks"]
+    assert st["chunks_cut"] <= st["chunks"]
     assert st["admits_ahead"] == sum(a for _, a in admissions(eng).values())
     assert eng.pending() == sum(not r.done for r in reqs)
     queued = {r.rid for r in eng._queue}
@@ -218,6 +226,7 @@ def test_tokens_are_the_old_orders(params, name):
     assert old.stats["chunks_ahead"] == 0
     assert old.stats["ahead_dropped_slot_steps"] == 0
     assert old.stats["admits_ahead"] == 0
+    assert old.stats["chunks_cut"] == 0
     if name in QUEUED:
         assert st["admits_ahead"] > 0
     assert st["tokens_out"] == old.stats["tokens_out"]
@@ -258,6 +267,7 @@ def test_the_other_engines_keep_the_old_order(params, kind):
     assert st["chunks"] > 0 and st["chunks_ahead"] == 0
     assert st["ahead_dropped_slot_steps"] == 0
     assert st["admits_ahead"] == 0 and not eng._released
+    assert st["chunks_cut"] == 0
     assert not any(a for _, a in admissions(eng).values())
 
 
@@ -453,14 +463,15 @@ def dispatch_log(eng):
 @pytest.mark.parametrize("sampled", [False, True])
 def test_the_queue_head_takes_a_slot_when_its_last_chunk_is_dispatched(
         params, sampled):
-    """R's last three tokens ride the chunk dispatched in iteration 3. That
-    dispatch frees its slot: N, at the queue's head, takes it in
-    iteration 4 while the chunk is unread, and N's first window rides the
-    next chunk dispatched, right behind R's last, as in the order that
-    reads each chunk before the next (where N is admitted after the read).
-    Until the read R is running to whoever looks, from the slot N holds."""
+    """R's last two tokens ride the chunk dispatched in iteration 3, cut to
+    two steps since N waits. That dispatch frees its slot: N, at the
+    queue's head, takes it in iteration 4 while the chunk is unread, and
+    N's first window rides the next chunk dispatched, right behind R's
+    last, as in the order that reads each chunk before the next (where N
+    is admitted after the read). Until the read R is running to whoever
+    looks, from the slot N holds."""
     kw = dict(SAMPLED) if sampled else {}
-    arrivals = [req(0, 5, 13, 1, **kw), req(0, 9, 40, 2, **kw),
+    arrivals = [req(0, 5, 12, 1, **kw), req(0, 9, 40, 2, **kw),
                 req(0, 6, 4, 3, **kw)]
     ahead, old = engine(params), engine(params, ahead=False)
     r, _, n = (ahead.submit(**a) for _, a in arrivals)
@@ -486,6 +497,7 @@ def test_the_queue_head_takes_a_slot_when_its_last_chunk_is_dispatched(
     assert ahead.slot_of(r) is None and ahead.progress(r) == ahead.results[r]
     assert admissions(ahead)[n] == (0, True)
     assert ahead.stats["admits_ahead"] == 1
+    assert ahead.stats["chunks_cut"] == 1
     got, want = ahead.run(), old.run()
     assert got == want
     assert admissions(old)[n] == (0, False) and old.stats["admits_ahead"] == 0
@@ -517,3 +529,221 @@ def test_an_eos_frees_its_slot_when_it_is_read(params, idx):
     assert reqs[0].out[-1] == reqs[0].eos and len(reqs[0].out) == idx + 1
     assert admissions(eng)[reqs[2].rid] == (0, False)
     assert eng.stats["ahead_dropped_slot_steps"] > 0
+
+
+def pow2_floor(n):
+    return 1 << (n.bit_length() - 1)
+
+
+def uncut(eng):
+    """``eng`` planning every chunk as if no request waited in the queue:
+    the chunk lengths of the rule before the cut, in the same order."""
+    dispatch = eng._dispatch_chunk
+
+    def hidden(step, rec, ahead=False):
+        queue, eng._queue = eng._queue, deque()
+        try:
+            return dispatch(step, rec, ahead)
+        finally:
+            eng._queue = queue
+
+    eng._dispatch_chunk = hidden
+    return eng
+
+
+def plan_log(eng):
+    """Every chunk the engine dispatches, with what the host knew as it
+    planned it: the iteration, whether a request waited in the queue, the
+    tokens each decoding request had left to make by length (rid ->
+    count), the length the rule before the cut gives, and the rids the
+    dispatch released."""
+    log = []
+    dispatch = eng._dispatch_chunk
+
+    def logged(step, rec, ahead=False):
+        active = eng._decoding()
+        left = {eng._slot_req[s].rid: LMEngine._left(eng._slot_req[s])
+                for s in active}
+        cap = eng._m_slot - max((eng._pos_host[s] for s in active),
+                                default=0)
+        lane = bool(eng._lane_at)
+        if lane:
+            windows = sum(-(-(eng._slot_req[s].prompt.size - at)
+                            // LANE_ROWS) for s, at in eng._lane_at.items())
+            old = min(CHUNK, cap, windows)
+        else:
+            old = pow2_floor(min(CHUNK, cap, max(left.values(), default=1)))
+        queued = bool(eng._queue)
+        chunk = dispatch(step, rec, ahead)
+        if chunk is not None:
+            log.append(dict(chunk=chunk, it=rec["iteration"], lane=lane,
+                            queued=queued, left=left, old=old,
+                            released={r for r in left if r in eng._released}))
+        return chunk
+
+    eng._dispatch_chunk = logged
+    return log
+
+
+def cuts_read(eng, log):
+    """The chunks of ``log`` shorter than the rule before gives, of those
+    read so far."""
+    return sum(e["chunk"].n < e["old"] for e in log
+               if e["chunk"] is not eng._flight)
+
+
+#: every executable the engine may use: the lane's, and a plain chunk of
+#: ``chunk`` steps or of a power of two below it
+PROGRAMS = {("lane", CHUNK)} | {("chunk", 1 << k)
+                                for k in range(CHUNK.bit_length())}
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_a_chunk_ends_by_the_earliest_end_while_a_queue_waits(params, name):
+    """With a request in the queue, a chunk ends no later than the step in
+    which the first decoding request ends by length (a lane chunk there,
+    a plain one at a power of two at or before it), and a chunk that ends
+    a request ends at its last token; with an empty queue a chunk runs
+    the length of the rule before. ``chunks_cut`` counts the chunks made
+    shorter, slots x steps = kept + wasted after every iteration, the
+    tokens are those of the lengths before, and no executable is new."""
+    arrivals, _ = CASES[name]
+    if callable(arrivals):
+        arrivals = arrivals(params)
+    eng, before = engine(params), uncut(engine(params))
+    log, log_before = plan_log(eng), plan_log(before)
+
+    def each(eng, reqs):
+        lane_holds(eng, reqs)
+        assert eng.stats["chunks_cut"] == cuts_read(eng, log)
+
+    got = [r.out for r in drive(eng, arrivals, each=each)]
+    assert got == [r.out for r in drive(before, arrivals)]
+    for e in log:
+        n = e["chunk"].n
+        if e["queued"] and e["left"]:
+            first = min(e["left"].values())
+            cut = min(e["old"], first)
+            assert n == (cut if e["lane"] else pow2_floor(cut))
+            assert all(e["left"][rid] == n for rid in e["released"])
+        else:
+            assert n == e["old"]
+    assert eng.stats["chunks_cut"] == sum(e["chunk"].n < e["old"]
+                                          for e in log)
+    assert before.stats["chunks_cut"] == 0
+    # until a request waits, the lengths are the rule's before
+    queued = next((i for i, e in enumerate(log)
+                   if e["queued"] and e["left"]), len(log))
+    assert [e["chunk"].n for e in log[:queued]] \
+        == [e["chunk"].n for e in log_before[:queued]]
+    assert eng._seen_programs <= PROGRAMS
+    assert before._seen_programs <= PROGRAMS
+
+
+def device_step(log, which):
+    """The steps of the chunks of ``log`` before the one ``which`` picks,
+    and those through it."""
+    i = which(log)
+    before = sum(e["chunk"].n for e in log[:i])
+    return before, before + log[i]["chunk"].n
+
+
+def ends(rid):
+    """The entry of a log whose dispatch released ``rid``."""
+    return lambda log: next(i for i, e in enumerate(log)
+                            if rid in e["released"])
+
+
+def last_window(log, rid):
+    """The device step (from 0) whose lane window ended ``rid``'s
+    prompt."""
+    for i, e in enumerate(log):
+        for slot, j in e["chunk"].firsts.items():
+            if e["chunk"].cols[slot][0].rid == rid:
+                return sum(f["chunk"].n for f in log[:i]) + j
+    raise AssertionError(f"no window ended request {rid}")
+
+
+@pytest.mark.parametrize("sampled", [False, True])
+def test_a_plain_chunk_is_cut_to_powers_of_two_that_reach_the_end(
+        params, sampled):
+    """R has 3 tokens left to make when the chunk of iteration 3 is
+    planned, while N waits: the chunks run 2 steps, then 1, and that one
+    ends R at its last token and frees its slot (3 = 2 + 1), where the
+    rule before runs 4 with a step of R's slot past its end. N takes the
+    slot in the next iteration, and its first window rides the next chunk
+    a step sooner on the device."""
+    kw = dict(SAMPLED) if sampled else {}
+    arrivals = [req(0, 5, 13, 1, **kw), req(0, 9, 40, 2, **kw),
+                req(0, 6, 4, 3, **kw)]
+    eng, before = engine(params), uncut(engine(params))
+    logs = plan_log(eng), plan_log(before)
+    reqs = drive(eng, arrivals)
+    assert [r.out for r in reqs] == [r.out for r in drive(before, arrivals)]
+    r, _, n = (q.rid for q in reqs)
+    got = [[e["chunk"].n for e in log][:5] for log in logs]
+    assert got == [[2, 4, 4, 2, 1], [2, 4, 4, 4, 1]]
+    assert [e["it"] for e in logs[0]][:5] == [1, 1, 2, 3, 4]
+    assert eng.stats["chunks_cut"] == 2
+    # R's last token ends the chunk that releases it, a step sooner, and
+    # N's window rides the chunk right behind, the lane's only one
+    assert [device_step(log, ends(r))[1] for log in logs] == [13, 14]
+    assert [last_window(log, n) for log in logs] == [13, 14]
+    for log in logs:
+        behind = log[ends(r)(log) + 1]
+        assert behind["lane"] and behind["chunk"].n == 1
+    assert admissions(eng)[n] == admissions(before)[n] == (0, True)
+
+
+def test_a_lane_chunk_is_cut_at_the_end(params):
+    """R ends 2 steps into a chunk whose lane has 4 of B's windows while N
+    waits: the chunk runs 2 steps (a traced count: not floored), ends R
+    at its last token and frees its slot; the lane runs the rest of B's
+    windows and N's behind them in the next chunk. The lane takes its
+    windows in order, so N's window lies where it did under the rule
+    before; R's last token is read 2 steps sooner."""
+    arrivals = [req(0, 5, 7, 1), req(1, 3 * LANE_ROWS + 58, 3, 2),
+                req(1, 6, 4, 3)]
+    eng, before = engine(params), uncut(engine(params))
+    logs = plan_log(eng), plan_log(before)
+    reqs = drive(eng, arrivals)
+    assert [r.out for r in reqs] == [r.out for r in drive(before, arrivals)]
+    r, b, n = (q.rid for q in reqs)
+    cut, old = (log[ends(r)(log)] for log in logs)
+    assert (cut["lane"], cut["queued"], cut["chunk"].n, cut["old"]) \
+        == (True, True, 2, 4)
+    assert (old["lane"], old["chunk"].n) == (True, 4)
+    assert eng.stats["chunks_cut"] == 1
+    assert [device_step(log, ends(r))[1] for log in logs] == [7, 9]
+    assert [last_window(log, b) for log in logs] == [8, 8]
+    assert [last_window(log, n) for log in logs] == [9, 9]
+    assert eng._seen_programs <= PROGRAMS
+
+
+@pytest.mark.parametrize("idx", [0, 2, 4, 6])
+def test_an_eos_does_not_cut(params, idx):
+    """A request an EOS ends at token ``idx`` of 12 while a prompt waits
+    for a slot: every chunk dispatched until the EOS is read runs the
+    length it runs for the same request without an EOS. The host cannot
+    count an end the tokens decide, so it cuts nothing there."""
+    seed, eos = first_seen_at(params, idx, **SAMPLED)
+    arrivals = [req(0, 5, 12, seed, eos=eos, **SAMPLED), req(0, 70, 30),
+                req(0, 130, 5)]
+    plain = [(at, {k: v for k, v in kw.items() if k != "eos"})
+             for at, kw in arrivals]
+    done_at = []
+
+    def each(eng, reqs):
+        lane_holds(eng, reqs)
+        if reqs[0].done and not done_at:
+            done_at.append(eng.stats["iterations"])
+
+    eng, other = engine(params), engine(params)
+    logs = plan_log(eng), plan_log(other)
+    reqs = drive(eng, arrivals, each=each)
+    drive(other, plain)
+    assert reqs[0].out[-1] == reqs[0].eos and len(reqs[0].out) == idx + 1
+    got, want = ([(e["it"], e["chunk"].n) for e in log
+                  if e["it"] <= done_at[0]] for log in logs)
+    assert got == want
+    assert any(e["queued"] for e in logs[0] if e["it"] <= done_at[0])

@@ -415,11 +415,12 @@ def test_records_say_what_each_iteration_did(params, kind):
     if kind == "lane":
         # a record says what the chunk READ in its iteration did: two
         # lane steps, then (dispatched before those were read) the chunk
-        # of four behind the first tokens
+        # behind the first tokens, cut to the one step the first request
+        # has left since the third waits
         keys = ("active", "chunk", "lane_steps", "lane_rows", "lane_tokens")
         assert [recs[0][k] for k in keys] == [0, 2, 2, 2 * LANE_ROWS, 5 + 9]
-        assert [recs[1][k] for k in keys] == [2, 4, 0, 0, 0]
-        # the chunk of four ends the first request, so its slot is free
+        assert [recs[1][k] for k in keys] == [2, 1, 0, 0, 0]
+        # that chunk ends the first request, so its slot is free
         # once that chunk is dispatched (iteration 1): the third request
         # takes it in iteration 2, ahead of the chunk's read, and its
         # prompt's lane step rides the chunk dispatched there, read in
