@@ -29,16 +29,20 @@ writes the output. The new rows go to ``store[.., pos[b], :]`` by one small
 DMA a stream, started before the first item and waited for after the
 last.
 
-Arithmetic is float32 on the VPU: a one-row product is a multiply and a
-lane reduction, which keeps the precision the configuration states with
-no bf16 passes, and the MXU would run it at one row of 128.
-
 Grouped-query attention (fewer K/V heads than query heads, each K/V head
 serving ``group`` query heads in order) is read from the shapes: the
-queries have ``group`` times the heads of the new rows and the store. An
-item then fetches a K/V head's block once and folds it into the online
-softmax of each query head of its group. A group of one is the program
-the equal-head block has always run.
+queries have ``group`` times the heads of the new rows and the store.
+
+Arithmetic is float32 throughout. With equal heads (a group of one) it
+runs on the VPU: a head's product with a block is a multiply and a lane
+reduction, where the MXU would take its one query row as a tile of 8
+rows. A group's query rows share a K/V head's block, so an item fetches
+the block once and folds it into the whole group on the MXU, as two
+float32 products at ``highest`` precision: the group's rows (padded with
+zero rows to a sublane tile of 8, dropped at the output) against the
+block's keys, then the probabilities against its values, the online
+softmax's state lane-dense along the block. Which form runs follows from
+the shapes alone.
 
 Where the computation is not placed on a TPU the dense form runs
 (``ops/pallas.per_platform``); it is also the reference the tests hold the
@@ -69,6 +73,8 @@ _TAIL_ROWS = 32
 #: of reductions overlap and a tail's arithmetic fits under the next item's
 #: DMA. 16 (the loop unrolled whole) ran 0.7 % faster alone and took 0.09 s
 #: longer to lower, paid again in every process's set-up (PERF.md, PR 36)
+#: (K/V heads where the queries are grouped: at a group of 8, two a trip
+#: ran 3 % faster than one, PERF.md §6)
 _HEAD_UNROLL = 4
 
 
@@ -292,16 +298,18 @@ def _decode_kernel(row0_ref, pos_ref, write_ref, n_items_ref, item_b_ref,
                 act(block_copies(t, par, pl.multiple_of(j * grain, grain),
                                  grain))
 
-    # a stream without items (no request) gets its own value row back
-    if group == 1:
-        o_ref[...] = vn_ref[...].astype(o_ref.dtype)
-    else:
-        for hq in range(n_heads * group):
-            o_ref[:, hq] = vn_ref[:, hq // group].astype(o_ref.dtype)
+    # a stream without items (no request) gets its own value row back (a
+    # K/V head's to each query row of its group)
+    o_ref[...] = jnp.broadcast_to(vn_ref[...], o_ref.shape).astype(
+        o_ref.dtype)
     for b in range(n_streams):
         pl.when(write_ref[b] == 1)(functools.partial(
             start, row_copies(b) if row_dma else tile_copies(b, False)))
     pl.when(n_items > 0)(lambda: item_copies(0, 0, start))
+
+    nt = (((1,), (1,)), ((), ()))      # contract the last axis of both
+    f32 = dict(precision=jax.lax.Precision.HIGHEST,
+               preferred_element_type=jnp.float32)
 
     def item(t, carry):
         par = t % 2
@@ -313,24 +321,13 @@ def _decode_kernel(row0_ref, pos_ref, write_ref, n_items_ref, item_b_ref,
 
         @pl.when(blk == 0)
         def _from_new_row():
-            if group == 1:
-                m_scr[...] = jnp.sum(
-                    q_ref[b].astype(jnp.float32)
-                    * kn_ref[b].astype(jnp.float32),
-                    axis=-1, keepdims=True) * sm_scale  # (H, 1, 1)
-                l_scr[...] = jnp.ones_like(l_scr)
-                acc_scr[...] = vn_ref[b].astype(jnp.float32)
-                return
+            m_scr[...] = jnp.sum(
+                q_ref[b].astype(jnp.float32)
+                * kn_ref[b].astype(jnp.float32),
+                axis=-1, keepdims=True) * sm_scale  # (H, rows, 1)
             l_scr[...] = jnp.ones_like(l_scr)
-            for h in range(n_heads):
-                at = pl.ds(h * group, group)
-                m_scr[at] = jnp.sum(
-                    q_ref[b, at].astype(jnp.float32)
-                    * kn_ref[b, h][None].astype(jnp.float32),
-                    axis=-1, keepdims=True) * sm_scale  # (group, 1, 1)
-                acc_scr[at] = jnp.broadcast_to(
-                    vn_ref[b, h][None].astype(jnp.float32),
-                    (group,) + vn_ref.shape[2:])
+            acc_scr[...] = jnp.broadcast_to(vn_ref[b].astype(jnp.float32),
+                                            acc_scr.shape)
 
         item_copies(t, par, wait)
         n_live = p - blk * block
@@ -347,38 +344,53 @@ def _decode_kernel(row0_ref, pos_ref, write_ref, n_items_ref, item_b_ref,
                     jnp.int32, (grain, vbuf.shape[-1]), 0)
                 vbuf[at] = jnp.where(r < n_live, vbuf[at], 0)
 
-        rows = blk * block + jax.lax.broadcasted_iota(
-            jnp.int32, (block, 1), 0)
-        live = rows < p                                 # (block, 1)
+        # the block's rows down a column of scores (one query head's) or,
+        # grouped, along a row of them (each query row's)
+        shape, axis = ((block, 1), 0) if group == 1 else ((1, block), 1)
+        rows = blk * block + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        live = rows < p
 
-        def fold(k, v, h, hq):
-            """Query head ``hq``'s online softmax over K/V head h's block
-            (k, v: (block, hd))."""
-            s = jnp.sum(k * q_ref[b, hq].astype(jnp.float32), axis=-1,
+        def fold(k, v, h):
+            """Query head h's online softmax over its K/V head's block (k,
+            v: (block, hd)), on the VPU."""
+            s = jnp.sum(k * q_ref[b, h].astype(jnp.float32), axis=-1,
                         keepdims=True) * sm_scale       # (block, 1)
             s = jnp.where(live, s, _NEG_INF)
-            m_prev = m_scr[hq]                          # (1, 1)
+            m_prev = m_scr[h]                           # (1, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
             pr = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            l_scr[hq] = alpha * l_scr[hq] + jnp.sum(pr, axis=0,
-                                                    keepdims=True)
-            acc_scr[hq] = alpha * acc_scr[hq] + jnp.sum(
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(pr, axis=0, keepdims=True)
+            acc_scr[h] = alpha * acc_scr[h] + jnp.sum(
                 pr * v, axis=0, keepdims=True)          # (1, hd)
-            m_scr[hq] = m_new
+            m_scr[h] = m_new
+
+        def fold_group(k, v, h):
+            """The online softmax of K/V head h's query rows (its group,
+            padded to whole sublane tiles) over its block, as two products
+            on the MXU."""
+            s = jax.lax.dot_general(q_ref[b, h].astype(jnp.float32), k, nt,
+                                    **f32)              # (rows, block)
+            s = jnp.where(live, s * sm_scale, _NEG_INF)
+            m_prev = m_scr[h]                           # (rows, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            pr = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(pr, axis=1, keepdims=True)
+            acc_scr[h] = alpha * acc_scr[h] + jnp.dot(pr, v, **f32)
+            m_scr[h] = m_new
 
         def head(h):
             k = kbuf[par, h].astype(jnp.float32)        # (block, hd)
             v = vbuf[par, h].astype(jnp.float32)
-            for j in range(group):
-                fold(k, v, h, h * group + j)
+            (fold if group == 1 else fold_group)(k, v, h)
 
         def heads(i, c):
             for j in range(unroll):
                 head(i * unroll + j)
             return c
 
-        unroll = math.gcd(n_heads, max(_HEAD_UNROLL // group, 1))
+        unroll = math.gcd(n_heads, _HEAD_UNROLL)
         jax.lax.fori_loop(0, n_heads // unroll, heads, 0)
 
         @pl.when((blk + 1) * block >= p)
@@ -432,9 +444,16 @@ def _decode_pallas(q, k_new, v_new, kc, vc, li, pos, active=None, *,
     item_blk = jnp.clip(t - (ends - nblk)[item_b], 0, n_max - 1)
     write = (active & (pos < max_len)).astype(jnp.int32)
     flat = (kc.shape[0] * kc.shape[1] * h, max_len, hd)
+    group = hq // h
+    if group > 1:
+        # a K/V head's query rows as one slab of whole sublane tiles, the
+        # padding zeros, dropped from the output
+        padded = -(-group // 8) * 8
+        q = jnp.pad(q.reshape(b, h, group, hd),
+                    ((0, 0), (0, 0), (0, padded - group), (0, 0)))
     kernel = functools.partial(
         _decode_kernel, n_streams=b, n_heads=h, block=block, grain=grain,
-        max_len=max_len, sm_scale=1.0 / math.sqrt(hd), group=hq // h)
+        max_len=max_len, sm_scale=1.0 / math.sqrt(hd), group=group)
     # a new row wider than one lane tile goes in through its tile of 8
     tile = [] if hd == 128 else [pltpu.VMEM((2, b, h, 8, hd), kc.dtype)]
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -452,9 +471,11 @@ def _decode_pallas(q, k_new, v_new, kc, vc, li, pos, active=None, *,
             pltpu.VMEM((2, h, block, hd), vc.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SemaphoreType.DMA((2, b)),
-            pltpu.VMEM((hq, 1, 1), jnp.float32),
-            pltpu.VMEM((hq, 1, 1), jnp.float32),
-            pltpu.VMEM((hq, 1, hd), jnp.float32),
+            # the online softmax's state a query row: (H, 1) or, grouped,
+            # (K/V heads, the group's rows)
+            pltpu.VMEM(q.shape[1:3] + (1,), jnp.float32),
+            pltpu.VMEM(q.shape[1:3] + (1,), jnp.float32),
+            pltpu.VMEM(q.shape[1:3] + (hd,), jnp.float32),
         ] + tile,
         input_output_aliases={9: 1, 10: 2},
         # two buffers each of K and V, the tiles, and room for the item's
@@ -467,6 +488,8 @@ def _decode_pallas(q, k_new, v_new, kc, vc, li, pos, active=None, *,
     )(row0, pos, write, ends[-1:].astype(jnp.int32), item_b, item_blk,
       q, k_new.astype(kc.dtype), v_new.astype(vc.dtype),
       kc.reshape(flat), vc.reshape(flat))
+    if group > 1:
+        o = o[:, :, :group].reshape(b, hq, 1, hd)
     return o, kc2.reshape(kc.shape), vc2.reshape(vc.shape)
 
 

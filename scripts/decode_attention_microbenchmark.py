@@ -4,6 +4,12 @@ step's 24 calls of ``pallas.decode_attention`` cost on the attached chip.
     chiprun -- python scripts/decode_attention_microbenchmark.py \
         PARENT_CHECKOUT .:32:4 .:64:4 .:256:4 .:32:1 .:32:16
 
+and at Qwen3-Next's grouped shape (2 calls a step, 16 query heads over 2
+K/V heads of 256):
+
+    python scripts/decode_attention_microbenchmark.py \
+        PARENT_CHECKOUT . --dims 16,2,2,2048,256 --slots 64 --hi 700
+
 A form is ``ROOT[:GRAIN[:HEADS]]``: the kernel of the checkout at ROOT
 (``.`` is this one), with ``_TAIL_ROWS`` (a tail's sub-tile) set to GRAIN
 and ``_HEAD_UNROLL`` (heads a trip of an item's loop) to HEADS where given;
@@ -16,7 +22,9 @@ every set, in turn. Prints one JSON line a form: ms a step (the median over
 sets of each set's median of ``--reps`` steps), the seconds to lower and to
 compile the step (a lowering is paid again in every process, cache hit or
 not: ``setup_s``), store rows read and live a step, and the output's
-largest gap from the first form's.
+largest gap from the first form's. ``--dims`` gives the query heads, the
+K/V heads (fewer than the query heads where the queries are grouped), the
+layers, ``max_len`` and the head size; by default Cerebras-GPT-1.3B's.
 """
 
 from __future__ import annotations
@@ -60,8 +68,8 @@ def load_kernel(root: str):
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("forms", nargs="+", help="ROOT[:GRAIN[:HEADS]]")
-    ap.add_argument("--dims", default="16,24,2048,128",
-                    help="heads,layers,max_len,head size")
+    ap.add_argument("--dims", default="16,16,24,2048,128",
+                    help="query heads,K/V heads,layers,max_len,head size")
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--lo", type=int, default=32)
     ap.add_argument("--hi", type=int, default=680)
@@ -69,13 +77,15 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=36)
     args = ap.parse_args()
-    h, n_layers, max_len, hd = map(int, args.dims.split(","))
+    hq, h, n_layers, max_len, hd = map(int, args.dims.split(","))
     s = args.slots
     dev = jax.devices()[0]
-    print(json.dumps({"platform": dev.platform, "kind": dev.device_kind}),
-          flush=True)
-    row = jax.random.normal(jax.random.PRNGKey(args.seed), (3, s, h, 1, hd),
-                            jnp.float32)
+    print(json.dumps({"platform": dev.platform, "kind": dev.device_kind,
+                      "dims": args.dims, "slots": s}), flush=True)
+    q = jax.random.normal(jax.random.PRNGKey(args.seed), (s, hq, 1, hd),
+                          jnp.float32)
+    row = jax.random.normal(jax.random.PRNGKey(args.seed + 1),
+                            (2, s, h, 1, hd), jnp.float32)
     store = (s, n_layers, h, max_len, hd)
     kc = jax.random.normal(jax.random.PRNGKey(1), store, jnp.float32)
     vc = jax.random.normal(jax.random.PRNGKey(2), store, jnp.float32)
@@ -84,8 +94,8 @@ def main() -> None:
     def step(da, kc, vc, pos):
         o = []
         for li in range(n_layers):
-            oi, kc, vc = da.decode_attention(row[0], row[1], row[2], kc, vc,
-                                             li, pos, active, layer_axis=1)
+            oi, kc, vc = da.decode_attention(q, row[0], row[1], kc, vc, li,
+                                             pos, active, layer_axis=1)
             o.append(oi)
         return jnp.stack(o), kc, vc
 

@@ -362,3 +362,33 @@ def test_decode_attention_lowers_for_tpu():
         jax.ShapeDtypeStruct((s,), jnp.bool_)).mlir_module()
     assert "tpu_custom_call" in text
     assert "output_operand_alias" in text
+
+
+def test_grouped_decode_attention_lowers_for_tpu():
+    """Qwen3-Next's full layers (64 slots, 2 layers, 16 query heads over 2
+    K/V heads of 256, 2048 positions, float32) lower for the TPU platform
+    to the Mosaic call named ``decode_attention``, with the stores aliased
+    in and out."""
+    import jax
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.ops.pallas import decode_attention as da
+
+    s, layers, hq, h, m, hd = 64, 2, 16, 2, 2048, 256
+    row = jax.ShapeDtypeStruct((s, h, 1, hd), jnp.float32)
+    store = jax.ShapeDtypeStruct((s, layers, h, m, hd), jnp.float32)
+
+    def fn(q, kn, vn, kc, vc, li, pos, active):
+        return da.decode_attention(q, kn, vn, kc, vc, li, pos, active,
+                                   layer_axis=1)
+
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        jax.ShapeDtypeStruct((s, hq, 1, hd), jnp.float32), row, row, store,
+        store, jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((s,), jnp.int32),
+        jax.ShapeDtypeStruct((s,), jnp.bool_)).mlir_module()
+    assert "tpu_custom_call" in text
+    assert 'kernel_name = "decode_attention"' in text
+    for out, operand in ((1, 9), (2, 10)):
+        assert (f"output_tuple_indices = [{out}], operand_index = {operand},"
+                in text)

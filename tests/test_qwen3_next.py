@@ -300,6 +300,38 @@ def test_grouped_decode_attention_is_the_equal_heads_form(group, hd, pos):
                                    rtol=0, atol=0)
 
 
+# one stream at each edge of a block (256 rows at 512 positions) and of its
+# tail's sub-tiles (32 rows): none, one, a sub-tile less one, a sub-tile, a
+# block less one, a block, a block and a sub-tile and one
+EDGES = (0, 1, 31, 32, 255, 256, 289)
+
+
+@pytest.mark.parametrize("group,hd", [(8, 256), (4, 128), (2, 128)])
+@pytest.mark.parametrize("active", ["all", "mixed"])
+def test_grouped_decode_attention_at_block_and_tail_edges(group, hd, active):
+    """The grouped kernel (interpreter) folds a K/V head's block into its
+    group as two products, the group padded to 8 rows where it is smaller:
+    at every edge of a block and of a tail's sub-tiles it gives the dense
+    grouped form's output, and both stores to the bit; a stream that is not
+    active writes nothing and gets its K/V head's value row back."""
+    b, layers, hkv, m = len(EDGES), 2, 2, 512
+    ks = jax.random.split(jax.random.PRNGKey(hd + group), 5)
+    q = jax.random.normal(ks[0], (b, hkv * group, 1, hd))
+    kn, vn = (jax.random.normal(kk, (b, hkv, 1, hd)) for kk in ks[1:3])
+    kc, vc = (jax.random.normal(kk, (b, layers, hkv, m, hd))
+              for kk in ks[3:5])
+    pos = jnp.array(EDGES)
+    act = jnp.arange(b) % 2 == 0 if active == "mixed" else \
+        jnp.ones((b,), bool)
+    want = da.window_attention_reference(q, kn, vn, kc, vc, 1, pos, act,
+                                         layer_axis=1)
+    got = da.decode_attention(q, kn, vn, kc, vc, 1, pos, act, layer_axis=1,
+                              interpret=True)
+    np.testing.assert_allclose(got[0], want[0], rtol=0, atol=2e-6)
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_array_equal(got[2], want[2])
+
+
 def test_grouped_lane_attention_is_the_equal_heads_form():
     hkv, group, hd, p = 2, 8, 256, 16
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
